@@ -22,12 +22,23 @@ Layer map (mirrors reference SURVEY.md §1):
 
 __version__ = "0.1.0"
 
+import os as _os
+
 import jax as _jax
 
 # LONG/TIMESTAMP columns are int64; without x64, jnp.asarray silently wraps
 # them to int32. Float width stays policy-controlled (config.use_float64):
 # decimals are explicitly cast to float32 on TPU in types.device_dtype.
 _jax.config.update("jax_enable_x64", True)
+
+# Persistent compile cache. JAX reads JAX_COMPILATION_CACHE_DIR itself, so
+# when it is set nothing is set here; otherwise the cache sits at a fixed
+# place in the checkout (the path is part of the cache key: a directory
+# that moves never hits).
+if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    _jax.config.update("jax_compilation_cache_dir", _os.path.join(
+        _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))),
+        ".jax_cache"))
 
 from snappydata_tpu.session import SnappySession  # noqa: E402,F401
 from snappydata_tpu import config  # noqa: E402,F401

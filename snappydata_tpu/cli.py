@@ -12,6 +12,13 @@ Usage:
   python -m snappydata_tpu backup  --data-dir D --dest DIR
   python -m snappydata_tpu restore --backup DIR --data-dir D
   python -m snappydata_tpu status  --locator HOST:PORT
+
+A chip belongs to one process at a time. Of the long-running roles only
+`server` initialises the accelerator; `locator` and `lead` pin JAX to the
+CPU before any JAX call, so a lead started beside a server never takes the
+server's chip (or, finding it taken, slides to the CPU unnoticed). Each
+role prints the platform it ended up on. To run more than one server
+process on a one-chip machine, start the extra ones with JAX_PLATFORMS=cpu.
 """
 
 from __future__ import annotations
@@ -23,11 +30,27 @@ import sys
 import time
 
 
+def _pin_cpu() -> None:
+    """For the roles that do not own the accelerator."""
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
+
+
+def _device_line() -> str:
+    import jax
+
+    devs = jax.devices()
+    return (f"platform={devs[0].platform} "
+            f"device_kind={devs[0].device_kind!r} devices={len(devs)}")
+
+
 def _cmd_locator(args) -> int:
+    _pin_cpu()
     from snappydata_tpu.cluster import LocatorNode
 
     node = LocatorNode(host=args.host, port=args.port).start()
-    print(f"locator running at {node.address}")
+    print(f"locator running at {node.address} [{_device_line()}]")
     _wait_forever()
     return 0
 
@@ -60,12 +83,13 @@ def _cmd_server(args) -> int:
                       mesh_devices=mesh_devices).start()
     extra = f", submesh {mesh_devices}" if mesh_devices else ""
     print(f"server {node.member_id} flight at {node.flight_address}"
-          + extra)
+          + extra + f" [{_device_line()}]")
     _wait_forever()
     return 0
 
 
 def _cmd_lead(args) -> int:
+    _pin_cpu()
     from snappydata_tpu import SnappySession
     from snappydata_tpu.catalog import Catalog
     from snappydata_tpu.cluster import LeadNode
@@ -81,7 +105,8 @@ def _cmd_lead(args) -> int:
     role = "primary" if node.is_primary else "standby"
     print(f"lead {node.member_id} ({role}) flight at "
           f"{node.host}:{node.flight.port}"
-          + (f", rest at {node.rest_address}" if node.rest_address else ""))
+          + (f", rest at {node.rest_address}" if node.rest_address else "")
+          + f" [{_device_line()}]")
     _wait_forever()
     return 0
 
@@ -222,8 +247,11 @@ def main(argv=None) -> int:
             rp.add_argument("--rest-port", type=int, default=5050)
         if role == "server":
             rp.add_argument("--mesh-devices", default=None,
-                            help="comma-separated GLOBAL device indices "
-                                 "this server's submesh owns")
+                            help="comma-separated indices into THIS "
+                                 "process's jax.devices() that the "
+                                 "server's submesh owns (one process "
+                                 "holds a host's chips: two server "
+                                 "processes cannot split them this way)")
             rp.add_argument("--coordinator", default=None,
                             help="jax.distributed coordinator host:port "
                                  "(multi-host slice)")

@@ -144,13 +144,13 @@ class Properties:
     compaction_min_fallbacks: int = 1
     # Pallas compensated-f32 kernel for global float SUM/AVG instead of
     # the emulated-f64 segment reduction on TPU (ops/pallas_reduce.py).
-    # Default OFF until measured on hardware; bench.py reports the
-    # side-by-side timing when a TPU is reachable.
+    # Compiles and matches the XLA lane on the v5e (chip_smoke.py checks
+    # it on every PR); default OFF until its rate is measured there.
     pallas_reduce: bool = False
     # Fused Pallas grouped-aggregate kernel for the dictionary fast path
     # (the TPC-H Q1 shape): one VMEM pass per slot batch with per-group
     # per-lane Kahan partials, f64 combine outside (ops/pallas_group.py).
-    # Same default-OFF-until-measured policy as pallas_reduce.
+    # Same policy as pallas_reduce: checked by chip_smoke.py, default OFF.
     pallas_group_reduce: bool = False
     # Grouped-aggregate reduction strategy (ops/reduction.py): every
     # compatible slot of a query packs into one [N, S] matrix per

@@ -605,7 +605,7 @@ class CompiledPlan:
     def execute(self, params: Tuple) -> Result:
         tables, outs = self._run_device(params)
         # single bulk device→host transfer (per-array .asarray costs one
-        # round trip each — painful over a remote/tunneled TPU link).
+        # round trip each).
         # The transfer span absorbs the wait on the async dispatch, so
         # device_execute ≈ dispatch and transfer ≈ compute+copy.
         with tracing.span("transfer"):

@@ -52,11 +52,7 @@ import numpy as np
 
 from snappydata_tpu.utils import locks
 
-try:  # jax >= 0.4.35 re-exports; keep the experimental path for older
-    from jax.experimental.shard_map import shard_map
-except ImportError:  # pragma: no cover - newer jax moved it
-    from jax import shard_map
-
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 # -- shuffle specialization flag ------------------------------------------

@@ -1,18 +1,22 @@
-"""Native (C++) kernels with build-on-first-use and pure-Python fallback.
+"""Native (C++) kernels, built on first use.
 
-Compiles native/_fastingest.cpp with the system compiler on first import
-(cached under native/build/). Everything keeps working without a compiler:
-`fast_encode_strings` falls back to a vectorized pandas implementation.
+Compiles native/_fastingest.cpp with the system compiler into
+native/build/, under a name keyed on a hash of the source and the compile
+line, so a stale binary or one built some other way is never loaded.
+Without a compiler `fast_encode_strings` uses a vectorized pandas
+implementation, several times slower on the load path: the failed build
+is reported on stderr, and `build_info()` says which encoder is in use.
 """
 
 from __future__ import annotations
 
+import hashlib
 import importlib.util
 import os
 import subprocess
 import sys
 import sysconfig
-import threading
+import time
 from snappydata_tpu.utils import locks
 from typing import Optional, Tuple
 
@@ -26,29 +30,35 @@ _BUILD_DIR = os.path.join(_REPO_ROOT, "native", "build")
 _lock = locks.named_lock("native.loader")
 _native = None
 _tried = False
+_info = {"available": False, "so_path": None, "build_s": None,
+         "error": None}
 
 
-def _build() -> Optional[str]:
-    os.makedirs(_BUILD_DIR, exist_ok=True)
-    so_path = os.path.join(
-        _BUILD_DIR,
-        f"_fastingest.cpython-{sys.version_info.major}"
-        f"{sys.version_info.minor}.so")
-    if os.path.exists(so_path) and \
-            os.path.getmtime(so_path) >= os.path.getmtime(_SRC):
-        return so_path
-    cc = os.environ.get("CXX", "g++")
+def _build() -> str:
+    """Path of the artefact for this source + compile line, compiling it
+    when absent. Raises on a failed build."""
     cmd = [
-        cc, "-O3", "-shared", "-fPIC", "-std=c++17",
+        os.environ.get("CXX", "g++"), "-O3", "-shared", "-fPIC",
+        "-std=c++17",
         f"-I{sysconfig.get_paths()['include']}",
         f"-I{np.get_include()}",
-        _SRC, "-o", so_path,
+        _SRC,
     ]
+    with open(_SRC, "rb") as fh:
+        key = hashlib.sha256(fh.read() + "\0".join(cmd).encode()).hexdigest()
+    so_path = os.path.join(_BUILD_DIR, f"_fastingest-{key[:16]}.so")
+    if os.path.exists(so_path):
+        return so_path
+    os.makedirs(_BUILD_DIR, exist_ok=True)
+    tmp = f"{so_path}.{os.getpid()}.tmp"
+    t0 = time.time()
     try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-    except (subprocess.CalledProcessError, FileNotFoundError,
-            subprocess.TimeoutExpired):
-        return None
+        subprocess.run(cmd + ["-o", tmp], check=True, capture_output=True,
+                       timeout=120)
+    except subprocess.CalledProcessError as e:
+        raise RuntimeError(e.stderr.decode(errors="replace")[-2000:]) from e
+    os.replace(tmp, so_path)   # atomic: a concurrent loader never sees half
+    _info["build_s"] = round(time.time() - t0, 2)
     return so_path
 
 
@@ -58,20 +68,29 @@ def _load():
         if _tried:
             return _native
         _tried = True
-        if not os.path.exists(_SRC):
-            return None
-        so_path = _build()
-        if so_path is None:
-            return None
         try:
+            so_path = _build()
             spec = importlib.util.spec_from_file_location("_fastingest",
                                                           so_path)
             mod = importlib.util.module_from_spec(spec)
             spec.loader.exec_module(mod)
-            _native = mod
-        except Exception:
-            _native = None
+        except (OSError, RuntimeError, ImportError,
+                subprocess.TimeoutExpired) as e:
+            _info["error"] = f"{type(e).__name__}: {e}"
+            print("snappydata_tpu.native: no native encoder, string ingest "
+                  f"uses the slower pandas path ({_info['error']})",
+                  file=sys.stderr)
+            return None
+        _native = mod
+        _info.update(available=True, so_path=so_path)
         return _native
+
+
+def build_info() -> dict:
+    """{available, so_path, build_s (None when the artefact was already
+    there), error} after the one load attempt."""
+    _load()
+    return dict(_info)
 
 
 def native_available() -> bool:

@@ -24,9 +24,9 @@ exact) and combines in int64; MIN/MAX keep plain masked partials with
 the same +/-inf fillers as the packed families, so empty groups match
 the unrolled path bit-for-bit.
 
-Gated behind `properties.pallas_group_reduce` (default OFF until
-measured on hardware — bench.py records the side-by-side `q1_pallas_s`
-when a TPU is reachable).  Eligibility mirrors the global kernel: f32
+Gated behind `properties.pallas_group_reduce` (default OFF: it compiles
+and matches on the v5e, chip_smoke.py checks that; its rate is not
+measured).  Eligibility mirrors the global kernel: f32
 value plates only (the TPU storage contract already stores DOUBLE as
 f32 plates), dictionary/bool fast-path group indexes with
 G <= MAX_GROUPS, and the documented compensated-summation caveat
@@ -50,9 +50,10 @@ import jax
 import jax.numpy as jnp
 
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-_LANES = 128
-_SUBLANES = 8
+from snappydata_tpu.ops.pallas_reduce import (_CODE_STEP, _LANES, _SUBLANES,
+                                              interpret_default)
 
 # rows per grid step. Smaller than pallas_reduce's 2048: the per-group
 # carries cost ops * [G, 8, 128] f32 VMEM (G=64, 4 sums + count ->
@@ -139,7 +140,8 @@ def _make_kernel(spec: Tuple[Tuple[str, Optional[int], int], ...],
         garange = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
 
         def body(i, carry):
-            sl = pl.ds(i * _SUBLANES, _SUBLANES)
+            sl = pl.ds(pl.multiple_of(i * _SUBLANES, _SUBLANES),
+                       _SUBLANES)
             gblk = gidx_ref[sl, :]
             gm = gblk[None].astype(jnp.int32) == garange  # [G, 8, 128]
             # one VMEM load + one group-select per UNIQUE input block
@@ -204,16 +206,17 @@ def _grouped_call(gidx2d, ins,
     out_blk = pl.BlockSpec((G, _SUBLANES, _LANES), lambda i: (0, 0, 0))
     kinds = tuple(k for k, _, _ in spec)
     n_out = sum(_outs_of(k) for k in kinds)
-    outs = pl.pallas_call(
-        _make_kernel(spec, len(ins), G),
-        grid=(nblocks,),
-        in_specs=[blk] * (1 + len(ins)),
-        out_specs=(out_blk,) * n_out,
-        out_shape=tuple(
-            jax.ShapeDtypeStruct((G, _SUBLANES, _LANES), jnp.float32)
-            for _ in range(n_out)),
-        interpret=interpret,
-    )(gidx2d, *ins)
+    with jax.enable_x64(False):   # see pallas_reduce._kahan_call
+        outs = pl.pallas_call(
+            _make_kernel(spec, len(ins), G),
+            grid=(nblocks,),
+            in_specs=[blk] * (1 + len(ins)),
+            out_specs=(out_blk,) * n_out,
+            out_shape=tuple(
+                jax.ShapeDtypeStruct((G, _SUBLANES, _LANES), jnp.float32)
+                for _ in range(n_out)),
+            interpret=interpret,
+        )(gidx2d, *ins)
 
     results = []
     oi = 0
@@ -255,7 +258,7 @@ def grouped_reduce(ops: Sequence[Tuple[str, Optional[jnp.ndarray],
     kinds = tuple(k for k, _, _ in ops)
     assert all(k in _KINDS for k in kinds), kinds
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_default()
 
     n = gidx.reshape(-1).shape[0]
     tile = _BLOCK_ROWS * _LANES
@@ -295,11 +298,6 @@ def grouped_reduce(ops: Sequence[Tuple[str, Optional[jnp.ndarray],
     return list(outs)
 
 
-def pallas_group_available() -> bool:
-    """True when the TPU lowering path is usable on this backend."""
-    return jax.default_backend() == "tpu"
-
-
 # ==========================================================================
 # Fused decode+filter+grouped-aggregate: the TPC-H Q1 shape over ENCODED
 # batches.  Value inputs arrive as VALUE_DICT code plates plus per-batch
@@ -314,7 +312,7 @@ def pallas_group_available() -> bool:
 # generic engine keeps per-slot null masks.
 # ==========================================================================
 
-_CBLOCK_ROWS = 512   # multiple of 32 (small-int tiles) and 8 (f32)
+_CBLOCK_ROWS = 512   # multiple of _CODE_STEP
 
 
 @functools.lru_cache(maxsize=32)
@@ -322,7 +320,7 @@ def _make_code_kernel(spec: Tuple, n_vmem: int, n_dict: int, G: int):
     """spec: per slot ("count",) or ("sum", plain_idx_or_None,
     ((code_vmem_idx, dict_idx), ...)) — VMEM indices point into the
     [gidx, mask, *values] block list, dict indices into the SMEM list."""
-    steps = _CBLOCK_ROWS // _SUBLANES
+    steps = _CBLOCK_ROWS // _CODE_STEP
 
     def kernel(*refs):
         gidx_ref = refs[0]
@@ -342,39 +340,48 @@ def _make_code_kernel(spec: Tuple, n_vmem: int, n_dict: int, G: int):
         garange = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
 
         def body(i, carry):
-            sl = pl.ds(i * _SUBLANES, _SUBLANES)
-            gblk = gidx_ref[0, sl, :]
+            # code plates load one small-int tile (32 rows) at a time
+            # and fold into the [G, 8, 128] chains as four sub-steps
+            sl = pl.ds(pl.multiple_of(i * _CODE_STEP, _CODE_STEP),
+                       _CODE_STEP)
+            gblk = gidx_ref[0, sl, :].astype(jnp.int32)
             mblk = mask_ref[0, sl, :]
-            sel = (gblk[None].astype(jnp.int32) == garange) & mblk[None]
-            new = []
-            oi = 0
+            vals = []
             for op in spec:
                 if op[0] == "count":
-                    new.append(carry[oi]
-                               + jnp.where(sel, 1.0, 0.0))
-                    oi += 1
+                    vals.append(None)
                     continue
                 _, plain_idx, factors = op
                 v = vmem[plain_idx][0, sl, :] if plain_idx is not None \
-                    else jnp.ones((_SUBLANES, _LANES), jnp.float32)
+                    else jnp.ones((_CODE_STEP, _LANES), jnp.float32)
                 for cvi, dvi in factors:
                     codes = vmem[cvi][0, sl, :].astype(jnp.int32)
                     dref = dicts[dvi]
-                    dval = jnp.zeros((_SUBLANES, _LANES), jnp.float32)
+                    dval = jnp.zeros((_CODE_STEP, _LANES), jnp.float32)
 
                     def dec(k, acc, _c=codes, _d=dref):
-                        return jnp.where(_c == k, _d[0, k], acc)
+                        return jnp.where(_c == k, _d[0, 0, k], acc)
 
-                    dval = jax.lax.fori_loop(0, dref.shape[1], dec, dval)
+                    dval = jax.lax.fori_loop(0, dref.shape[2], dec, dval)
                     v = v * dval
-                sm, cp = carry[oi], carry[oi + 1]
-                vv = jnp.where(sel, v[None], 0.0)
-                y = vv - cp
-                t = sm + y
-                new.append(t)
-                new.append((t - sm) - y)
-                oi += 2
-            return tuple(new)
+                vals.append(v)
+            carry = list(carry)
+            for r in range(0, _CODE_STEP, _SUBLANES):
+                rows = slice(r, r + _SUBLANES)
+                sel = (gblk[rows][None] == garange) & mblk[rows][None]
+                oi = 0
+                for v in vals:
+                    if v is None:
+                        carry[oi] = carry[oi] + jnp.where(sel, 1.0, 0.0)
+                        oi += 1
+                        continue
+                    sm, cp = carry[oi], carry[oi + 1]
+                    y = jnp.where(sel, v[rows][None], 0.0) - cp
+                    t = sm + y
+                    carry[oi] = t
+                    carry[oi + 1] = (t - sm) - y
+                    oi += 2
+            return tuple(carry)
 
         final = jax.lax.fori_loop(0, steps, body,
                                   tuple(r[...] for r in out_refs))
@@ -390,23 +397,26 @@ def _grouped_code_call(vmem_ins, dict_ins, spec, G: int, dshapes,
                        interpret: bool):
     B, capr, _ = vmem_ins[0].shape
     S = capr // _CBLOCK_ROWS
-    from jax.experimental.pallas import tpu as pltpu
-
-    blk = pl.BlockSpec((1, _CBLOCK_ROWS, _LANES), lambda b, s: (b, s, 0))
-    out_blk = pl.BlockSpec((G, _SUBLANES, _LANES), lambda b, s: (0, 0, 0))
     n_out = sum(1 if op[0] == "count" else 2 for op in spec)
-    outs = pl.pallas_call(
-        _make_code_kernel(spec, len(vmem_ins), len(dict_ins), G),
-        grid=(B, S),
-        in_specs=[blk] * len(vmem_ins) + [
-            pl.BlockSpec((1, d), lambda b, s: (b, 0),
-                         memory_space=pltpu.SMEM) for d in dshapes],
-        out_specs=(out_blk,) * n_out,
-        out_shape=tuple(
-            jax.ShapeDtypeStruct((G, _SUBLANES, _LANES), jnp.float32)
-            for _ in range(n_out)),
-        interpret=interpret,
-    )(*vmem_ins, *dict_ins)
+    with jax.enable_x64(False):   # see pallas_reduce._kahan_call
+        blk = pl.BlockSpec((1, _CBLOCK_ROWS, _LANES),
+                           lambda b, s: (b, s, 0))
+        out_blk = pl.BlockSpec((G, _SUBLANES, _LANES),
+                               lambda b, s: (0, 0, 0))
+        outs = pl.pallas_call(
+            _make_code_kernel(spec, len(vmem_ins), len(dict_ins), G),
+            grid=(B, S),
+            # dictionaries ride SMEM as [B, 1, D]: one batch's block
+            # spans the array's last two dimensions whole
+            in_specs=[blk] * len(vmem_ins) + [
+                pl.BlockSpec((1, 1, d), lambda b, s: (b, 0, 0),
+                             memory_space=pltpu.SMEM) for d in dshapes],
+            out_specs=(out_blk,) * n_out,
+            out_shape=tuple(
+                jax.ShapeDtypeStruct((G, _SUBLANES, _LANES), jnp.float32)
+                for _ in range(n_out)),
+            interpret=interpret,
+        )(*vmem_ins, *dict_ins)
     results = []
     oi = 0
     for op in spec:
@@ -436,7 +446,7 @@ def grouped_code_reduce(gidx, mask, slots, num_segments: int,
     float64 for sums."""
     assert 1 <= num_segments <= MAX_GROUPS, num_segments
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_default()
     gidx = jnp.asarray(gidx)
     B, cap = gidx.shape
     capr = cap // _LANES
@@ -466,10 +476,11 @@ def grouped_code_reduce(gidx, mask, slots, num_segments: int,
             cvi = len(vmem)
             vmem.append(shape3(codes, jnp.asarray(codes).dtype))
             dvi = len(dict_ins)
-            dict_ins.append(jnp.asarray(dicts, dtype=jnp.float32))
+            dict_ins.append(
+                jnp.asarray(dicts, dtype=jnp.float32)[:, None, :])
             fs.append((cvi, dvi))
         spec.append(("sum", pi, tuple(fs)))
-    dshapes = tuple(int(d.shape[1]) for d in dict_ins)
+    dshapes = tuple(int(d.shape[2]) for d in dict_ins)
     return list(_grouped_code_call(tuple(vmem), tuple(dict_ins),
                                    tuple(spec), int(num_segments),
                                    dshapes, bool(interpret)))

@@ -12,9 +12,9 @@ the kernel. Accuracy matches the f64 path to <=1e-6 relative while the
 hot loop stays entirely in native f32 vector ops.
 
 Used for global (ungrouped) SUM/AVG over float32 plates — the TPC-H
-Q6 shape — behind `properties.pallas_reduce` (**default OFF** until
-measured on hardware; bench.py records the side-by-side timing when a
-TPU is reachable). Scope caveats the gate enforces and the docs own:
+Q6 shape — behind `properties.pallas_reduce` (**default OFF**: it
+compiles and matches on the v5e, chip_smoke.py checks that; its rate
+is not measured). Scope caveats the gate enforces and the docs own:
 only float32 inputs qualify (an f64 input would be truncated — the TPU
 storage contract already stores DOUBLE as f32 plates, so on TPU this
 loses nothing), and compensated summation bounds error relative to
@@ -33,10 +33,18 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _LANES = 128
 _SUBLANES = 8
+
+
+def interpret_default() -> bool:
+    """Interpreter only on the CPU backend (the tests' backend, which has
+    no Mosaic lowering); any accelerator process compiles the kernel or
+    fails loudly."""
+    return jax.default_backend() == "cpu"
 
 
 # rows per grid step: 2048x128 f32 block = 1MB data + 256KB mask in
@@ -54,8 +62,9 @@ def _kahan_kernel(x_ref, m_ref, sum_ref, comp_ref):
 
     def body(i, carry):
         s, c = carry
-        blk = x_ref[pl.ds(i * _SUBLANES, _SUBLANES), :]
-        msk = m_ref[pl.ds(i * _SUBLANES, _SUBLANES), :]
+        sl = pl.ds(pl.multiple_of(i * _SUBLANES, _SUBLANES), _SUBLANES)
+        blk = x_ref[sl, :]
+        msk = m_ref[sl, :]
         v = jnp.where(msk, blk, 0.0)
         # Kahan: y = v - c; t = s + y; c = (t - s) - y; s = t
         y = v - c
@@ -69,37 +78,33 @@ def _kahan_kernel(x_ref, m_ref, sum_ref, comp_ref):
     comp_ref[:, :, :] = c[None]
 
 
-try:  # pallas import is cheap; actual lowering happens at first call
-    from jax.experimental import pallas as pl
-    _PALLAS = True
-except ImportError:  # pragma: no cover - pallas always ships with jax
-    _PALLAS = False
-
-
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _kahan_call(x2d: jnp.ndarray, mask2d: jnp.ndarray,
                 interpret: bool = False):
     rows = x2d.shape[0]
     nblocks = rows // _BLOCK_ROWS
-    sums, comps = pl.pallas_call(
-        _kahan_kernel,
-        grid=(nblocks,),
-        in_specs=[
-            pl.BlockSpec((_BLOCK_ROWS, _LANES), lambda i: (i, 0)),
-            pl.BlockSpec((_BLOCK_ROWS, _LANES), lambda i: (i, 0)),
-        ],
-        out_specs=(
-            pl.BlockSpec((1, _SUBLANES, _LANES), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, _SUBLANES, _LANES), lambda i: (i, 0, 0)),
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct((nblocks, _SUBLANES, _LANES),
-                                 jnp.float32),
-            jax.ShapeDtypeStruct((nblocks, _SUBLANES, _LANES),
-                                 jnp.float32),
-        ),
-        interpret=interpret,
-    )(x2d, mask2d)
+    # Mosaic has no 64-bit types and the package runs with x64 on: trace
+    # the kernel (loop indices, index maps, literals) in 32-bit mode
+    with jax.enable_x64(False):
+        sums, comps = pl.pallas_call(
+            _kahan_kernel,
+            grid=(nblocks,),
+            in_specs=[
+                pl.BlockSpec((_BLOCK_ROWS, _LANES), lambda i: (i, 0)),
+                pl.BlockSpec((_BLOCK_ROWS, _LANES), lambda i: (i, 0)),
+            ],
+            out_specs=(
+                pl.BlockSpec((1, _SUBLANES, _LANES), lambda i: (i, 0, 0)),
+                pl.BlockSpec((1, _SUBLANES, _LANES), lambda i: (i, 0, 0)),
+            ),
+            out_shape=(
+                jax.ShapeDtypeStruct((nblocks, _SUBLANES, _LANES),
+                                     jnp.float32),
+                jax.ShapeDtypeStruct((nblocks, _SUBLANES, _LANES),
+                                     jnp.float32),
+            ),
+            interpret=interpret,
+        )(x2d, mask2d)
     # exact f64 combine of the small per-block partials. Kahan's
     # c = (t - s) - y holds the EXCESS already folded into s, so the
     # true chain total is s - c (review finding: + doubled the residual
@@ -114,12 +119,9 @@ def masked_kahan_sum(values: jnp.ndarray, mask: jnp.ndarray,
 
     `values`: any-shape f32/f64 array; `mask`: same-shape bool. The
     flattened data pads to a [rows, 128] layout with rows a multiple of
-    8 (TPU native tiling). `interpret=None` auto-selects: compiled on
-    TPU, interpreter elsewhere (CPU has no Mosaic lowering)."""
-    if not _PALLAS:   # degrade gracefully: plain f64 reduction
-        return jnp.sum(jnp.where(mask, values, 0).astype(jnp.float64))
+    8 (TPU native tiling). `interpret=None` is interpret_default()."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_default()
     flat = values.reshape(-1).astype(jnp.float32)
     m = mask.reshape(-1)
     n = flat.shape[0]
@@ -131,13 +133,6 @@ def masked_kahan_sum(values: jnp.ndarray, mask: jnp.ndarray,
     x2d = flat.reshape(-1, _LANES)
     m2d = m.reshape(-1, _LANES)
     return _kahan_call(x2d, m2d, interpret=interpret)
-
-
-def pallas_reduce_available() -> bool:
-    """True when the TPU lowering path is usable on this backend."""
-    if not _PALLAS:
-        return False
-    return jax.default_backend() == "tpu"
 
 
 # ==========================================================================
@@ -158,12 +153,15 @@ def pallas_reduce_available() -> bool:
 # below 2^24 per lane) and combines in int64 outside.
 #
 # CPU runs use the interpreter (correctness + the opt-in
-# SNAPPY_BENCH_PALLAS=1 bench lane); the real Mosaic lowering engages on
-# TPU.  Codes load as uint8/uint16 and widen in-register — block rows are
-# a multiple of 32 to satisfy the small-int tile shape.
+# SNAPPY_BENCH_PALLAS=1 bench lane); the Mosaic lowering engages on TPU.
+# Codes load as uint8/uint16 and widen in-register.
 # ==========================================================================
 
-_FBLOCK_ROWS = 512   # multiple of 32 (int8 tiling) and of 8 (f32 tiling)
+_FBLOCK_ROWS = 512   # multiple of _CODE_STEP
+# rows per inner-loop load: the native tile of a one-byte plate is
+# (32, 128) (two-byte: (16, 128)), so code plates load 32 rows at a time
+# and fold into the [8, 128] Kahan chains as four f32-tile sub-steps
+_CODE_STEP = 32
 
 
 def _fused_q6_kernel(qty_ref, disc_ref, ship_ref, price_ref, valid_ref,
@@ -179,17 +177,17 @@ def _fused_q6_kernel(qty_ref, disc_ref, ship_ref, price_ref, valid_ref,
         comp_ref[...] = zero
         cnt_ref[...] = zero
 
-    steps = _FBLOCK_ROWS // _SUBLANES
-    d_pad = dict_ref.shape[1]
-    qhi = qhi_ref[0, 0]
-    dlo = dlo_ref[0, 0]
-    dhi = dhi_ref[0, 0]
+    steps = _FBLOCK_ROWS // _CODE_STEP
+    d_pad = dict_ref.shape[2]
+    qhi = qhi_ref[0, 0, 0]
+    dlo = dlo_ref[0, 0, 0]
+    dhi = dhi_ref[0, 0, 0]
     slo = slo_ref[0, 0]
     shi = shi_ref[0, 0]
 
     def body(i, carry):
         sm, cp, ct = carry
-        sl = pl.ds(i * _SUBLANES, _SUBLANES)
+        sl = pl.ds(pl.multiple_of(i * _CODE_STEP, _CODE_STEP), _CODE_STEP)
         q = qty_ref[0, sl, :].astype(jnp.int32)
         d = disc_ref[0, sl, :].astype(jnp.int32)
         sh = ship_ref[0, sl, :]
@@ -203,13 +201,18 @@ def _fused_q6_kernel(qty_ref, disc_ref, ship_ref, price_ref, valid_ref,
         dval = jnp.zeros_like(pz)
 
         def dec(k, acc):
-            return jnp.where(d == k, dict_ref[0, k], acc)
+            return jnp.where(d == k, dict_ref[0, 0, k], acc)
 
         dval = jax.lax.fori_loop(0, d_pad, dec, dval)
         v = jnp.where(ok, pz * dval, 0.0)
-        y = v - cp
-        t = sm + y
-        return t, (t - sm) - y, ct + jnp.where(ok, 1.0, 0.0)
+        one = jnp.where(ok, 1.0, 0.0)
+        for r in range(0, _CODE_STEP, _SUBLANES):
+            y = v[r:r + _SUBLANES] - cp
+            t = sm + y
+            cp = (t - sm) - y
+            sm = t
+            ct = ct + one[r:r + _SUBLANES]
+        return sm, cp, ct
 
     carry0 = (sum_ref[...], comp_ref[...], cnt_ref[...])
     sm, cp, ct = jax.lax.fori_loop(0, steps, body, carry0)
@@ -223,29 +226,29 @@ def _fused_q6_call(qty, disc, ship, price, valid, dicts,
                    qhi, dlo, dhi, slo, shi, interpret: bool = False):
     B, capr, _ = price.shape
     S = capr // _FBLOCK_ROWS
-    blk = pl.BlockSpec((1, _FBLOCK_ROWS, _LANES), lambda b, s: (b, s, 0))
-    from jax.experimental.pallas import tpu as pltpu
-
-    smem_dict = pl.BlockSpec((1, dicts.shape[1]), lambda b, s: (b, 0),
-                             memory_space=pltpu.SMEM)
-    smem_b = pl.BlockSpec((1, 1), lambda b, s: (b, 0),
-                          memory_space=pltpu.SMEM)
-    smem_g = pl.BlockSpec((1, 1), lambda b, s: (0, 0),
-                          memory_space=pltpu.SMEM)
-    out_blk = pl.BlockSpec((_SUBLANES, _LANES), lambda b, s: (0, 0))
-    sums, comps, cnts = pl.pallas_call(
-        _fused_q6_kernel,
-        grid=(B, S),
-        in_specs=[blk, blk, blk, blk, blk, smem_dict,
-                  smem_b, smem_b, smem_b, smem_g, smem_g],
-        out_specs=(out_blk, out_blk, out_blk),
-        out_shape=(
-            jax.ShapeDtypeStruct((_SUBLANES, _LANES), jnp.float32),
-            jax.ShapeDtypeStruct((_SUBLANES, _LANES), jnp.float32),
-            jax.ShapeDtypeStruct((_SUBLANES, _LANES), jnp.float32),
-        ),
-        interpret=interpret,
-    )(qty, disc, ship, price, valid, dicts, qhi, dlo, dhi, slo, shi)
+    out_sds = jax.ShapeDtypeStruct((_SUBLANES, _LANES), jnp.float32)
+    with jax.enable_x64(False):   # see _kahan_call
+        blk = pl.BlockSpec((1, _FBLOCK_ROWS, _LANES),
+                           lambda b, s: (b, s, 0))
+        # per-batch scalars ride SMEM as [B, 1, n] so one batch's block
+        # spans the array's last two dimensions whole
+        smem_dict = pl.BlockSpec((1, 1, dicts.shape[2]),
+                                 lambda b, s: (b, 0, 0),
+                                 memory_space=pltpu.SMEM)
+        smem_b = pl.BlockSpec((1, 1, 1), lambda b, s: (b, 0, 0),
+                              memory_space=pltpu.SMEM)
+        smem_g = pl.BlockSpec((1, 1), lambda b, s: (0, 0),
+                              memory_space=pltpu.SMEM)
+        out_blk = pl.BlockSpec((_SUBLANES, _LANES), lambda b, s: (0, 0))
+        sums, comps, cnts = pl.pallas_call(
+            _fused_q6_kernel,
+            grid=(B, S),
+            in_specs=[blk, blk, blk, blk, blk, smem_dict,
+                      smem_b, smem_b, smem_b, smem_g, smem_g],
+            out_specs=(out_blk, out_blk, out_blk),
+            out_shape=(out_sds, out_sds, out_sds),
+            interpret=interpret,
+        )(qty, disc, ship, price, valid, dicts, qhi, dlo, dhi, slo, shi)
     total = (jnp.sum(sums.astype(jnp.float64))
              - jnp.sum(comps.astype(jnp.float64)))
     count = jnp.sum(cnts.astype(jnp.int64))
@@ -271,7 +274,7 @@ def fused_code_filter_sum(qty_codes, disc_codes, ship, price, valid,
     "translate the literal once" contract; a miss yields a threshold
     that matches nothing).  Returns (float64 sum, int64 count)."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_default()
     B, cap = price.shape
     capr = cap // _LANES
     pad_r = ((capr + _FBLOCK_ROWS - 1) // _FBLOCK_ROWS) * _FBLOCK_ROWS
@@ -290,11 +293,11 @@ def fused_code_filter_sum(qty_codes, disc_codes, ship, price, valid,
     vd = shape3(valid, jnp.bool_)
 
     def col_b(a):
-        return jnp.asarray(a, dtype=jnp.int32).reshape(B, 1)
+        return jnp.asarray(a, dtype=jnp.int32).reshape(B, 1, 1)
 
     return _fused_q6_call(
         qty, disc, sh, pz, vd,
-        jnp.asarray(disc_dicts, dtype=jnp.float32),
+        jnp.asarray(disc_dicts, dtype=jnp.float32)[:, None, :],
         col_b(qty_hi_codes), col_b(disc_lo_codes), col_b(disc_hi_codes),
         jnp.asarray([[int(ship_lo)]], dtype=jnp.int32),
         jnp.asarray([[int(ship_hi)]], dtype=jnp.int32),

@@ -1194,16 +1194,19 @@ class SnappySession:
         b = self.conf.scan_tile_bytes
         if b != 0:
             return max(0, b)
-        try:
-            import jax
+        import jax
 
-            stats = jax.local_devices()[0].memory_stats()
-            limit = (stats or {}).get("bytes_limit")
-            if limit:
-                return int(limit) // 2
-        except Exception:
-            pass
-        return 0  # unknown memory (e.g. CPU): tiling off unless explicit
+        dev = jax.local_devices()[0]
+        limit = (dev.memory_stats() or {}).get("bytes_limit")
+        if limit:
+            return int(limit) // 2
+        if dev.platform != "cpu":
+            # an accelerator that cannot say how much memory it has would
+            # turn "table ≫ HBM" into an allocation failure mid-query
+            raise RuntimeError(
+                f"{dev.platform} device reports no bytes_limit in "
+                f"memory_stats(); set scan_tile_bytes explicitly")
+        return 0  # the CPU backend reports none: tiling off unless explicit
 
     def _tilable_agg_shape(self, plan: ast.Plan):
         """Shared shape probe for the tile pass and the governor's

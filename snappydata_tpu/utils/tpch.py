@@ -268,10 +268,12 @@ ORDER BY o_orderpriority"""
 
 
 def load_tpch(session, sf: float = 0.001, seed: int = 0,
-              all_tables: bool = False) -> None:
+              all_tables: bool = False) -> Dict[str, Dict[str, np.ndarray]]:
     """Create + populate the TPC-H tables at the given scale factor.
     Default: the three headline-benchmark tables; all_tables adds
-    supplier/part/nation/region for the wider query set."""
+    supplier/part/nation/region for the wider query set. Returns the
+    generated lineitem/orders/customer arrays by table name, exactly as
+    inserted, for a caller that builds an oracle from them."""
     n_l = max(1000, int(LINEITEM_ROWS_PER_SF * sf))
     n_o = max(250, int(ORDERS_ROWS_PER_SF * sf))
     n_c = max(25, int(CUSTOMER_ROWS_PER_SF * sf))
@@ -284,10 +286,11 @@ def load_tpch(session, sf: float = 0.001, seed: int = 0,
     li["l_orderkey"] = np.minimum(li["l_orderkey"], n_o)  # FK into orders
     li["l_suppkey"] = (li["l_suppkey"] % n_s) + 1
     li["l_partkey"] = (li["l_partkey"] % n_p) + 1
+    orders = gen_orders(n_o, n_c, seed + 1)
+    customer = gen_customer(n_c, seed + 2)
     session.insert_arrays("lineitem", list(li.values()))
-    session.insert_arrays("orders",
-                          list(gen_orders(n_o, n_c, seed + 1).values()))
-    session.insert_arrays("customer", list(gen_customer(n_c, seed + 2).values()))
+    session.insert_arrays("orders", list(orders.values()))
+    session.insert_arrays("customer", list(customer.values()))
     if all_tables:
         session.sql(SUPPLIER_DDL)
         session.sql(PART_DDL)
@@ -301,6 +304,7 @@ def load_tpch(session, sf: float = 0.001, seed: int = 0,
             "partsupp", list(gen_partsupp(n_p, n_s, seed + 6).values()))
         session.insert_arrays("nation", list(gen_nation().values()))
         session.insert_arrays("region", list(gen_region().values()))
+    return {"lineitem": li, "orders": orders, "customer": customer}
 
 
 Q4 = """SELECT o_orderpriority, count(*) AS order_count

@@ -552,18 +552,21 @@ def test_bench_check_guard_logic():
         {"value": None, "detail": {}}, base) == []
 
 
-def test_bench_check_catches_the_recorded_r05_slide():
-    """The guard, applied to the repo's own historical records, trips on
-    exactly the regression ROADMAP item 1 documents (r04→r05 load_s
-    30.6→119.8) and passes the in-tolerance geomean wobble."""
-    import os
+# the two historical driver records the guard was built on (CPU runs;
+# shape as the driver wrote them: the bench's own line under "parsed")
+_R04 = {"n": 4, "rc": 0, "parsed": {
+    "value": 17299257.0, "detail": {"platform": "cpu", "load_s": 30.6}}}
+_R05 = {"n": 5, "rc": 0, "parsed": {
+    "value": 15133431.4, "detail": {"platform": "cpu", "load_s": 119.79}}}
 
+
+def test_bench_check_catches_the_recorded_r05_slide():
+    """The guard trips on exactly the regression the r04→r05 records
+    carried (load_s 30.6→119.8) and passes the in-tolerance geomean
+    wobble."""
     import bench
 
-    root = os.path.dirname(os.path.abspath(bench.__file__))
-    r04 = json.load(open(os.path.join(root, "BENCH_r04.json")))
-    r05 = json.load(open(os.path.join(root, "BENCH_r05.json")))
-    fails = bench.check_regression(r05, r04)
+    fails = bench.check_regression(_R05, _R04)
     assert any("load_s" in f for f in fails)
     assert not any("geomean" in f for f in fails), \
         "the -12.7% geomean wobble is within the noise tolerance"
@@ -792,18 +795,21 @@ def test_create_failure_rolls_back_registration():
     s.stop()
 
 
-def test_bench_check_candidate_is_newest_record():
+def test_bench_check_candidate_is_newest_record(tmp_path, monkeypatch):
     """--check <newest BENCH_r*.json> must compare against its
     PREDECESSOR, not against itself (always-pass)."""
-    import os
-
     import bench
 
-    root = os.path.dirname(os.path.abspath(bench.__file__))
-    records = bench._bench_records(root)
+    for rec in (_R04, _R05):
+        with open(tmp_path / f"BENCH_r{rec['n']:02d}.json", "w") as fh:
+            json.dump(rec, fh)
+    real_records = bench._bench_records
+    monkeypatch.setattr(bench, "_bench_records",
+                        lambda _root: real_records(str(tmp_path)))
+    records = bench._bench_records(None)
     # r05 carries the recorded load_s regression vs r04: checking it by
     # path (as CI would check a just-written record) must compare it
     # against r04 and trip — a self-compare would always pass
-    r05 = os.path.join(root, "BENCH_r05.json")
+    r05 = str(tmp_path / "BENCH_r05.json")
     assert r05 in records
     assert bench.run_check([r05]) == 1
