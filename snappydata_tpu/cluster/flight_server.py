@@ -670,60 +670,76 @@ class SnappyFlightServer(flight.FlightServerBase):
             reader.read_all()
             global_registry().inc("mutation_dedup_hits")
             return
+        from snappydata_tpu.observability import tracing
+
+        # the server's trace opens BEFORE the stream is read: receiving
+        # and converting the Arrow stream is the put's first phase (span
+        # `decode`), then `wal_append` / `apply` / `wal_sync` from
+        # session._journal_then — together they cover the put's root
         try:
-            table = reader.read_all()
-            arrays, nulls = arrow_to_arrays(table)
-            info = self.session.catalog.describe(target)
-            # same gate as every session write lane: acked rows put into
-            # a view's backing table would vanish at the view's next sync
-            self.session._reject_matview_write(info)
-            from snappydata_tpu.storage.table_store import RowTableData
-
-            # WAL-then-apply under the store's mutation lock (same
-            # invariant as session mutations: journal first so a
-            # concurrent checkpoint can't fold un-journaled rows, and
-            # carry null masks so recovery doesn't turn bulk-ingested
-            # NULLs into zeros). stmt_scope threads the client's
-            # statement id into the WAL header — recovery replay re-seeds
-            # the dedup window from it, so a retry racing a server
-            # RESTART still dedups.
-            # sync_force: the put RESPONSE is a durability ack the lead's
-            # fan-out (and its replica bookkeeping) relies on — the
-            # covering WAL fsync is forced even when this server runs
-            # wal_fsync_mode=interval. Relaxed acks are a local-session
-            # policy, never a network one. Scoped to THIS put's record so
-            # one client's ack never waits on other sessions' records.
-            from snappydata_tpu.observability import tracing
-
             with tracing.request_scope(
                     f"<put {target}>", user=sess.user, kind="server",
                     trace_id=(body or {}).get("trace_id"),
-                    origin=self._origin()), \
-                    reliability.stmt_scope(stmt_id):
-                if isinstance(info.data, RowTableData):
-                    from snappydata_tpu.session import _restore_none_arrays
-
-                    raw = _restore_none_arrays(arrays, nulls)
-                    n = self.session._journal_then(
-                        info, "insert", raw, None,
-                        lambda: self.session._fold_views(
-                            info, raw, None, info.data.insert_arrays(raw)),
-                        sync_force=True)
-                else:
-                    nmask = nulls if any(m is not None for m in nulls) \
-                        else None
-                    n = self.session._journal_then(
-                        info, "insert", arrays, nmask,
-                        lambda: self.session._fold_views(
-                            info, arrays, nmask,
-                            info.data.insert_arrays(arrays, nulls=nmask)),
-                        sync_force=True)
+                    origin=self._origin()):
+                n = self._put_rows(target, stmt_id, reader)
         except BaseException:
             if dedup is not None:
                 dedup.abort(stmt_id)   # nothing applied: a retry may run
             raise
         if dedup is not None:
-            dedup.commit(stmt_id, {"rows": [[int(n or 0)]]})
+            dedup.commit(stmt_id, {"rows": [[n]]})
+
+    def _put_rows(self, target: str, stmt_id, reader) -> int:
+        """Read the put's Arrow stream and insert its rows durably;
+        returns the row count.  Runs inside do_put's server trace."""
+        from snappydata_tpu import reliability
+        from snappydata_tpu.observability import tracing
+
+        with tracing.span("decode") as sp:
+            table = reader.read_all()
+            arrays, nulls = arrow_to_arrays(table)
+            sp.set("rows", int(table.num_rows))
+            sp.set("bytes", int(table.nbytes))
+        info = self.session.catalog.describe(target)
+        # same gate as every session write lane: acked rows put into
+        # a view's backing table would vanish at the view's next sync
+        self.session._reject_matview_write(info)
+        from snappydata_tpu.storage.table_store import RowTableData
+
+        # WAL-then-apply under the store's mutation lock (same
+        # invariant as session mutations: journal first so a
+        # concurrent checkpoint can't fold un-journaled rows, and
+        # carry null masks so recovery doesn't turn bulk-ingested
+        # NULLs into zeros). stmt_scope threads the client's
+        # statement id into the WAL header — recovery replay re-seeds
+        # the dedup window from it, so a retry racing a server
+        # RESTART still dedups.
+        # sync_force: the put RESPONSE is a durability ack the lead's
+        # fan-out (and its replica bookkeeping) relies on — the
+        # covering WAL fsync is forced even when this server runs
+        # wal_fsync_mode=interval. Relaxed acks are a local-session
+        # policy, never a network one. Scoped to THIS put's record so
+        # one client's ack never waits on other sessions' records.
+        with reliability.stmt_scope(stmt_id):
+            if isinstance(info.data, RowTableData):
+                from snappydata_tpu.session import _restore_none_arrays
+
+                raw = _restore_none_arrays(arrays, nulls)
+                n = self.session._journal_then(
+                    info, "insert", raw, None,
+                    lambda: self.session._fold_views(
+                        info, raw, None, info.data.insert_arrays(raw)),
+                    sync_force=True)
+            else:
+                nmask = nulls if any(m is not None for m in nulls) \
+                    else None
+                n = self.session._journal_then(
+                    info, "insert", arrays, nmask,
+                    lambda: self.session._fold_views(
+                        info, arrays, nmask,
+                        info.data.insert_arrays(arrays, nulls=nmask)),
+                    sync_force=True)
+        return int(n or 0)
 
     # -- ops --------------------------------------------------------------
 

@@ -34,6 +34,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import threading
+import time
 import weakref
 from snappydata_tpu.utils import locks
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
@@ -316,8 +317,13 @@ class CompiledPlan:
                  traced_pre: Optional[Callable] = None,
                  traced_main: Optional[Callable] = None,
                  agg_notes: Optional[Dict] = None,
-                 tile_merge: Optional[Dict] = None):
+                 tile_merge: Optional[Dict] = None,
+                 kind: str = "plan"):
         self.relations = relations
+        # what the plan IS (agg / global_agg / scan, join_ in front when
+        # it joins): the stem of the names its jitted functions carry
+        # into the profile's `XLA Modules` line and an HLO dump
+        self.kind = kind
         self.aux_builders = aux_builders
         self.static_providers = static_providers
         self.traced = traced
@@ -347,6 +353,13 @@ class CompiledPlan:
         # predicates lowered to the code/run lanes in that trace —
         # tallied once at trace time, re-counted per execution
         self._code_notes: Dict[tuple, dict] = {}
+
+    def _jit_target(self, traced, static, suffix: str = ""):
+        """`traced` closed over its static sizes, named for what it is
+        (`snappy_agg_main`), never for its parameters."""
+        return tracing.name_jit_target(
+            functools.partial(traced, static),
+            f"snappy_{self.kind}{suffix}")
 
     def _noted_call(self, static, phase: str, fn, args):
         """Dispatch `fn` with the compressed-domain trace tally
@@ -389,6 +402,16 @@ class CompiledPlan:
             fb = reg.counter("compressed_fallbacks") - fb0
             if fb:
                 sp.set("compressed_fallbacks", fb)
+            # what went up, always present on a traced bind (0 where the
+            # device cache served every plate): bytes handed to the
+            # device by storage/device.build_device_table and by the
+            # small per-execution puts below, and host time inside those
+            # calls.  Attrs, not a child span: `bind`'s self time is
+            # what readers of this span have always read.
+            for key in ("upload_bytes", "upload_ms", "plates_built",
+                        "plates_cached"):
+                sp.attrs.setdefault(key, 0)
+            sp.attrs["upload_ms"] = round(sp.attrs["upload_ms"], 4)
             return out
 
     def _bind_inner(self, params: Tuple, sp):
@@ -398,6 +421,19 @@ class CompiledPlan:
         # cooperative cancellation point sits right before it
         check_current()
         reg = global_registry()
+        traced_bind = not isinstance(sp, tracing._NoopSpan)
+
+        def put(x):
+            """jax.device_put of a small host value, counted into the
+            traced bind's upload evidence."""
+            if not traced_bind:
+                return jax.device_put(x)
+            t0 = time.perf_counter()
+            out = jax.device_put(x)
+            sp.add("upload_ms", (time.perf_counter() - t0) * 1e3)
+            sp.add("upload_bytes", int(getattr(x, "nbytes", 0)))
+            return out
+
         # data-dependent validity (e.g. join build-key uniqueness): raises
         # CompileError -> executor reroutes to the host path
         for check in self.bind_checks:
@@ -428,8 +464,8 @@ class CompiledPlan:
                 pad_valid[:len(kept)] = True
                 idx = np.zeros(b_new, dtype=np.int64)
                 idx[:len(kept)] = kept
-                take_idx = jnp.asarray(idx)
-                pad_mask = jnp.asarray(pad_valid)[:, None]
+                take_idx = put(idx)
+                pad_mask = put(pad_valid)[:, None]
             reg.inc("column_batches_seen", int(dt.num_batches))
             sp.add("batches_seen", int(dt.num_batches))
             for ci in r.used:
@@ -463,11 +499,11 @@ class CompiledPlan:
             # join-artifact aux builds already return device arrays —
             # re-wrapping them through numpy would pull them to host
             return x if isinstance(x, jnp.ndarray) \
-                else jax.device_put(np.asarray(x))
+                else put(np.asarray(x))
 
         aux = [_up(b(params)) for b in self.aux_builders]
         static = tuple(p() for p in self.static_providers)
-        pvals = tuple(jax.device_put(_param_scalar(v)) for v in params)
+        pvals = tuple(put(_param_scalar(v)) for v in params)
         return tables, arrays, aux, static, pvals
 
     def _run_device(self, params: Tuple):
@@ -527,13 +563,20 @@ class CompiledPlan:
                 fnp = self._jitted_pre.get(static)
                 first = fnp is None
                 if first:
-                    fnp = jax.jit(functools.partial(self.traced_pre, static))
+                    fnp = jax.jit(self._jit_target(self.traced_pre, static,
+                                                   "_pre"))
                     self._jitted_pre[static] = fnp
                 # first call of a static key traces + XLA-compiles inside
                 # the dispatch — surfaced as its own span so a trace shows
-                # compile time apart from steady-state execution
+                # compile time apart from steady-state execution.  `first`
+                # is this dict's view only: when the batch count passes a
+                # bucket the static key is unchanged, jax.jit retraces and
+                # XLA compiles inside a span named device_execute.  The
+                # `xla_compiles` attr (0 here; tracing's jax.monitoring
+                # listener adds to it) says what really happened.
                 with tracing.span("jit_compile" if first
-                                  else "device_execute", phase="pre"), \
+                                  else "device_execute", phase="pre",
+                                  xla_compiles=0), \
                         _dispatch_scope():
                     pre = _settle(self._noted_call(
                         static, "pre", fnp,
@@ -545,10 +588,12 @@ class CompiledPlan:
             fn = self._jitted_main.get(static)
             first = fn is None
             if first:
-                fn = jax.jit(functools.partial(self.traced_main, static))
+                fn = jax.jit(self._jit_target(self.traced_main, static,
+                                              "_main"))
                 self._jitted_main[static] = fn
             with tracing.span("jit_compile" if first
-                              else "device_execute", phase="main"), \
+                              else "device_execute", phase="main",
+                              xla_compiles=0), \
                     _dispatch_scope():
                 outs = _settle(self._noted_call(
                     static, "main", fn,
@@ -562,10 +607,10 @@ class CompiledPlan:
             fn = self._jitted.get(static)
             first = fn is None
             if first:
-                fn = jax.jit(functools.partial(self.traced, static))
+                fn = jax.jit(self._jit_target(self.traced, static))
                 self._jitted[static] = fn
             with tracing.span("jit_compile" if first
-                              else "device_execute"), \
+                              else "device_execute", xla_compiles=0), \
                     _dispatch_scope():
                 outs = _settle(self._noted_call(
                     static, "single", fn,
@@ -603,13 +648,19 @@ class CompiledPlan:
                                 table=tref() if tref is not None else None)
 
     def execute(self, params: Tuple) -> Result:
+        """Bind, dispatch, bring the outputs home, assemble the Result.
+
+        Dispatch is asynchronous: `device_execute` (`jit_compile` on a
+        static key's first call) ends when the program is ENQUEUED, so
+        the `transfer` span holds two things — the wait for the device
+        to finish the program, then the single bulk device→host copy
+        (per-array .asarray would cost one round trip each).  Its attrs
+        split them: `wait_ms` (`jax.block_until_ready`, the copies
+        already queued behind the program), `copy_ms` (`jax.device_get`
+        of outputs already complete: what is left of the copy) and
+        `bytes` copied.  The span's extent is what it has always been."""
         tables, outs = self._run_device(params)
-        # single bulk device→host transfer (per-array .asarray costs one
-        # round trip each).
-        # The transfer span absorbs the wait on the async dispatch, so
-        # device_execute ≈ dispatch and transfer ≈ compute+copy.
-        with tracing.span("transfer"):
-            outs = jax.device_get(outs)
+        outs = _transfer(outs)
         if bool(np.asarray(outs[2])):
             raise CompileError(
                 "device overflow (group-by cardinality beyond max_groups, "
@@ -670,19 +721,19 @@ class CompiledPlan:
         first = fn is None
         if first:
             reg.inc("serving_vmap_compiles")
-            fn = jax.jit(jax.vmap(functools.partial(self.traced, static),
-                                  in_axes=(None, 0, 0)))
+            fn = jax.jit(jax.vmap(
+                self._jit_target(self.traced, static, "_vmap"),
+                in_axes=(None, 0, 0)))
             self._jitted_vmap[key] = fn
         with tracing.span("jit_compile" if first else "device_execute",
-                          batched=len(params_list)):
+                          batched=len(params_list), xla_compiles=0):
             outs = self._noted_call(key, "vmap", fn,
                                     (tuple(arrays), aux, pvals))
         self._count_compressed(reg, key, ("vmap",))
         self._count_agg_notes(reg, static)
         # the whole batch comes home in ONE transfer — the amortization
         # the micro-batcher buys (vs one device_get per request)
-        with tracing.span("transfer"):
-            outs = jax.device_get(outs)
+        outs = _transfer(outs)
         reg.inc("serving_bulk_transfers")
         return tables, outs
 
@@ -721,6 +772,29 @@ class CompiledPlan:
             nulls.append(nmask)
             dtypes.append(oc.dtype)
         return Result(names, cols, nulls, dtypes)
+
+
+def _transfer(outs):
+    """Device outputs → host under the `transfer` span, which carries
+    the split of its own time as attrs: the wait for the asynchronous
+    dispatch to finish, then the copy (see CompiledPlan.execute)."""
+    with tracing.span("transfer") as sp:
+        if isinstance(sp, tracing._NoopSpan):
+            return jax.device_get(outs)
+        t0 = time.perf_counter()
+        # start the copies now, as a bare device_get would: they queue
+        # behind the program, so waiting for it first adds no round trip
+        for x in jax.tree_util.tree_leaves(outs):
+            x.copy_to_host_async()
+        jax.block_until_ready(outs)
+        t1 = time.perf_counter()
+        host = jax.device_get(outs)
+        t2 = time.perf_counter()
+        sp.set("wait_ms", round((t1 - t0) * 1e3, 4))
+        sp.set("copy_ms", round((t2 - t1) * 1e3, 4))
+        sp.set("bytes", sum(int(x.nbytes)
+                            for x in jax.tree_util.tree_leaves(host)))
+        return host
 
 
 def data_needs_mask(v, mask) -> bool:
@@ -1171,7 +1245,11 @@ class Compiler:
                           self.bind_checks,
                           traced_pre=traced_pre, traced_main=traced_main,
                           agg_notes=getattr(self, "_agg_notes", None),
-                          tile_merge=getattr(self, "_tile_merge", None))
+                          tile_merge=getattr(self, "_tile_merge", None),
+                          kind=("join_" if n_rel > 1 else "")
+                          + (("agg" if plan.group_exprs else "global_agg")
+                             if is_agg else "scan")
+                          + ("_partial" if self.partial_raw else ""))
         cp.join_meta = self.join_meta
         return cp
 
@@ -1569,10 +1647,11 @@ class Compiler:
             def run_filter(ctx) -> RelOut:
                 out = child(ctx)
                 rt = Runtime(out.cols, ctx.params, ctx.aux_slice(builder))
-                p = pred(rt)
-                keep = p.value
-                if p.null is not None:
-                    keep = keep & ~p.null
+                with tracing.op_scope("filter"):
+                    p = pred(rt)
+                    keep = p.value
+                    if p.null is not None:
+                        keep = keep & ~p.null
                 # run-space bookkeeping for the RLE aggregate lane: the
                 # filter stays pure only if THIS predicate survived in
                 # run space over the same run partition as every one
@@ -2530,6 +2609,7 @@ class Compiler:
                 num_groups = min(max_groups, n)
             return fast, cards, eff_cards, num_groups
 
+        @tracing.op_scope("group_index")
         def compute_pre(ctx, rt, out, valid):
             """Combined group index + overflow flag — the cacheable
             prefix of every grouped aggregate."""

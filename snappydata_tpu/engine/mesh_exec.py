@@ -50,6 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from snappydata_tpu.observability import tracing
 from snappydata_tpu.utils import locks
 
 from jax import shard_map
@@ -237,7 +238,9 @@ def _exchange_relation(arrays, layout, rel, perm, live, b_new, cap, ctx):
         return jax.device_put(x, ctx.sharding_for(x))
 
     gather = jax.jit(
-        lambda flat: flat.reshape(-1)[perm_d].reshape(b_new, cap),
+        tracing.name_jit_target(
+            lambda flat: flat.reshape(-1)[perm_d].reshape(b_new, cap),
+            "snappy_mesh_exchange"),
         out_shardings=ctx.batch_sharding)
     replaced: Dict[int, object] = {}
     nbytes = 0
@@ -297,7 +300,6 @@ def run_partial(compiled, params: Tuple, probe_data, ctx,
     compiled._assemble, or None when this lane must decline (caller
     falls back to GSPMD, counted by reason there)."""
     from snappydata_tpu.engine.exprs import CompileError
-    from snappydata_tpu.observability import tracing
 
     reg = _reg()
     strategy, decline = ("scan", None) if not compiled.join_meta else \
@@ -365,7 +367,8 @@ def run_partial(compiled, params: Tuple, probe_data, ctx,
     # before any collective rendezvous starts
     rfail.hit("mesh.dispatch")
     with tracing.span("jit_compile" if first else "device_execute",
-                      phase="mesh", devices=ctx.num_devices), \
+                      phase="mesh", devices=ctx.num_devices,
+                      xla_compiles=0), \
             dispatch_lock:
         outs = compiled._noted_call(
             static, "mesh", fn, (tuple(arrays), tuple(aux), pvals))
@@ -436,6 +439,7 @@ def _build_mesh_fn(compiled, static, tags, ctx, layout, sharded_rels,
 
     aux_specs = jax.tree.map(lambda _: P(), tuple(aux))
     p_specs = jax.tree.map(lambda _: P(), tuple(pvals))
+    tracing.name_jit_target(merged_fn, f"snappy_{compiled.kind}_mesh")
     return jax.jit(shard_map(
         merged_fn, mesh=ctx.mesh,
         in_specs=(tuple(arr_specs), aux_specs, p_specs),

@@ -30,6 +30,19 @@ Span tree invariants:
 - Worker threads do not inherit contextvars: a thread acting for a
   traced request re-enters with ``attach(trace, span)`` (the hedged
   replica-read workers in cluster/distributed.py do).
+- A span is a timeline entry: ``start_ms`` is its start as an offset
+  from its trace's root (``perf_counter``), parentage is the tree.
+- While a profiler session is active (``jax.profiler.start_trace``, or
+  a capture through the profiler server) every span of an active trace
+  is ALSO open in the profiler's host trace as ``snappy:<name>``
+  (``jax.profiler.TraceAnnotation``; the root carries the trace id and
+  ``kind``), on the thread that opened it — so the program's spans sit
+  on the device trace's clock by construction, no clock conversion.
+  With no session the bridge costs one ``is_enabled()`` read per span.
+- XLA compiles are counted where they happen: one process-wide
+  ``jax.monitoring`` listener adds ``xla_compiles`` / ``xla_compile_ms``
+  / ``retraces`` to whichever span is current (and to the registry),
+  whatever that span is called.
 
 Completed traces land in a bounded in-process ring
 (``trace_ring_entries``) served by ``GET /status/api/v1/traces``; any
@@ -50,7 +63,17 @@ import uuid
 from collections import deque
 from typing import Dict, List, Optional
 
+import jax
+from jax.profiler import TraceAnnotation
+
 from snappydata_tpu import config
+
+# the profiler's host-trace prefix of a span's interval
+PROFILE_PREFIX = "snappy:"
+# is a profiler session recording host events right now?  One atomic
+# read in the profiler's own runtime (~25 ns): the whole cost of the
+# bridge on a traced span while nobody profiles
+_profiling = TraceAnnotation.is_enabled
 
 # children per span beyond which further same-level spans collapse into
 # a truncation counter (a per-tile bind span tree must stay bounded)
@@ -86,8 +109,14 @@ class Span:
         if self.duration_s is None:
             self.duration_s = time.perf_counter() - self._t0
 
-    def to_dict(self) -> dict:
+    def to_dict(self, t_root: Optional[float] = None) -> dict:
+        """`start_ms` is this span's start as an offset from its
+        trace's root (`t_root`; a span serialized alone is its own
+        root)."""
+        if t_root is None:
+            t_root = self._t0
         out = {"name": self.name,
+               "start_ms": round((self._t0 - t_root) * 1e3, 4),
                "ms": round((self.duration_s or 0.0) * 1e3, 4)}
         if self.attrs:
             # defensive copy: a straggling worker (a losing hedge leg)
@@ -103,8 +132,25 @@ class Span:
             else:
                 out["attrs"] = {"attrs_unstable": True}
         if self.children:
-            out["children"] = [c.to_dict() for c in list(self.children)]
+            out["children"] = [c.to_dict(t_root)
+                               for c in list(self.children)]
         return out
+
+    def self_seconds(self) -> float:
+        """Duration less what the children cover: the union of their
+        intervals clipped to this span's, so parallel children (hedge
+        legs, member fan-out) are not taken out twice."""
+        end = self._t0 + (self.duration_s or 0.0)
+        covered, at = 0.0, self._t0
+        for c in sorted((c for c in list(self.children)
+                         if c.duration_s is not None),
+                        key=lambda c: c._t0):
+            lo = max(c._t0, at)
+            hi = min(c._t0 + c.duration_s, end)
+            if hi > lo:
+                covered += hi - lo
+                at = hi
+        return max(0.0, (self.duration_s or 0.0) - covered)
 
 
 class Trace:
@@ -148,15 +194,18 @@ class Trace:
         return n
 
     def phase_seconds(self) -> Dict[str, float]:
-        """Total seconds per span NAME across the whole tree — the
-        per-phase breakdown EXPLAIN ANALYZE and bench.py report.  Spans
-        still open (crashed mid-phase) are skipped."""
+        """SELF seconds per span NAME across the whole tree (a span's
+        duration less what its children cover) — the per-phase
+        breakdown EXPLAIN ANALYZE and the ring's `phases_ms` report, so
+        the phases sum to the part of the request that sits under some
+        span and a nested span is not counted in its parent again.
+        Spans still open (crashed mid-phase) are skipped."""
         out: Dict[str, float] = {}
         stack = list(self.root.children)
         while stack:
             sp = stack.pop()
             if sp.duration_s is not None:
-                out[sp.name] = out.get(sp.name, 0.0) + sp.duration_s
+                out[sp.name] = out.get(sp.name, 0.0) + sp.self_seconds()
             stack.extend(sp.children)
         return out
 
@@ -226,7 +275,7 @@ class request_scope:
     ~4µs per request on the serving point-lookup profile."""
 
     __slots__ = ("sql", "user", "kind", "trace_id", "origin", "force",
-                 "_tr", "_tok_t", "_tok_s")
+                 "_tr", "_tok_t", "_tok_s", "_ann")
 
     def __init__(self, sql: str = "", user: str = "",
                  kind: str = "session", trace_id: Optional[str] = None,
@@ -248,6 +297,8 @@ class request_scope:
         tr = Trace(self.sql, self.user, self.kind,
                    trace_id=self.trace_id, origin=self.origin)
         self._tr = tr
+        self._ann = _annotation(tr.root.name, trace_id=tr.trace_id,
+                                kind=tr.kind) if _profiling() else None
         self._tok_t = _trace.set(tr)
         self._tok_s = _span.set(tr.root)
         return tr
@@ -262,8 +313,18 @@ class request_scope:
         _span.reset(self._tok_s)
         _trace.reset(self._tok_t)
         tr.finish()
+        if self._ann is not None:
+            self._ann.__exit__(et, ev, tb)
         _RING.record(tr)
         return False
+
+
+def _annotation(name: str, **meta) -> TraceAnnotation:
+    """Open `snappy:<name>` in the profiler's host trace, on this
+    thread, from now until its `__exit__`."""
+    ann = TraceAnnotation(PROFILE_PREFIX + name, **meta)
+    ann.__enter__()
+    return ann
 
 
 class _NoopSpan:
@@ -284,7 +345,7 @@ class span:
     read, no allocation) when no trace is active.  Enters to the span
     so callers can `.set()` evidence on it."""
 
-    __slots__ = ("name", "attrs", "_sp", "_tok")
+    __slots__ = ("name", "attrs", "_sp", "_tok", "_ann")
 
     def __init__(self, name: str, **attrs):
         self.name = name
@@ -299,6 +360,7 @@ class span:
             parent.attrs["children_truncated"] = \
                 parent.attrs.get("children_truncated", 0) + 1
             return _NOOP
+        self._ann = _annotation(self.name) if _profiling() else None
         sp = Span(self.name, self.attrs or None)
         parent.children.append(sp)
         self._sp = sp
@@ -310,6 +372,8 @@ class span:
         if sp is not None:
             _span.reset(self._tok)
             sp.close()
+            if self._ann is not None:
+                self._ann.__exit__(et, ev, tb)
         return False
 
 
@@ -318,6 +382,70 @@ def annotate(key: str, value) -> None:
     sp = _span.get()
     if sp is not None:
         sp.attrs[key] = value
+
+
+# -----------------------------------------------------------------------
+# XLA compiles, wherever they happen
+# -----------------------------------------------------------------------
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_RETRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+
+
+def _on_jax_duration(event: str, duration_s: float, **_meta) -> None:
+    """`jax.monitoring` duration listener.  JAX fires it on the thread
+    that traces/compiles, so the contextvar names the span the compile
+    really happened under — `device_execute` when a batch-count bucket
+    re-specializes a plan whose static key did not change.  A load from
+    the persistent compile cache fires the backend event too: it is a
+    shape first met."""
+    if event not in (_COMPILE_EVENT, _RETRACE_EVENT):
+        return
+    from snappydata_tpu.observability.metrics import global_registry
+
+    reg = global_registry()
+    sp = _span.get()
+    if event == _COMPILE_EVENT:
+        reg.inc("xla_compiles")
+        reg.record_time("xla_compile", duration_s)
+        if sp is not None:
+            sp.add("xla_compiles", 1)
+            sp.add("xla_compile_ms", round(duration_s * 1e3, 3))
+    else:
+        reg.inc("jit_retraces")
+        if sp is not None:
+            sp.add("retraces", 1)
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+
+
+# -----------------------------------------------------------------------
+# names on the device side
+# -----------------------------------------------------------------------
+
+# operator scopes of a compiled plan (`jax.named_scope`): they ride the
+# HLO's op_name metadata, so a profile's device ops and an HLO dump say
+# which operator an XLA fusion came from.  Metadata only — the compiled
+# code does not change.  One list, so a trace reader can name them all.
+OP_SCOPES = ("filter", "decode", "dict_gather", "group_index",
+             "group_reduce", "join")
+
+
+def op_scope(name: str):
+    """`jax.named_scope(name)` for a name of `OP_SCOPES`."""
+    if name not in OP_SCOPES:
+        raise ValueError(f"unknown operator scope {name!r}")
+    return jax.named_scope(name)
+
+
+def name_jit_target(fn, name: str):
+    """Give the callable handed to `jax.jit` a stable `__name__`, so
+    the profile's `XLA Modules` line and an HLO dump read `jit_<name>`
+    and not `jit__unnamed_wrapped_function_` (what a bare
+    `functools.partial` gets)."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
 
 
 @contextlib.contextmanager

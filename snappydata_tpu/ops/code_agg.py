@@ -26,6 +26,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from snappydata_tpu.observability import tracing
+
 # static cell budget for the (group, batch, code) bincount space: past
 # this the scatter output outweighs what the lane saves, so callers
 # keep the gather path
@@ -38,6 +40,7 @@ def dict_space_cells(nseg: int, codes_shape, dicts_shape) -> int:
     return int(nseg) * int(codes_shape[0]) * int(dicts_shape[1])
 
 
+@tracing.op_scope("group_reduce")
 def dict_space_sum(codes, dicts, gidx, w, nseg: int):
     """SUM over a VALUE_DICT column in dictionary space.
 
@@ -67,6 +70,7 @@ def dict_space_sum(codes, dicts, gidx, w, nseg: int):
     return jnp.sum(counts * dicts.astype(jnp.float64)[None], axis=(1, 2))
 
 
+@tracing.op_scope("group_reduce")
 def run_space_sum_count(values, ends, run_mask):
     """Global SUM + COUNT over an RLE plate in run space.
 

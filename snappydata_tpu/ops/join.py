@@ -52,6 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from snappydata_tpu import config
+from snappydata_tpu.observability import tracing
 from snappydata_tpu.parallel import mesh
 # the expanded-output axis reuses the batch axis' two-shapes-per-octave
 # bucketing ({2^k, 1.5*2^k}) — one policy, so a waste-bound tweak there
@@ -306,6 +307,7 @@ def probe_expand_bound_per_shard(artifact: dict, probe_ident,
 #                each range, and the k-th passing row is located with one
 #                more searchsorted into that prefix sum.
 
+@tracing.op_scope("join")
 def match_ranges_dense(skeys, pkeys):
     """(counts, lo) per probe key against an unfiltered sorted build;
     `lo` is in the sorted POSITION domain (k-th match at order[lo+k])."""
@@ -314,6 +316,7 @@ def match_ranges_dense(skeys, pkeys):
     return hi - lo, lo
 
 
+@tracing.op_scope("join")
 def match_ranges(skeys, order, pass_flat, pkeys):
     """Pass-aware flavor: returns (counts, base, cum) where `counts[p]`
     is the number of PASSING build rows whose key equals `pkeys[p]`,
@@ -330,6 +333,7 @@ def match_ranges(skeys, order, pass_flat, pkeys):
     return top - base, base, cum
 
 
+@tracing.op_scope("join")
 def nth_match(base, rank, cum, order):
     """Flat build position of the (rank+1)-th PASSING row of a match
     range (garbage when the range has fewer passing rows — callers mask
@@ -340,12 +344,14 @@ def nth_match(base, rank, cum, order):
     return order[jnp.clip(pos, 0, cum.shape[0] - 1)]
 
 
+@tracing.op_scope("join")
 def nth_match_dense(base, rank, order):
     """Dense flavor: the k-th match of a range starting at sorted
     position `base` is simply order[base + k]."""
     return order[jnp.clip(base + rank, 0, order.shape[0] - 1)]
 
 
+@tracing.op_scope("join")
 def expand(counts, counts_eff, bucket: int):
     """Static-shape one-to-many expansion bookkeeping.
 

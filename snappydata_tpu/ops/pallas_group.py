@@ -216,6 +216,7 @@ def _grouped_call(gidx2d, ins,
                 jax.ShapeDtypeStruct((G, _SUBLANES, _LANES), jnp.float32)
                 for _ in range(n_out)),
             interpret=interpret,
+            name="snappy_group_reduce",
         )(gidx2d, *ins)
 
     results = []
@@ -416,6 +417,7 @@ def _grouped_code_call(vmem_ins, dict_ins, spec, G: int, dshapes,
                 jax.ShapeDtypeStruct((G, _SUBLANES, _LANES), jnp.float32)
                 for _ in range(n_out)),
             interpret=interpret,
+            name="snappy_code_group_reduce",
         )(*vmem_ins, *dict_ins)
     results = []
     oi = 0

@@ -104,6 +104,7 @@ def _kahan_call(x2d: jnp.ndarray, mask2d: jnp.ndarray,
                                      jnp.float32),
             ),
             interpret=interpret,
+            name="snappy_kahan_sum",
         )(x2d, mask2d)
     # exact f64 combine of the small per-block partials. Kahan's
     # c = (t - s) - y holds the EXCESS already folded into s, so the
@@ -248,6 +249,7 @@ def _fused_q6_call(qty, disc, ship, price, valid, dicts,
             out_specs=(out_blk, out_blk, out_blk),
             out_shape=(out_sds, out_sds, out_sds),
             interpret=interpret,
+            name="snappy_code_filter_sum",
         )(qty, disc, ship, price, valid, dicts, qhi, dlo, dhi, slo, shi)
     total = (jnp.sum(sums.astype(jnp.float64))
              - jnp.sum(comps.astype(jnp.float64)))

@@ -46,6 +46,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from snappydata_tpu.observability import tracing
+
 STRATEGIES = ("auto", "unroll", "scatter", "matmul")
 
 # unroll's G-masked-reductions shape only ever wins in the small-G
@@ -123,6 +125,7 @@ def resolve_strategy(requested: str, backend: str, num_segments: int,
     return "scatter"
 
 
+@tracing.op_scope("group_index")
 def make_onehot(gidx, num_segments: int, acc_dtype):
     """[N, G] one-hot of the (already validity-masked) group index in
     the accumulator dtype.  Callers pass the REAL group count: rows
@@ -144,6 +147,7 @@ def _pack(cols):
     return jnp.stack(cols, axis=1)
 
 
+@tracing.op_scope("group_reduce")
 def packed_sum(cols, gidx, num_segments: int, strategy: str,
                onehot=None):
     """Fused segmented SUM of a family's columns (list of [N] arrays)
@@ -177,6 +181,7 @@ def packed_sum(cols, gidx, num_segments: int, strategy: str,
     return jax.ops.segment_sum(packed, gidx, num_segments=num_segments)
 
 
+@tracing.op_scope("group_reduce")
 def packed_minmax(kind: str, cols, gidx, num_segments: int,
                   strategy: str):
     """Fused segmented MIN/MAX of a family's columns (list of [N]

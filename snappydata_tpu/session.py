@@ -9,6 +9,7 @@ parse → analyze → plan-cache lookup keyed on tokenized plan → execute).
 from __future__ import annotations
 
 import threading
+import time
 from snappydata_tpu.utils import locks
 from typing import Any, Dict, List, Optional, Sequence
 
@@ -334,29 +335,35 @@ class SnappySession:
             from snappydata_tpu.reliability import current_stmt_id
 
             sid = current_stmt_id()
+            from snappydata_tpu.observability import tracing
             from snappydata_tpu.storage import mvcc
 
+            # the write path's spans, one set of names for this branch
+            # and _journal_then: `wal_append` and `wal_sync` are opened
+            # by the store itself (storage/persistence.py), `apply`
+            # here; the wait for the mutation lock rides the span that
+            # was open when it was taken, as `lock_wait_ms`
+            t_lock = time.perf_counter()
             with ddl_gate, ds.mutation_lock:
+                _note_lock_wait(t_lock)
                 seq = ds.wal_append(_norm(table), "sql", sql=sql_text,
                                     params=tuple(params),
                                     extra={"stmt_id": sid} if sid else None)
                 # the WAL seq IS the commit timestamp: manifests this
                 # statement publishes carry it (mvcc epoch fences)
-                with mvcc.commit_scope(seq):
+                with mvcc.commit_scope(seq), tracing.span("apply") as sp:
                     # locklint: blocking-under-lock nested reads under a
                     # DML's mutation hold run on STORE-LESS scratch
                     # sessions (tile-merge scratch, matview folds) whose
                     # _sql_statement never reaches wal_sync/fsync; device
                     # waits here are the cost of journal->apply atomicity
                     result = self.execute_statement(stmt, tuple(params))
+                    sp.set("rows", _affected_rows(result))
             # ack gate (group commit): the record may still sit in the
             # commit buffer — wal_sync blocks until the covering fsync,
             # OUTSIDE the mutation lock so concurrent committers coalesce
             # into one group fsync instead of serializing on it
-            from snappydata_tpu.observability import tracing
-
-            with tracing.span("wal_sync"):
-                ds.wal_sync(seq)
+            ds.wal_sync(seq)
             return result
         result = self.execute_statement(stmt, tuple(params))
         if ds is not None:
@@ -2172,18 +2179,28 @@ class SnappySession:
         replica promotion) set it, scoped to exactly THIS record's seq
         so one put never waits on (or fails for) other sessions'
         records."""
+        from snappydata_tpu.observability import tracing
         from snappydata_tpu.views import matview as _mv
+
+        def applied():
+            # span `apply` (attr `rows`): the in-memory apply — encode,
+            # cut batches, fold views; a row-buffer roll-over shows as
+            # its `rollover` child (storage/table_store.py)
+            with tracing.span("apply", rows=len(arrays[0])):
+                return apply_fn()
 
         ds = self.disk_store
         if ds is None:
             with _mv.managed_base_write():
-                return apply_fn()
+                return applied()
         from snappydata_tpu.reliability import current_stmt_id
 
         sid = current_stmt_id()
         from snappydata_tpu.storage import mvcc
 
+        t_lock = time.perf_counter()
         with ds.mutation_lock:
+            _note_lock_wait(t_lock)
             seq = ds.wal_append(info.name, kind, arrays=arrays,
                                 nulls=nulls,
                                 extra={"stmt_id": sid} if sid else None)
@@ -2192,7 +2209,7 @@ class SnappySession:
                 # mutation hold IS the WAL invariant (on-disk log >=
                 # in-memory state); apply_fn is the statement's own
                 # apply, not a foreign registry callback
-                out = apply_fn()
+                out = applied()
         ds.wal_sync(seq, force=sync_force)
         return out
 
@@ -2285,11 +2302,14 @@ class SnappySession:
             extra["stmt_id"] = current_stmt_id()
         from snappydata_tpu.storage import mvcc
 
+        from snappydata_tpu.observability import tracing
+
         with self.disk_store.mutation_lock:
             seq = self.disk_store.wal_append(
                 info.name, "delete_keys", arrays=key_arrays, extra=extra)
-            with mvcc.commit_scope(seq):
+            with mvcc.commit_scope(seq), tracing.span("apply") as sp:
                 out = apply()
+                sp.set("rows", int(out or 0))
         self.disk_store.wal_sync(seq)   # ack after the covering fsync
         return out
 
@@ -3582,6 +3602,24 @@ def _contains_subquery(plan: ast.Plan) -> bool:
 
     ast.transform_plan_exprs(plan, fn)
     return found[0]
+
+
+def _note_lock_wait(t_asked: float) -> None:
+    """`lock_wait_ms` on the span open now: how long the store's
+    mutation lock, asked for at `t_asked`, took to get."""
+    from snappydata_tpu.observability import tracing
+
+    tracing.annotate("lock_wait_ms",
+                     round((time.perf_counter() - t_asked) * 1e3, 4))
+
+
+def _affected_rows(result) -> int:
+    """The row count a DML statement's one-cell Result carries (0 for
+    a statement that reports none) — the `apply` span's `rows`."""
+    try:
+        return int(result.rows()[0][0])
+    except (IndexError, TypeError, ValueError):
+        return 0
 
 
 def _sql_literal(v) -> str:

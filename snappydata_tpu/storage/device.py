@@ -19,6 +19,7 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import dataclasses
+import time
 import weakref
 from typing import Dict, Optional, Sequence, Tuple
 
@@ -27,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from snappydata_tpu import types as T
+from snappydata_tpu.observability import tracing
 from snappydata_tpu.storage.table_store import ColumnTableData, Manifest
 from snappydata_tpu.utils import locks
 
@@ -253,11 +255,36 @@ def build_device_table(data: ColumnTableData, manifest: Optional[Manifest],
 
     failpoints.hit("device.transfer")
 
-    def _place(host_array):
+    # evidence for the executor's `bind` span, where this build runs
+    # under one: bytes handed to the device and host time inside those
+    # calls go on it as attrs — no child span, so `bind`'s self time
+    # stays what it was.  Untraced binds, prefetch workers and callers
+    # outside a `bind` span pay nothing and leave no stray attr.
+    sp = tracing.current_span()
+    if sp is not None and sp.name != "bind":
+        sp = None
+
+    def _counted(put):
+        if sp is None:
+            return put
+
+        def counted(host_array):
+            t0 = time.perf_counter()
+            out = put(host_array)
+            sp.add("upload_ms", (time.perf_counter() - t0) * 1e3)
+            sp.add("upload_bytes", int(host_array.nbytes))
+            return out
+        return counted
+
+    def _place_on(host_array):
         from snappydata_tpu.parallel.mesh import shard_batches
 
         return shard_batches(host_array, ctx) if ctx is not None \
             else jnp.asarray(host_array)
+
+    _place = _counted(_place_on)
+    _upload = _counted(jnp.asarray)   # the unsharded assembly lanes
+    plates = [0, 0]   # column plates [built, found in the device cache]
 
     if "valid" in cache:
         # a partially-filled entry pins the padded batch shape: a
@@ -292,6 +319,7 @@ def build_device_table(data: ColumnTableData, manifest: Optional[Manifest],
             # per-field dictionary codes) — element_at field access
             # becomes a static plate pick in the compiled program
             key = ("scol", ci)
+            plates[key in cache] += 1
             if key not in cache:
                 cache[key] = _build_struct_column(
                     data, manifest, views, row_chunks, ci, f, b, cap,
@@ -303,6 +331,7 @@ def build_device_table(data: ColumnTableData, manifest: Optional[Manifest],
             # values as-is, string values as codes) + lengths +
             # value-null bits — feeds the device element_at lowering
             key = ("mcol", ci)
+            plates[key in cache] += 1
             if key not in cache:
                 cache[key] = _build_map_column(
                     data, manifest, views, row_chunks, ci, f, b, cap,
@@ -319,6 +348,7 @@ def build_device_table(data: ColumnTableData, manifest: Optional[Manifest],
             # size/element_at/array_contains (ref: SerializedArray
             # fixed-width fast path)
             key = ("acol", ci)
+            plates[key in cache] += 1
             if key not in cache:
                 cache[key] = _build_array_column(
                     data, manifest, views, row_chunks, ci, f, b, cap,
@@ -352,6 +382,7 @@ def build_device_table(data: ColumnTableData, manifest: Optional[Manifest],
                                    cols_enc, any_delta, bool(row_chunks),
                                    code_ok)
         key = ("ccol", ci) if cd_mode else ("col", ci)
+        plates[key in cache] += 1
         if key not in cache:
             # itemized fallback counting happens exactly once per build
             # (cache miss), decoded OR compressed — so every decode-first
@@ -510,14 +541,14 @@ def build_device_table(data: ColumnTableData, manifest: Optional[Manifest],
                 nonzero_keep = [i for i in keep if i < b_actual]
                 if nonzero_keep:
                     placed = placed.at[np.array(nonzero_keep)].set(
-                        jnp.asarray(stacked[np.array(nonzero_keep)]))
+                        _upload(stacked[np.array(nonzero_keep)]))
                 if dd_rle:
                     from snappydata_tpu.storage.device_decode import \
                         rle_views_to_plate
 
                     idxs = np.array([i for i, _ in dd_rle])
                     dec = rle_views_to_plate([c for _, c in dd_rle],
-                                             cap, dt)
+                                             cap, dt, place=_upload)
                     placed = placed.at[idxs].set(dec.astype(dt))
                 if dd_bits:
                     from snappydata_tpu.storage.device_decode import \
@@ -525,7 +556,7 @@ def build_device_table(data: ColumnTableData, manifest: Optional[Manifest],
 
                     idxs = np.array([i for i, _ in dd_bits])
                     dec = bitset_views_to_plate([c for _, c in dd_bits],
-                                                cap)
+                                                cap, place=_upload)
                     placed = placed.at[idxs].set(dec.astype(dt))
                 if dd_vd:
                     from snappydata_tpu.storage.device_decode import \
@@ -533,7 +564,7 @@ def build_device_table(data: ColumnTableData, manifest: Optional[Manifest],
 
                     idxs = np.array([i for i, _ in dd_vd])
                     dec = valdict_views_to_plate([c for _, c in dd_vd],
-                                                 cap, dt)
+                                                 cap, dt, place=_upload)
                     placed = placed.at[idxs].set(dec)
             else:
                 placed = _place(stacked)
@@ -548,6 +579,9 @@ def build_device_table(data: ColumnTableData, manifest: Optional[Manifest],
         if dom is not None:
             dict_domains[ci] = dom
 
+    if sp is not None:
+        sp.add("plates_built", plates[0])
+        sp.add("plates_cached", plates[1])
     if _cache_budget.enabled():
         _cache_budget.touch(data._device_cache, cache_key,
                             _entry_bytes(cache), data=data)

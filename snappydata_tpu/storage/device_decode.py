@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from snappydata_tpu.observability import tracing
 from snappydata_tpu.utils import locks
 
 # bind-transfer accounting (powers the bench/device-decode metric and the
@@ -112,19 +113,23 @@ def _rle_expand(values: jnp.ndarray, ends: jnp.ndarray, cap: int):
         seg = jnp.minimum(seg, vals.shape[0] - 1)
         return vals[seg]
 
-    return jax.vmap(one)(values, ends)
+    with tracing.op_scope("decode"):
+        return jax.vmap(one)(values, ends)
 
 
 @functools.partial(jax.jit, static_argnames=("cap",))
 def _bitset_expand(packed: jnp.ndarray, cap: int):
     """packed: [N, ceil(cap/8)] uint8 (LSB-first, numpy packbits
     bitorder='little') → bool [N, cap]."""
-    idx = jnp.arange(cap)
-    byte = packed[:, idx // 8]
-    return ((byte >> (idx % 8).astype(jnp.uint8)) & 1).astype(jnp.bool_)
+    with tracing.op_scope("decode"):
+        idx = jnp.arange(cap)
+        byte = packed[:, idx // 8]
+        return ((byte >> (idx % 8).astype(jnp.uint8))
+                & 1).astype(jnp.bool_)
 
 
-def rle_views_to_plate(rle_cols, cap: int, dt) -> jnp.ndarray:
+def rle_views_to_plate(rle_cols, cap: int, dt,
+                       place=jnp.asarray) -> jnp.ndarray:
     """Stack N encoded RLE columns into device plates [N, cap].
 
     `rle_cols`: list of EncodedColumn with .data (run values) and .runs
@@ -144,14 +149,15 @@ def rle_views_to_plate(rle_cols, cap: int, dt) -> jnp.ndarray:
         _counters["bytes_encoded"] += int(vals[i].nbytes + ends[i].nbytes)
         _counters["bytes_decoded_equiv"] += int(cap * vals.dtype.itemsize)
         _counters["batches_device_decoded"] += 1
-    return _rle_expand(jnp.asarray(vals), jnp.asarray(ends), cap)
+    return _rle_expand(place(vals), place(ends), cap)
 
 
 @jax.jit
 def _valdict_expand(codes: jnp.ndarray, dicts: jnp.ndarray):
     """codes: [N, cap] uint8; dicts: [N, D] (D padded per call).  Lane j
     of row i takes dicts[i, codes[i, j]] — a per-batch device gather."""
-    return jnp.take_along_axis(dicts, codes.astype(jnp.int32), axis=1)
+    with tracing.op_scope("dict_gather"):
+        return jnp.take_along_axis(dicts, codes.astype(jnp.int32), axis=1)
 
 
 def _valdict_code_dtype(vd_cols) -> np.dtype:
@@ -161,7 +167,8 @@ def _valdict_code_dtype(vd_cols) -> np.dtype:
         c.data.dtype.itemsize > 1 for c in vd_cols) else np.dtype(np.uint8)
 
 
-def valdict_views_to_plate(vd_cols, cap: int, dt) -> jnp.ndarray:
+def valdict_views_to_plate(vd_cols, cap: int, dt,
+                           place=jnp.asarray) -> jnp.ndarray:
     """Stack N value-dict columns into decoded plates [N, cap]: the
     uint8/uint16 codes and the (padded) dictionaries cross the link, the
     values-gather runs in-trace."""
@@ -176,10 +183,11 @@ def valdict_views_to_plate(vd_cols, cap: int, dt) -> jnp.ndarray:
         _counters["bytes_encoded"] += int(c.data.nbytes + d.nbytes)
         _counters["bytes_decoded_equiv"] += int(cap * dicts.dtype.itemsize)
         _counters["batches_device_decoded"] += 1
-    return _valdict_expand(jnp.asarray(codes), jnp.asarray(dicts))
+    return _valdict_expand(place(codes), place(dicts))
 
 
-def bitset_views_to_plate(bit_cols, cap: int) -> jnp.ndarray:
+def bitset_views_to_plate(bit_cols, cap: int,
+                          place=jnp.asarray) -> jnp.ndarray:
     """Stack N boolean-bitset columns into decoded bool plates [N, cap]."""
     nbytes = (cap + 7) // 8
     n = len(bit_cols)
@@ -190,7 +198,7 @@ def bitset_views_to_plate(bit_cols, cap: int) -> jnp.ndarray:
         _counters["bytes_encoded"] += int(raw.nbytes)
         _counters["bytes_decoded_equiv"] += int(cap)
         _counters["batches_device_decoded"] += 1
-    return _bitset_expand(jnp.asarray(packed), cap)
+    return _bitset_expand(place(packed), cap)
 
 
 # ==========================================================================
@@ -323,8 +331,9 @@ def code_values(plate: CodePlate) -> jnp.ndarray:
     """Lazy decode of a CodePlate: a per-batch dictionary gather that XLA
     fuses into whatever consumes the values (the fused
     decode+filter+aggregate form of the default scan)."""
-    return jnp.take_along_axis(plate.dicts,
-                               plate.codes.astype(jnp.int32), axis=1)
+    with tracing.op_scope("dict_gather"):
+        return jnp.take_along_axis(plate.dicts,
+                                   plate.codes.astype(jnp.int32), axis=1)
 
 
 def rle_values(plate: RlePlate, cap: int) -> jnp.ndarray:
@@ -337,6 +346,7 @@ def bit_values(plate: BitPlate, cap: int) -> jnp.ndarray:
     return _bitset_expand(plate.packed, cap)
 
 
+@tracing.op_scope("filter")
 def code_cmp_mask(op: str, plate: CodePlate, lit) -> jnp.ndarray:
     """Code-domain lowering of `column OP literal` over a CodePlate:
     the literal translates to per-batch code thresholds through the
@@ -378,6 +388,7 @@ def code_cmp_mask(op: str, plate: CodePlate, lit) -> jnp.ndarray:
     return m
 
 
+@tracing.op_scope("filter")
 def rle_cmp_mask(fn, plate: RlePlate, lit, cap: int) -> jnp.ndarray:
     """Run-arithmetic filter over an RlePlate: evaluate the predicate
     per RUN (O(runs) compares) and expand the boolean run mask — the

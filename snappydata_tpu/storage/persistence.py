@@ -40,6 +40,7 @@ import numpy as np
 
 from snappydata_tpu import types as T
 from snappydata_tpu.fault import failpoints
+from snappydata_tpu.observability import tracing
 from snappydata_tpu.reliability import failpoints as rfail
 from snappydata_tpu.storage.batch import ColumnBatch
 from snappydata_tpu.storage.encoding import (ColumnStats, EncodedColumn,
@@ -850,7 +851,19 @@ class DiskStore:
 
         Group commit: the framed record lands in the commit buffer; the
         covering fsync is released by wal_sync(seq) — callers MUST gate
-        their ack on it (session/_journal_then/flight do_put all do)."""
+        their ack on it (session/_journal_then/flight do_put all do).
+
+        Traced as span `wal_append` (attr `bytes`: the framed record),
+        opened HERE so every journaling path carries the same name."""
+        with tracing.span("wal_append") as sp:
+            seq, nbytes = self._wal_append(table, kind, sql, params,
+                                           arrays, nulls, extra)
+            sp.set("bytes", nbytes)
+            return seq
+
+    def _wal_append(self, table, kind, sql, params, arrays, nulls,
+                    extra) -> Tuple[int, int]:
+        """(seq, framed bytes) of the appended record."""
         mode, _group_s, buffer_bytes = self._wal_policy()
         rfail.hit("wal.append")
         spec = failpoints.hit("wal.append")   # per-RECORD failpoint:
@@ -921,7 +934,7 @@ class DiskStore:
             # from here the caller applies: losing this record later
             # (failed drain) means memory-exceeds-journal divergence
             self._returned_seq = max(self._returned_seq, seq)
-        return seq
+        return seq, len(raw)
 
     def _flush_pending_torn(self) -> None:
         """Write queued torn groups (crash mid-append). Caller holds
@@ -994,7 +1007,14 @@ class DiskStore:
         targets everything appended so far. In `interval` mode the ack is
         relaxed (returns immediately) unless `force=True` — network
         surfaces (Flight do_put, replica fan-out) force it so a remote
-        ack always implies durability."""
+        ack always implies durability.
+
+        Traced as span `wal_sync` (attr `forced`): the ack's wait for
+        the covering fsync, ~0 where the flusher got there first."""
+        with tracing.span("wal_sync", forced=bool(force)):
+            self._wal_sync(seq, force)
+
+    def _wal_sync(self, seq: Optional[int], force: bool) -> None:
         mode, _group_s, _bb = self._wal_policy()
         with self._lock:
             barrier = seq is None

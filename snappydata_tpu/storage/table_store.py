@@ -530,18 +530,31 @@ class ColumnTableData:
         return BatchView(batch)
 
     def _rollover_locked(self) -> List[BatchView]:
-        arrays, nulls, cnt = self._row_buffer.snapshot()
-        self._row_buffer.clear()
-        out = []
-        pos = 0
-        while pos < cnt:
-            take = min(self.capacity, cnt - pos)
-            sl = slice(pos, pos + take)
-            out.append(self._cut_batch(
-                [a[sl] for a in arrays],
-                [m[sl] if m is not None else None for m in nulls]))
-            pos += take
-        return out
+        """Cut the row buffer into column batches.  Traced as span
+        `rollover` (a child of the write's `apply`): `rows`,
+        `batches_cut`, and `dict_entries_copied` — every cut batch
+        copies each string column's WHOLE table dictionary
+        (`_cut_batch` / `_intern_strings`), the cost that makes an
+        insert that rolls over stall."""
+        from snappydata_tpu.observability import tracing
+
+        with tracing.span("rollover") as sp:
+            arrays, nulls, cnt = self._row_buffer.snapshot()
+            self._row_buffer.clear()
+            out = []
+            pos = 0
+            while pos < cnt:
+                take = min(self.capacity, cnt - pos)
+                sl = slice(pos, pos + take)
+                out.append(self._cut_batch(
+                    [a[sl] for a in arrays],
+                    [m[sl] if m is not None else None for m in nulls]))
+                pos += take
+            sp.set("rows", int(cnt))
+            sp.set("batches_cut", len(out))
+            sp.set("dict_entries_copied",
+                   len(out) * sum(len(d) for d in self._dicts.values()))
+            return out
 
     # --- schema evolution (ref: AlterTableAddColumnCommand /
     # AlterTableDropColumnCommand, SnappySession.alterTable:1628; we extend
