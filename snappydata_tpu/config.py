@@ -110,16 +110,21 @@ class Properties:
     scan_compressed_domain: str = "auto"
     # Aggregate-on-codes (engine/executor._emit_aggregate +
     # ops/code_agg.py): SUM/AVG over a VALUE_DICT column reduces in
-    # DICTIONARY SPACE — one bincount over the small integer codes per
-    # (group, batch) then an O(D) dot with the per-batch dictionaries —
-    # instead of gathering N decoded values (the "GPU Acceleration of
-    # SQL Analytics on Compressed Data" formulation). Group keys that
-    # are dict/RLE-encoded already group by pure code arithmetic
-    # regardless of this knob (counted agg_code_domain); this knob only
-    # gates the value-side bincount-dot, whose win is bandwidth-bound
-    # (TPU) but scatter-bound on CPU XLA.
+    # DICTIONARY SPACE — row counts per (group, batch, code) cell from a
+    # per-batch one-hot product over the small integer codes, then an
+    # O(D) contraction with the per-batch dictionaries — instead of
+    # gathering N decoded values (the "GPU Acceleration of SQL Analytics
+    # on Compressed Data" formulation). Group keys that are dict/RLE-
+    # encoded already group by pure code arithmetic regardless of this
+    # knob (counted agg_code_domain); this knob only gates the
+    # value-side count-and-contract, which the TPU runs on the MXU at
+    # the rate it reads the codes; the CPU backend materialises the
+    # one-hots, which costs more than the gather it saves. The lane
+    # engages while padded groups x dictionary width stays inside
+    # code_agg.DICT_SPACE_MAX_PRODUCT; past it the slot rides the packed
+    # families.
     #   auto  engage on TPU backends, stay on the gather path on CPU
-    #   on    engage everywhere eligibility holds (bench uses this)
+    #   on    engage everywhere eligibility holds (tests and bench.py)
     #   off   always gather decoded values
     # Rides the compiled plan's static key: flipping re-specializes,
     # no cache flush. Counted agg_dict_space per engaged execution.

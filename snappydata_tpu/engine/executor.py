@@ -593,11 +593,12 @@ class CompiledPlan:
                 self._jitted_main[static] = fn
             with tracing.span("jit_compile" if first
                               else "device_execute", phase="main",
-                              xla_compiles=0), \
+                              xla_compiles=0) as sp, \
                     _dispatch_scope():
                 outs = _settle(self._noted_call(
                     static, "main", fn,
                     (tuple(arrays), tuple(aux), pvals, pre)))
+                self._note_slots(sp, static)
             # a gidx-cache hit SKIPPED the pre pass — its code predicates
             # didn't run this execution (review finding: they were
             # re-counted in proportion to the hit rate)
@@ -610,14 +611,26 @@ class CompiledPlan:
                 fn = jax.jit(self._jit_target(self.traced, static))
                 self._jitted[static] = fn
             with tracing.span("jit_compile" if first
-                              else "device_execute", xla_compiles=0), \
+                              else "device_execute",
+                              xla_compiles=0) as sp, \
                     _dispatch_scope():
                 outs = _settle(self._noted_call(
                     static, "single", fn,
                     (tuple(arrays), tuple(aux), pvals)))
+                self._note_slots(sp, static)
             self._count_compressed(reg, static, ("single",))
         self._count_agg_notes(reg, static)
         return tables, outs
+
+    def _note_slots(self, sp, static) -> None:
+        """The main dispatch span says how its aggregate slots reduced,
+        from the trace-time notes (so after the call that may trace): how
+        many the dictionary-space lane took, and how many of any family
+        were emitted as a `segment_*` scatter.  0 where none, and on a
+        plan that aggregates nothing."""
+        note = self.agg_notes.get(static) if self.agg_notes else None
+        for key in ("dict_space_slots", "scatter_slots"):
+            sp.set(key, note[key] if note else 0)
 
     def _count_agg_notes(self, reg, static) -> None:
         """Per-execution metrics from the trace-time aggregate notes:
@@ -2739,14 +2752,28 @@ class Compiler:
             # plan must never iterate a set another thread's in-flight
             # trace is still mutating
             note = {"passes": 0, "strategies": set(), "lanes": set(),
-                    "rle_fallbacks": 0}
+                    "rle_fallbacks": 0, "dict_space_slots": 0,
+                    "scatter_slots": 0}
             tok = ctx.static[code_agg_si]
-            # dictionary-space SUM is a scatter-heavy lane: auto keeps
-            # it off the (serial-scatter) CPU backend; "on" forces it
-            # everywhere, "off" kills it.  The code-domain group index
-            # and run-space lanes are cheap arithmetic — only "off"
-            # disables those.
+            # dictionary-space SUM counts by a one-hot product shaped
+            # for the MXU: auto engages it on the accelerator only (the
+            # CPU backend materialises the one-hots, which costs more
+            # than the gather it saves); "on" forces it everywhere,
+            # "off" kills it.  The code-domain group index and run-space
+            # lanes are cheap arithmetic — only "off" disables those.
             code_agg_on = tok == 2 or (tok == 1 and backend != "cpu")
+
+            def dict_space_takes(cpl) -> bool:
+                return (cpl is not None and code_agg_on
+                        and code_agg.dict_space_engages(
+                            nseg, cpl.codes.shape, cpl.dicts.shape))
+
+            def family_pass(strategy: str, nslots: int) -> None:
+                """One packed family reduced `nslots` aggregate slots."""
+                note["passes"] += 1
+                note["strategies"].add(strategy)
+                if strategy == "scatter":
+                    note["scatter_slots"] += nslots
             rle_ok = (tok != 0 and rle_gate_si is not None
                       and bool(ctx.static[rle_gate_si])
                       and jnp.ndim(out.valid) == 2)
@@ -2813,11 +2840,7 @@ class Compiler:
                         and v.dtype == jnp.float32)
                     if not eligible:
                         continue
-                    if (kind == "sum" and code_agg_on
-                            and _cpl is not None
-                            and code_agg.dict_space_cells(
-                                nseg, _cpl.codes.shape, _cpl.dicts.shape)
-                            <= code_agg.DICT_SPACE_MAX_CELLS):
+                    if kind == "sum" and dict_space_takes(_cpl):
                         # the dictionary-space lane below takes this
                         # slot — it never gathers the value plate
                         continue
@@ -2898,6 +2921,7 @@ class Compiler:
                     slot_arrays[i] = jax.ops.segment_sum(
                         new.astype(jnp.int64), g_s, num_segments=nseg)
                     note["passes"] += 1
+                    note["scatter_slots"] += 1
                 elif kind == "sum":
                     acc_dt = _acc_dtype(sdt, jnp.asarray(v).dtype)
                     # run-space SUM: Σ value·length over surviving runs
@@ -2920,20 +2944,19 @@ class Compiler:
                         note["strategies"].add("rle_runs")
                         note["lanes"].add("rle_runs")
                         continue
-                    # dictionary-space SUM: bincount codes into the
-                    # (group, batch, code) space, contract with the
-                    # dictionary stack — the value plate is never
-                    # gathered (ops/code_agg.py)
-                    if (cpl is not None and code_agg_on
-                            and acc_dt != jnp.int64
-                            and code_agg.dict_space_cells(
-                                nseg, cpl.codes.shape, cpl.dicts.shape)
-                            <= code_agg.DICT_SPACE_MAX_CELLS):
+                    # dictionary-space SUM: count codes per (group,
+                    # batch, code) cell by a one-hot product, contract
+                    # with the dictionary stack — the value plate is
+                    # never gathered (ops/code_agg.py).  Past the
+                    # lane's groups x dictionary bound the slot rides
+                    # the packed families below.
+                    if acc_dt != jnp.int64 and dict_space_takes(cpl):
                         slot_arrays[i] = code_agg.dict_space_sum(
                             cpl.codes, cpl.dicts, gidx, w, nseg)
                         note["passes"] += 1
                         note["strategies"].add("dict_space")
                         note["lanes"].add("dict_space")
+                        note["dict_space_slots"] += 1
                         continue
                     if (not groups and v.dtype == jnp.float32
                             and config.global_properties().pallas_reduce):
@@ -3013,8 +3036,7 @@ class Compiler:
                                     else jnp.where(w, 1.0, 0.0))
                 res = reduction.packed_sum(cols, gidx, num_groups,
                                            fsum_strat, onehot=onehot)
-                note["passes"] += 1
-                note["strategies"].add(fsum_strat)
+                family_pass(fsum_strat, len(fsum_cols))
                 for pos, (i, _) in enumerate(fsum_cols):
                     slot_arrays[i] = res[:, pos]
                 if join_counts:
@@ -3029,8 +3051,7 @@ class Compiler:
                 count_res = reduction.packed_sum(
                     [w.astype(cdt) for w in count_ws], gidx, num_groups,
                     fsum_strat).astype(jnp.int64)
-                note["passes"] += 1
-                note["strategies"].add(fsum_strat)
+                family_pass(fsum_strat, len(count_users))
             for i, c in count_users:
                 slot_arrays[i] = count_res[:, c]
             if isum_cols:
@@ -3038,8 +3059,7 @@ class Compiler:
                     req, backend, num_groups, n, "isum", jnp.int64)
                 ires = reduction.packed_sum(
                     [c for _, c in isum_cols], gidx, num_groups, istrat)
-                note["passes"] += 1
-                note["strategies"].add(istrat)
+                family_pass(istrat, len(isum_cols))
                 for pos, (i, _) in enumerate(isum_cols):
                     slot_arrays[i] = ires[:, pos]
             guard_res: Dict[tuple, object] = {}
@@ -3050,8 +3070,7 @@ class Compiler:
                     mcols[0].dtype)
                 mres = reduction.packed_minmax(mkind, mcols, gidx,
                                                num_groups, mstrat)
-                note["passes"] += 1
-                note["strategies"].add(mstrat)
+                family_pass(mstrat, sum(t[0] == "slot" for t, _ in entries))
                 for pos, (tag, _) in enumerate(entries):
                     if tag[0] == "slot":
                         slot_arrays[tag[1]] = mres[:, pos]
@@ -3161,6 +3180,8 @@ class Compiler:
                 "strategies": frozenset(note["strategies"]),
                 "lanes": frozenset(note["lanes"]),
                 "rle_fallbacks": note["rle_fallbacks"],
+                "dict_space_slots": note["dict_space_slots"],
+                "scatter_slots": note["scatter_slots"],
                 "table": base_table_ref}
             # nested data-dependent overflows (join expansion past its
             # bucket) ride the same flag: the executor reruns on host

@@ -308,3 +308,159 @@ def test_mesh_lane_matches_single_device():
         _assert_rows_equal(mesh_on, single)
         _assert_rows_equal(mesh_off, single)
     s.stop()
+
+
+# ----------------------------------------------------------------------
+# PR 26: the count step is a per-batch one-hot product, not a scatter
+# ----------------------------------------------------------------------
+
+_CAP = 512
+
+
+def _count_inputs(b, dp, code_dtype, nseg, seed):
+    """Codes, dictionaries, group index and weights with every kind of
+    row the count step must leave out: `w` false, the dump segment, and
+    one batch that is padding from end to end."""
+    rng = np.random.default_rng(seed)
+    codes = rng.integers(0, dp, (b, _CAP)).astype(code_dtype)
+    dicts = np.sort(rng.random((b, dp)) * 1e3, axis=1)
+    gidx = rng.integers(0, nseg, b * _CAP).astype(np.int32)  # dump incl.
+    w = rng.random(b * _CAP) < 0.9
+    pad = b // 2                      # a wholly padded batch
+    gidx[pad * _CAP:(pad + 1) * _CAP] = nseg - 1
+    w[pad * _CAP:(pad + 1) * _CAP] = False
+    codes[pad] = 0
+    return codes, dicts, gidx, w
+
+
+@pytest.mark.parametrize("b, batches_a_step", [
+    (6, 6), (6, 3), (7, 3)], ids=["one_step", "steps_divide", "steps_pad"])
+@pytest.mark.parametrize("dp, code_dtype, nseg", [
+    (16, np.uint8, 9), (64, np.uint8, 9), (256, np.uint8, 2),
+    (256, np.uint16, 9), (1024, np.uint16, 4)],
+    ids=["d16_u8", "d64_u8", "d256_u8_global", "d256_u16", "d1024_u16"])
+def test_dict_space_counts_equal_bincount(monkeypatch, dp, code_dtype,
+                                          nseg, b, batches_a_step):
+    """counts[g, b, c] == np.bincount exactly, whatever the chunking of
+    the batch axis; the sums equal the decoded path's to 1e-12."""
+    import jax.numpy as jnp
+
+    from snappydata_tpu.ops import code_agg
+
+    ngroups = nseg - 1
+    monkeypatch.setattr(
+        code_agg, "DICT_SPACE_CHUNK_BYTES",
+        batches_a_step * _CAP * (ngroups + dp) * 2)
+    step = code_agg._chunk_batches(b, _CAP, ngroups, dp)
+    assert step == batches_a_step
+    assert (b % step != 0) == (b == 7)
+    codes, dicts, gidx, w = _count_inputs(b, dp, code_dtype, nseg,
+                                          seed=dp + b)
+    got = np.asarray(code_agg.dict_space_counts(
+        jnp.asarray(codes), jnp.asarray(gidx), jnp.asarray(w), nseg, dp))
+    keep = w & (gidx < ngroups)
+    batch = np.repeat(np.arange(b), _CAP)
+    joint = (gidx.astype(np.int64) * b + batch) * dp + codes.reshape(-1)
+    want = np.bincount(joint[keep], minlength=ngroups * b * dp) \
+        .reshape(ngroups, b, dp)
+    assert got.shape == want.shape
+    assert (got == want).all()
+    assert got[:, b // 2].sum() == 0          # the padded batch
+    sums = np.asarray(code_agg.dict_space_sum(
+        jnp.asarray(codes), jnp.asarray(dicts), jnp.asarray(gidx),
+        jnp.asarray(w), nseg))
+    vals = np.take_along_axis(dicts, codes.astype(np.int64), 1).reshape(-1)
+    decoded = np.bincount(gidx[keep], weights=vals[keep],
+                          minlength=ngroups)
+    assert sums.shape == (nseg,) and sums[-1] == 0.0
+    np.testing.assert_allclose(sums[:ngroups], decoded, rtol=1e-12)
+
+
+def _hlo_of_main(monkeypatch, s, sql):
+    """Compiled HLO text of the statement's main (or single) phase, and
+    the plan's trace-time aggregate note."""
+    from snappydata_tpu.engine.executor import CompiledPlan
+
+    seen = []
+    orig = CompiledPlan._noted_call
+
+    def spy(self, static, phase, fn, args):
+        seen.append((self, static, phase, fn, args))
+        return orig(self, static, phase, fn, args)
+
+    monkeypatch.setattr(CompiledPlan, "_noted_call", spy)
+    s.sql(sql).rows()
+    monkeypatch.setattr(CompiledPlan, "_noted_call", orig)
+    plan, static, _phase, fn, args = [
+        x for x in seen if x[2] in ("main", "single")][-1]
+    return fn.lower(*args).compile().as_text(), plan.agg_notes[static]
+
+
+def _scatters_under(hlo: str, scope: str):
+    return [ln for ln in hlo.splitlines()
+            if " scatter(" in ln and f"/{scope}/" in ln]
+
+
+def test_q1_plan_holds_no_scatter_under_group_reduce(monkeypatch):
+    """The Q1-shaped plan as the chip specializes it (float32 plates,
+    the lane on, the small-G families on `unroll`): no `scatter` op is
+    left under `group_reduce`.  The control is the same plan with the
+    families forced onto `scatter`, where the guard finds them."""
+    from snappydata_tpu.utils import tpch
+
+    props = _props()
+    saved = (props.decimal_as_float64, props.agg_reduce_strategy)
+    try:
+        props.decimal_as_float64 = False
+        props.agg_reduce_strategy = "unroll"
+        props.set("agg_on_codes", "on")
+        s = SnappySession(catalog=Catalog())
+        tpch.load_tpch(s, sf=0.002, seed=11)
+        hlo, note = _hlo_of_main(monkeypatch, s, tpch.Q1)
+        assert note["dict_space_slots"] == 2 and note["scatter_slots"] == 0
+        assert "dict_space" in note["lanes"]
+        assert "/group_reduce/" in hlo
+        assert _scatters_under(hlo, "group_reduce") == []
+        props.agg_reduce_strategy = "scatter"
+        hlo, note = _hlo_of_main(monkeypatch, s, tpch.Q1)
+        # the lane's two slots still count without one; the packed
+        # families' do not
+        assert note["dict_space_slots"] == 2 and note["scatter_slots"] > 0
+        assert _scatters_under(hlo, "group_reduce")
+        s.stop()
+    finally:
+        props.decimal_as_float64, props.agg_reduce_strategy = saved
+
+
+def test_past_the_product_bound_the_slot_rides_the_packed_family():
+    """8 padded groups x 16,384 codes is past DICT_SPACE_MAX_PRODUCT: the
+    lane does not engage even forced on, the slot reduces with the
+    packed family (counted), and the answer is the decoded one.  A
+    narrow dictionary on the same table still engages."""
+    from snappydata_tpu.ops import code_agg
+
+    n = 100_000
+    rng = np.random.default_rng(3)
+    s = SnappySession(catalog=Catalog())
+    s.sql("CREATE TABLE wide (g BIGINT, q DOUBLE, r DOUBLE) USING column")
+    s.insert_arrays("wide", [
+        rng.integers(0, 6, n).astype(np.int64),
+        rng.integers(0, 10_000, n).astype(np.float64) / 8,
+        rng.integers(0, 40, n).astype(np.float64) / 8])
+    assert not code_agg.dict_space_engages(7, (1, n), (1, 16384))
+    assert code_agg.dict_space_engages(7, (1, n), (1, 64))
+    assert not code_agg.dict_space_engages(
+        7, (1, code_agg.DICT_SPACE_MAX_CAP + 1), (1, 64))
+    c0 = _counters()
+    on, off = _both(s, "SELECT g, sum(q), count(*) FROM wide "
+                       "GROUP BY g ORDER BY g")
+    _assert_rows_equal(on, off)
+    assert _delta(c0, "agg_dict_space") == 0
+    # both runs reduced through a packed family: passes were counted
+    assert _delta(c0, "agg_reduce_passes") >= 2
+    c0 = _counters()
+    on, off = _both(s, "SELECT g, sum(r), count(*) FROM wide "
+                       "GROUP BY g ORDER BY g")
+    _assert_rows_equal(on, off)
+    assert _delta(c0, "agg_dict_space") == 1
+    s.stop()

@@ -1,0 +1,67 @@
+"""Kernels of the main path compiled for the chip without the chip: the
+TPU compiler is installed here and compiles for a described v5e, so what
+it refuses, and what it would hold in HBM, is known before a chip run.
+Nothing runs: no result, no time.
+
+The topology is described inside a fixture, never at import or
+collection: only the worker that is handed this file loads the TPU's
+library.  Keep every such test in this one file.
+"""
+
+import os
+
+import pytest
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # noqa: BLE001 - whatever keeps libtpu out
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _dict_space_compiled(one_chip, batches, cap, dp, nseg):
+    import jax
+    import jax.numpy as jnp
+
+    from snappydata_tpu.ops import code_agg
+
+    def shape(dims, dt):
+        return jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
+
+    return jax.jit(
+        lambda c, d, g, w: code_agg.dict_space_sum(c, d, g, w, nseg)
+    ).lower(shape((batches, cap), jnp.uint8),
+            shape((batches, dp), jnp.float32),
+            shape((batches * cap,), jnp.int32),
+            shape((batches * cap,), jnp.bool_)).compile()
+
+
+@pytest.mark.parametrize("dp", [16, 64, 256])
+def test_dict_space_count_transient_does_not_grow_with_batches(one_chip,
+                                                               dp):
+    """Q1's shape at SF 2 (96 batches of 131,072 rows, 8 groups) and at
+    the documented SF 16 (768): the compiled count step holds no
+    scatter, and its temporaries stay inside the chunk budget and do
+    not grow with the batch count (materialised one-hots would be
+    1.8 GB and 14.5 GB at 64 codes; a masked copy of the group indexes
+    0.05 and 0.4 GB)."""
+    from snappydata_tpu.ops import code_agg
+
+    cap, nseg = 131072, 9
+    temps = []
+    for batches in (96, 768):
+        comp = _dict_space_compiled(one_chip, batches, cap, dp, nseg)
+        assert " scatter(" not in comp.as_text()
+        temps.append(comp.memory_analysis().temp_size_in_bytes)
+    # each step slices its own rows, so nothing is sized by the table
+    assert max(temps) <= code_agg.DICT_SPACE_CHUNK_BYTES, temps
+    assert temps[1] <= temps[0] + (1 << 20), temps

@@ -426,3 +426,111 @@ def test_op_scope_takes_names_of_the_list_only():
         pass
     with pytest.raises(ValueError):
         tracing.op_scope("fliter")
+
+
+# ----------------------------------------------------------------------
+# D. the main dispatch says how its aggregate slots reduced (PR 26)
+# ----------------------------------------------------------------------
+
+def _main_dispatch(root: dict) -> dict:
+    """The statement's main dispatch span: `phase` "main", or the
+    single-phase span, which carries no phase."""
+    found = [sp for sp, _ in _walk(root)
+             if sp["name"] in ("device_execute", "jit_compile")
+             and sp.get("attrs", {}).get("phase", "main") == "main"]
+    assert len(found) == 1, [sp["name"] for sp, _ in _walk(root)]
+    return found[0]
+
+
+@pytest.mark.parametrize("label, lane, strategy, want", [
+    ("q1", "on", "auto", (2, 0)),
+    ("q6", "on", "auto", (0, 0)),
+    ("q1", "off", "auto", (0, 0)),
+    # Q1's nine slots: two in dictionary space, three float sums and
+    # four counts through the packed families
+    ("q1", "on", "scatter", (2, 7)),
+], ids=["q1_lane", "q6", "q1_lane_off", "q1_families_on_scatter"])
+def test_main_dispatch_carries_slot_counts(_restore_knobs, label, lane,
+                                           strategy, want):
+    """`dict_space_slots` / `scatter_slots` on the main dispatch span,
+    cold (`jit_compile`) and warm (`device_execute`), 0 where none."""
+    from snappydata_tpu.utils import tpch
+
+    props = _restore_knobs
+    props.decimal_as_float64 = False
+    saved = (props.get("agg_on_codes"), props.agg_reduce_strategy)
+    try:
+        props.set("agg_on_codes", lane)
+        props.agg_reduce_strategy = strategy
+        s = SnappySession(catalog=Catalog())
+        tpch.load_tpch(s, sf=0.002, seed=7)
+        sql = {"q1": tpch.Q1, "q6": tpch.Q6}[label]
+        for expect_name in ("jit_compile", "device_execute"):
+            s.sql(sql).rows()
+            sp = _main_dispatch(tracing.ring().last().to_dict()["root"])
+            assert sp["name"] == expect_name
+            assert (sp["attrs"]["dict_space_slots"],
+                    sp["attrs"]["scatter_slots"]) == want
+        s.stop()
+    finally:
+        props.set("agg_on_codes", saved[0])
+        props.agg_reduce_strategy = saved[1]
+
+
+def test_slot_metrics_read_the_attrs():
+    """The two metric files PR 26 added, through the benchmark's own
+    manifest and `span_attr` reader on hand-built trees: a median over
+    the window's queries; None, not an error, on a program that does
+    not set the attrs."""
+    import json
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    bench = os.path.join(root, "benchmark")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    import manifest
+
+    m = manifest.Manifest(root)
+    assert manifest.problems(m) == []
+    names = ["dict_space_slots.scan", "scatter_slots.scan"]
+    assert [p["name"] for p in m.doc["per_layer"]][-2:] == names
+    for n in names:
+        entry = next(p for p in m.doc["per_layer"] if p["name"] == n)
+        assert entry["workloads"] == ["tpch_sf2.scan"]
+        with open(os.path.join(bench, "metrics", n + ".json")) as f:
+            mf = json.load(f)
+        for key in ("name", "unit", "better", "source", "layer", "moves"):
+            assert mf[key] == entry[key], (n, key)
+        assert mf["reader"] == "span_attr"
+
+    def stmt(name, kind, **attrs):
+        dispatch = {"name": "device_execute", "ms": 2.0}
+        if attrs:
+            dispatch["attrs"] = attrs
+        return {"name": name, "kind": kind, "ok": True, "ms": 1.0,
+                "traces": [{"kind": "embedded", "root": {
+                    "name": "request", "ms": 100.0, "children": [
+                        {"name": "bind", "ms": 1.0}, dispatch,
+                        {"name": "transfer", "ms": 90.0}]}}]}
+
+    def read(statements):
+        c = {"statements": statements, "back": "embedded",
+             "front": "embedded"}
+        return [m.read(n, c) for n in names]
+
+    q1 = stmt("q1", "query", phase="main", xla_compiles=0,
+              dict_space_slots=2, scatter_slots=0)
+    q6 = stmt("q6", "query", xla_compiles=0, dict_space_slots=0,
+              scatter_slots=0)
+    assert read([q1, q6, q1, q6]) == [1.0, 0.0]
+    assert read([q1, q1, q6]) == [2, 0]
+    # the parent's scatter form, had it carried the attrs
+    old_q1 = stmt("q1", "query", dict_space_slots=2, scatter_slots=2)
+    assert read([old_q1, q6]) == [1.0, 1.0]
+    # other kinds of statement are left out; a program without the
+    # attrs (the parent as it is) reads None
+    put = stmt("rf1", "insert_rows", dict_space_slots=9, scatter_slots=9)
+    assert read([put, q1, q6]) == [1.0, 0.0]
+    bare = stmt("q1", "query", xla_compiles=0)
+    assert read([bare, bare]) == [None, None]
