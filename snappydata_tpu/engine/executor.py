@@ -318,7 +318,8 @@ class CompiledPlan:
                  traced_main: Optional[Callable] = None,
                  agg_notes: Optional[Dict] = None,
                  tile_merge: Optional[Dict] = None,
-                 kind: str = "plan"):
+                 kind: str = "plan",
+                 join_notes: Optional[Dict] = None):
         self.relations = relations
         # what the plan IS (agg / global_agg / scan, join_ in front when
         # it joins): the stem of the names its jitted functions carry
@@ -335,6 +336,9 @@ class CompiledPlan:
         # trace-time notes per static key: chosen reduction strategies +
         # fused dispatch count, surfaced as per-execution metrics
         self.agg_notes = agg_notes
+        # trace-time notes of the plan's joins per static key (how many
+        # lowered, probe keys searched, expanded output slots)
+        self.join_notes = join_notes
         # partial-raw merge metadata: per-output merge ops + group-card
         # check for the tiled scan's on-device partial merge
         self.tile_merge = tile_merge
@@ -408,8 +412,12 @@ class CompiledPlan:
             # small per-execution puts below, and host time inside those
             # calls.  Attrs, not a child span: `bind`'s self time is
             # what readers of this span have always read.
+            # Join builds this bind sorted or found cached
+            # (ops/join.build_artifact counts them here): 0 on a plan
+            # that joins nothing.
             for key in ("upload_bytes", "upload_ms", "plates_built",
-                        "plates_cached"):
+                        "plates_cached", "join_builds_sorted",
+                        "join_builds_cached"):
                 sp.attrs.setdefault(key, 0)
             sp.attrs["upload_ms"] = round(sp.attrs["upload_ms"], 4)
             return out
@@ -507,8 +515,9 @@ class CompiledPlan:
         return tables, arrays, aux, static, pvals
 
     def _run_device(self, params: Tuple):
-        """Bind + dispatch; returns (tables, outs) with outs still ON
-        DEVICE (async) — callers decide when/whether to transfer.
+        """Bind + dispatch; returns (tables, outs, main dispatch span)
+        with outs still ON DEVICE (async) — callers decide when/whether
+        to transfer.
 
         Under an active mesh every dispatch serializes on
         parallel.mesh.dispatch_lock and BLOCKS to completion inside the
@@ -620,17 +629,28 @@ class CompiledPlan:
                 self._note_slots(sp, static)
             self._count_compressed(reg, static, ("single",))
         self._count_agg_notes(reg, static)
-        return tables, outs
+        return tables, outs, sp
 
     def _note_slots(self, sp, static) -> None:
         """The main dispatch span says how its aggregate slots reduced,
         from the trace-time notes (so after the call that may trace): how
         many the dictionary-space lane took, and how many of any family
         were emitted as a `segment_*` scatter.  0 where none, and on a
-        plan that aggregates nothing."""
+        plan that aggregates nothing.  `group_slots` is the static
+        number of group segments the reduce ran over."""
         note = self.agg_notes.get(static) if self.agg_notes else None
-        for key in ("dict_space_slots", "scatter_slots"):
+        for key in ("dict_space_slots", "scatter_slots", "group_slots"):
             sp.set(key, note[key] if note else 0)
+        # and what its joins were: how many lowered to the device, the
+        # probe keys they searched (the probe side's padded slots, one
+        # search a join) and the expanded output slots of one-to-many
+        # builds (0 where every build is unique)
+        jnote = self.join_notes.get(static) if self.join_notes else None
+        for key in _JOIN_NOTE_KEYS:
+            sp.set(key, jnote[key] if jnote else 0)
+        # 1 once the outputs are home and the overflow flag is set
+        # (CompiledPlan.execute): the statement then reruns on the host
+        sp.set("groups_overflow", 0)
 
     def _count_agg_notes(self, reg, static) -> None:
         """Per-execution metrics from the trace-time aggregate notes:
@@ -672,9 +692,10 @@ class CompiledPlan:
         already queued behind the program), `copy_ms` (`jax.device_get`
         of outputs already complete: what is left of the copy) and
         `bytes` copied.  The span's extent is what it has always been."""
-        tables, outs = self._run_device(params)
+        tables, outs, dispatch = self._run_device(params)
         outs = _transfer(outs)
         if bool(np.asarray(outs[2])):
+            dispatch.set("groups_overflow", 1)
             raise CompileError(
                 "device overflow (group-by cardinality beyond max_groups, "
                 "an exact-decimal sum at int64 risk, or a join expansion "
@@ -685,7 +706,7 @@ class CompiledPlan:
         """Run the compiled region and return (mask, pairs, overflow)
         still on device — the tiled scan merges per-tile partials there
         instead of round-tripping each tile through the host."""
-        _tables, outs = self._run_device(params)
+        _tables, outs, _dispatch = self._run_device(params)
         return outs
 
     def execute_batched(self, params_list: Sequence[Tuple]):
@@ -1235,8 +1256,14 @@ class Compiler:
                 rel_runtimes.append((cols, valid))
             return _TraceCtx(rel_runtimes, aux, params, static)
 
+        join_notes = {} if n_rel > 1 else None
+
         def traced(static, arrays, aux, params):
-            return emitter(make_ctx(static, arrays, aux, params))
+            ctx = make_ctx(static, arrays, aux, params)
+            outs = emitter(ctx)
+            if join_notes is not None:
+                join_notes[static] = ctx.join_note
+            return outs
 
         traced_pre = traced_main = None
         pre_emit = getattr(self, "_agg_pre_emit", None)
@@ -1259,6 +1286,7 @@ class Compiler:
                           traced_pre=traced_pre, traced_main=traced_main,
                           agg_notes=getattr(self, "_agg_notes", None),
                           tile_merge=getattr(self, "_tile_merge", None),
+                          join_notes=join_notes,
                           kind=("join_" if n_rel > 1 else "")
                           + (("agg" if plan.group_exprs else "global_agg")
                              if is_agg else "scan")
@@ -2101,8 +2129,14 @@ class Compiler:
         })
 
         def run_join(ctx) -> RelOut:
-            lo = left(ctx)
-            ro = right(ctx)
+            return join_body(ctx, left(ctx), right(ctx))
+
+        # everything of this join is under `join` in the HLO's op_name;
+        # its parts (ops/join.py) nest their own names
+        @tracing.op_scope("join")
+        def join_body(ctx, lo, ro) -> RelOut:
+            ctx.join_note["join_device_joins"] += 1
+            ctx.join_note["join_probe_rows"] += int(lo.valid.size)
             lpairs = [lo.cols[k] for k, _ in equi]
             rpairs = [ro.cols[k - nleft] for _, k in equi]
             # translate left string codes into right code space first
@@ -2193,13 +2227,14 @@ class Compiler:
                 cols: Dict[int, DVal] = dict(lo.cols)
                 for i in sorted(ro.cols.keys()):
                     src = ro.cols[i]
-                    flat_v = _broadcast_to_mask(src.value, ro.valid) \
-                        .reshape(-1)
-                    gv = flat_v[bpos]
-                    gnull = None
-                    if src.null is not None:
-                        gnull = _broadcast_to_mask(src.null, ro.valid) \
-                            .reshape(-1)[bpos]
+                    with tracing.op_scope("join_gather"):
+                        flat_v = _broadcast_to_mask(src.value, ro.valid) \
+                            .reshape(-1)
+                        gv = flat_v[bpos]
+                        gnull = None
+                        if src.null is not None:
+                            gnull = _broadcast_to_mask(
+                                src.null, ro.valid).reshape(-1)[bpos]
                     if how == "left":
                         gnull = _or_null(gnull, ~found)
                     cols[nleft + i] = DVal(gv, gnull, src.dtype,
@@ -2223,6 +2258,7 @@ class Compiler:
                                            jnp.int64(0))
                 else:
                     counts_eff = counts_f
+                ctx.join_note["join_expand_out_rows"] += int(bucket)
                 probe_of, rank, matched, slot_valid, total = _dj.expand(
                     counts_f, counts_eff, bucket)
                 bpos = locate(base_f[probe_of], rank)
@@ -2245,9 +2281,10 @@ class Compiler:
                     if isinstance(dv.value, tuple):
                         raise CompileError("array-plate column through "
                                            "an expanding join: host path")
-                    v, nl = flat_pair(dv, lo.valid)
-                    gv = v[probe_of]
-                    gnull = nl[probe_of] if nl is not None else None
+                    with tracing.op_scope("join_gather"):
+                        v, nl = flat_pair(dv, lo.valid)
+                        gv = v[probe_of]
+                        gnull = nl[probe_of] if nl is not None else None
                     if ext:  # build-extension slots: left side is NULL
                         gv = jnp.concatenate(
                             [gv, jnp.zeros((F,), gv.dtype)])
@@ -2268,9 +2305,10 @@ class Compiler:
                     if isinstance(src.value, tuple):
                         raise CompileError("array-plate column through "
                                            "an expanding join: host path")
-                    v, nl = flat_pair(src, ro.valid)
-                    gv = v[bpos]
-                    gnull = nl[bpos] if nl is not None else None
+                    with tracing.op_scope("join_gather"):
+                        v, nl = flat_pair(src, ro.valid)
+                        gv = v[bpos]
+                        gnull = nl[bpos] if nl is not None else None
                     if how in ("left", "full"):
                         gnull = _or_null(gnull, ~matched)
                     if ext:
@@ -3142,20 +3180,10 @@ class Compiler:
                                 if kd.dtype else jnp.int64))
                 else:
                     for kd in key_vals:
-                        kv = _broadcast_to_mask(kd.value, out.valid).reshape(-1)
-                        filler = _extreme(kv.dtype, False)
-                        key_arrays.append(jax.ops.segment_max(
-                            jnp.where(valid, kv, filler), gidx,
-                            num_segments=num_groups + 1)[:num_groups])
-                        if kd.null is not None:
-                            nb = _broadcast_to_mask(kd.null, out.valid) \
-                                .reshape(-1)
-                            key_nulls.append(jax.ops.segment_max(
-                                (nb & valid).astype(jnp.int32), gidx,
-                                num_segments=num_groups + 1)[:num_groups]
-                                .astype(bool))
-                        else:
-                            key_nulls.append(None)
+                        k_arr, k_null = _segment_key(
+                            kd, out.valid, valid, gidx, num_groups)
+                        key_arrays.append(k_arr)
+                        key_nulls.append(k_null)
                 key_arrays = [k[:num_groups] if k.shape[0] > num_groups else k
                               for k in key_arrays]
 
@@ -3182,6 +3210,7 @@ class Compiler:
                 "rle_fallbacks": note["rle_fallbacks"],
                 "dict_space_slots": note["dict_space_slots"],
                 "scatter_slots": note["scatter_slots"],
+                "group_slots": num_groups,
                 "table": base_table_ref}
             # nested data-dependent overflows (join expansion past its
             # bucket) ride the same flag: the executor reruns on host
@@ -3266,6 +3295,24 @@ def _slots_to_cols(e: ast.Expr, n_groups: int) -> ast.Expr:
     return e.map_children(lambda c: _slots_to_cols(c, n_groups))
 
 
+@tracing.op_scope("group_keys")
+def _segment_key(kd, mask2d, valid, gidx, num_groups):
+    """One generic GROUP BY key reduced to its value a group (every row
+    of a group holds the same), and its NULL flag where it has one."""
+    kv = _broadcast_to_mask(kd.value, mask2d).reshape(-1)
+    filler = _extreme(kv.dtype, False)
+    k_arr = jax.ops.segment_max(
+        jnp.where(valid, kv, filler), gidx,
+        num_segments=num_groups + 1)[:num_groups]
+    k_null = None
+    if kd.null is not None:
+        nb = _broadcast_to_mask(kd.null, mask2d).reshape(-1)
+        k_null = jax.ops.segment_max(
+            (nb & valid).astype(jnp.int32), gidx,
+            num_segments=num_groups + 1)[:num_groups].astype(bool)
+    return k_arr, k_null
+
+
 def _cards_of(key_infos, ctx):
     out = []
     for kind, si, _ in key_infos:
@@ -3276,6 +3323,10 @@ def _cards_of(key_infos, ctx):
         else:
             out.append(1)
     return out
+
+
+_JOIN_NOTE_KEYS = ("join_device_joins", "join_probe_rows",
+                   "join_expand_out_rows")
 
 
 class _TraceCtx:
@@ -3289,6 +3340,8 @@ class _TraceCtx:
         # it into the compiled output's third slot so the executor can
         # reroute to the exact host path
         self.overflow = jnp.asarray(False)
+        # and what the joins were (CompiledPlan._note_slots)
+        self.join_note = dict.fromkeys(_JOIN_NOTE_KEYS, 0)
 
     def aux_slice(self, builder) -> List:
         off = getattr(builder, "_aux_offset", 0)
@@ -3779,9 +3832,19 @@ class Executor:
                 return taken
 
         result = self._execute_core(node, params, plan_key)
-
-        for op in reversed(host_ops):
-            result = self._apply_host_op(op, result, params)
+        if not host_ops:
+            return result
+        # what is left above the device region (ORDER BY, LIMIT,
+        # DISTINCT, HAVING-level filters and projections over an
+        # aggregate's rows) runs here in NumPy, on the rows the region
+        # returned: a span of its own, so the host's part of a statement
+        # that "stayed on the device" is in its trace
+        with tracing.span("host_ops", rows_in=result.num_rows,
+                          ops=",".join(type(op).__name__
+                                       for op in reversed(host_ops))) as sp:
+            for op in reversed(host_ops):
+                result = self._apply_host_op(op, result, params)
+            sp.set("rows_out", result.num_rows)
         return result
 
     # -- core -------------------------------------------------------------

@@ -428,8 +428,15 @@ jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
 # HLO's op_name metadata, so a profile's device ops and an HLO dump say
 # which operator an XLA fusion came from.  Metadata only — the compiled
 # code does not change.  One list, so a trace reader can name them all.
+# A join is `join` with its parts nested inside it: `join_probe` (the
+# probe keys' binary search of the sorted build keys), `join_gather`
+# (build-side columns fetched through the match positions) and
+# `join_expand` (the one-to-many output axis); the innermost name says
+# which.  `group_keys` is a generic GROUP BY's key columns reduced to
+# one value a group.
 OP_SCOPES = ("filter", "decode", "dict_gather", "group_index",
-             "group_reduce", "join")
+             "group_reduce", "group_keys", "join", "join_probe",
+             "join_gather", "join_expand")
 
 
 def op_scope(name: str):

@@ -153,6 +153,15 @@ def clear_join_caches() -> None:
         _TRANS_CACHE.clear()
 
 
+def _count_on_bind(key: str) -> None:
+    """One more build sorted, or served from the cache, on the `bind`
+    span that asked (CompiledPlan._bind sets both to 0 on every traced
+    bind, so a reader finds a number, 0 included)."""
+    sp = tracing.current_span()
+    if sp is not None and sp.name == "bind":
+        sp.add(key, 1)
+
+
 def build_artifact(ident, token, compute: Callable[[], object]) -> dict:
     """Sorted-build artifact for one (bind identity, key signature).
 
@@ -172,15 +181,19 @@ def build_artifact(ident, token, compute: Callable[[], object]) -> dict:
             if e["ident"]() is ident:
                 e["tick"] = _next_tick()
                 reg.inc("join_build_cache_hits")
+                _count_on_bind("join_builds_cached")
                 return e
             # id() reuse after GC: the weakref proves staleness
             _BUILD_BYTES[0] -= _BUILD_CACHE.pop(key)["nbytes"]
     reg.inc("join_build_cache_misses")
+    _count_on_bind("join_builds_sorted")
     # the whole eager build — key materialization, argsort, dup probe,
     # pack — lowers to multi-device programs under a mesh (sharded
     # inputs) and fences like any other dispatch; the cache stores and
-    # counter increments stay OUTSIDE (dispatch_lock is a leaf)
-    with mesh.eager_fence():
+    # counter increments stay OUTSIDE (dispatch_lock is a leaf).  It is
+    # a span of its own under the bind that missed: a sort of the whole
+    # build side, paid once a build-side version
+    with tracing.span("join_build") as sp, mesh.eager_fence():
         bkeys = compute()
         order = jnp.argsort(bkeys).astype(jnp.int64)
         skeys = bkeys[order]
@@ -194,10 +207,14 @@ def build_artifact(ident, token, compute: Callable[[], object]) -> dict:
         # artifact through ONE aux input slot; `skeys` is kept separate
         # for the bind-time expansion bound's searchsorted
         packed = jnp.stack([skeys, order])
+        nbytes = int(skeys.nbytes) * 3
+        sp.set("build_rows", int(skeys.shape[0]))
+        sp.set("unique", unique)
+        sp.set("nbytes", nbytes)
     reg.inc("join_build_sorts")
     entry = {"skeys": skeys, "packed": packed,
              "unique": unique,
-             "nbytes": int(skeys.nbytes) * 3,
+             "nbytes": nbytes,
              "ident": weakref.ref(ident), "tick": _next_tick(),
              "bounds": {}}
     if budget <= 0 or entry["nbytes"] > budget:
@@ -307,7 +324,7 @@ def probe_expand_bound_per_shard(artifact: dict, probe_ident,
 #                each range, and the k-th passing row is located with one
 #                more searchsorted into that prefix sum.
 
-@tracing.op_scope("join")
+@tracing.op_scope("join_probe")
 def match_ranges_dense(skeys, pkeys):
     """(counts, lo) per probe key against an unfiltered sorted build;
     `lo` is in the sorted POSITION domain (k-th match at order[lo+k])."""
@@ -316,7 +333,7 @@ def match_ranges_dense(skeys, pkeys):
     return hi - lo, lo
 
 
-@tracing.op_scope("join")
+@tracing.op_scope("join_probe")
 def match_ranges(skeys, order, pass_flat, pkeys):
     """Pass-aware flavor: returns (counts, base, cum) where `counts[p]`
     is the number of PASSING build rows whose key equals `pkeys[p]`,
@@ -333,7 +350,7 @@ def match_ranges(skeys, order, pass_flat, pkeys):
     return top - base, base, cum
 
 
-@tracing.op_scope("join")
+@tracing.op_scope("join_gather")
 def nth_match(base, rank, cum, order):
     """Flat build position of the (rank+1)-th PASSING row of a match
     range (garbage when the range has fewer passing rows — callers mask
@@ -344,14 +361,14 @@ def nth_match(base, rank, cum, order):
     return order[jnp.clip(pos, 0, cum.shape[0] - 1)]
 
 
-@tracing.op_scope("join")
+@tracing.op_scope("join_gather")
 def nth_match_dense(base, rank, order):
     """Dense flavor: the k-th match of a range starting at sorted
     position `base` is simply order[base + k]."""
     return order[jnp.clip(base + rank, 0, order.shape[0] - 1)]
 
 
-@tracing.op_scope("join")
+@tracing.op_scope("join_expand")
 def expand(counts, counts_eff, bucket: int):
     """Static-shape one-to-many expansion bookkeeping.
 
