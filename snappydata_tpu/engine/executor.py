@@ -8,7 +8,8 @@ SnappyHashAggregateExec, HashJoinExec):
   Relation  → stacked [B,C] device arrays (storage/device.py)
   Filter    → valid &= predicate
   Project   → expression re-map
-  Join      → sorted build + searchsorted match RANGES per probe row
+  Join      → sorted build + match RANGES per probe row (sort-merge or
+              searchsorted, ops/join.probe_lowering)
               (the HashJoinExec replicated/collocated case).  Unique
               builds gather directly; non-unique builds prefix-sum the
               ranges into a {2^k, 1.5*2^k}-bucketed expanded output
@@ -1740,10 +1741,16 @@ class Compiler:
     # -- join --------------------------------------------------------------
 
     def _emit_join(self, plan: ast.Join):
-        """General device join: sorted build + searchsorted match RANGES.
+        """General device join: sorted build + match RANGES per probe key.
 
+        How a probe key finds its range is ops/join.probe_lowering's
+        choice at trace time, from the backend and the two shapes: one
+        sort-merge of build and probe keys (the TPU, unless the probe is
+        far smaller than its build) or searchsorted loops; the trace-time
+        note says which (`join_merge_probes`, `join_search_loops`).
         Unique builds (the dim/PK case) gather their single passing match
-        directly on the probe shape; non-unique builds prefix-sum the
+        directly on the probe shape — under the merge that row comes out
+        of the merge itself; non-unique builds prefix-sum the
         range widths into a bind-time-bucketed expanded output
         (ops/join.expand) — one-to-many/many-to-many inner, left, right
         and full outer all stay on device.  The sorted build keys +
@@ -2170,21 +2177,46 @@ class Compiler:
 
             use_art = artifact_mode and (
                 shuf_si is None or ctx.static[shuf_si] == 0)
+            # how a probe key finds its build rows: one sort-merge or
+            # searchsorted loops, from the backend and the two shapes
+            lowering = _dj.probe_lowering(
+                jax.default_backend(), int(pkeys.size),
+                int(ctx.aux[art_aux].shape[1] if use_art
+                    else ro.valid.size))
+            merge = lowering == _dj.PROBE_MERGE
+            note = ctx.join_note
+            note["join_merge_probes"] += int(merge)
+            looped = int(not merge)     # loops one search emits
+            # a unique build answers inner/left with one row a probe key
+            direct = mode_si is not None and ctx.static[mode_si] == 0 \
+                and how in ("inner", "left")
+            found = bpos = None
             if use_art:
                 packed = ctx.aux[art_aux]
                 skeys, order = packed[0], packed[1]
                 pass_flat = ro.valid.reshape(-1)
-                if build_filtered:
+                if merge and direct:
+                    # the merge carries the row and its pass bit: no
+                    # ranges, no prefix sum, no order[...] gather
+                    found, bpos = _dj.merge_unique(
+                        skeys, order,
+                        pass_flat[order] if build_filtered else None,
+                        pkeys)
+                elif build_filtered:
                     # the artifact sorts the FULL snapshot; query filters
                     # on the build side apply through this pass mask
                     # instead of a re-sort
                     counts, basec, cum = _dj.match_ranges(
-                        skeys, order, pass_flat, pkeys)
+                        skeys, order, pass_flat, pkeys, lowering)
+                    note["join_search_loops"] += 2 * looped
 
                     def locate(b, r):
-                        return _dj.nth_match(b, r, cum, order)
+                        note["join_search_loops"] += looped
+                        return _dj.nth_match(b, r, cum, order, lowering)
                 else:
-                    counts, basec = _dj.match_ranges_dense(skeys, pkeys)
+                    counts, basec = _dj.match_ranges_dense(
+                        skeys, pkeys, lowering)
+                    note["join_search_loops"] += 2 * looped
 
                     def locate(b, r):
                         return _dj.nth_match_dense(b, r, order)
@@ -2210,20 +2242,24 @@ class Compiler:
                 order = jnp.argsort(bkeys)
                 skeys = bkeys[order]
                 pass_flat = ro.valid.reshape(-1)
-                counts, basec = _dj.match_ranges_dense(skeys, pkeys)
+                counts, basec = _dj.match_ranges_dense(
+                    skeys, pkeys, lowering)
+                note["join_search_loops"] += 2 * looped
 
                 def locate(b, r):
                     return _dj.nth_match_dense(b, r, order)
-            found = counts > 0
+            if found is None:
+                found = counts > 0
             if how == "semi":
                 return RelOut(dict(lo.cols), lo.valid & found)
             if how == "anti":
                 return RelOut(dict(lo.cols), lo.valid & ~found)
 
-            if ctx.static[mode_si] == 0 and how in ("inner", "left"):
+            if direct:
                 # unique build: at most ONE passing match per probe row —
                 # direct gather on the probe shape, no expansion overhead
-                bpos = locate(basec, jnp.int64(0))
+                if bpos is None:
+                    bpos = locate(basec, jnp.int64(0))
                 cols: Dict[int, DVal] = dict(lo.cols)
                 for i in sorted(ro.cols.keys()):
                     src = ro.cols[i]
@@ -2258,7 +2294,9 @@ class Compiler:
                                            jnp.int64(0))
                 else:
                     counts_eff = counts_f
-                ctx.join_note["join_expand_out_rows"] += int(bucket)
+                note["join_expand_out_rows"] += int(bucket)
+                # the expansion's own search stays a loop
+                note["join_search_loops"] += 1
                 probe_of, rank, matched, slot_valid, total = _dj.expand(
                     counts_f, counts_eff, bucket)
                 bpos = locate(base_f[probe_of], rank)
@@ -3326,7 +3364,8 @@ def _cards_of(key_infos, ctx):
 
 
 _JOIN_NOTE_KEYS = ("join_device_joins", "join_probe_rows",
-                   "join_expand_out_rows")
+                   "join_expand_out_rows", "join_merge_probes",
+                   "join_search_loops")
 
 
 class _TraceCtx:

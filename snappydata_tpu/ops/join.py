@@ -1,10 +1,13 @@
 """Device join primitives: key encoding, cached build artifacts,
 one-to-many expansion.
 
-The device join is sort + searchsorted (ref: HashJoinExec keeping
+The device join is sort + rank (ref: HashJoinExec keeping
 replicated/collocated joins shuffle-free, PAPER.md): build keys sort
-once, every probe row binary-searches its match RANGE.  This module
-holds the pieces the executor's join emitter composes:
+once, every probe row finds its match RANGE in the sorted build — by one
+sort-merge of build and probe keys or by searchsorted loops, whichever
+`probe_lowering` picks at trace time from the backend and the two
+shapes.  This module holds the pieces the executor's join emitter
+composes:
 
 - **Key encoding** (`key_bits` / `combine_key_arrays` /
   `encode_build_keys`): the single int64 key domain both sides compare
@@ -26,6 +29,11 @@ holds the pieces the executor's join emitter composes:
   the expanded output size — per-probe match-range widths summed over
   the UNFILTERED probe leaf (query filters only shrink validity, so the
   bound is sound) — memoized on the artifact per probe bind identity.
+
+- **The probe** (`probe_lowering`, `sorted_rank`, `merge_unique`,
+  `match_ranges*`, `nth_match*`): the bounds of each probe key's run in
+  the sorted build and, for a unique build, the one matching row with
+  its pass bit straight out of the merge.
 
 - **One-to-many expansion** (`expand`): prefix-summed match counts map
   a static `{2^k, 1.5*2^k}`-bucketed output axis back to (probe row,
@@ -310,31 +318,151 @@ def probe_expand_bound_per_shard(artifact: dict, probe_ident,
     return bound
 
 
-# --- in-trace expansion ---------------------------------------------------
+# --- in-trace probe -------------------------------------------------------
+# How a probe key finds its build rows is one of two lowerings, chosen at
+# trace time by `probe_lowering` from the backend and the two shapes:
+#   loop  — `jnp.searchsorted`, a `while` of ceil(log2 B) dependent
+#           gathers a probe row.  Right where a gather is cheap (the CPU
+#           backend) or the probe is far smaller than its build.
+#   merge — sort the B build and P probe keys together once (build before
+#           probe on an equal key), read every answer off prefix scans of
+#           the merged order, and bring it home with a second sort on the
+#           carried index.  No gather: on the TPU both of Q3's probes at
+#           SF 1 take 57 ms each this way and 5,969 and 3,517 ms as
+#           loops (PERF.md section 6, PR 28).
+PROBE_LOOP = "loop"
+PROBE_MERGE = "merge"
+# The merge moves every element of the padded merged list at 6.7 ns (two
+# sorts and two scans: 57 ms at 8,388,608); a search gathers one element
+# a probe key a step at 14.9 ns (an int64 is two words), and a probe is
+# at least two searches.  Equal cost where the elements gathered by ONE
+# search are 6.7 / (2 * 14.9) of those sorted; measured on the chip from
+# P/B 1/64 (merge 40 ms, loops 45) to 64 (40 ms against 2,359), PERF.md
+# section 6, PR 28.
+MERGE_MIN_GATHERED_PER_SORTED = 0.225
+# the merged order's tags and positions are int32 (twice a position fits)
+_MERGE_MAX_ELEMENTS = 2 ** 30 - 1
+
+
+def probe_lowering(backend: str, n_probe: int, n_build: int) -> str:
+    """`merge` or `loop` for one probe of `n_probe` keys against a sorted
+    build of `n_build`: what the code can observe, never a knob.  The
+    CPU backend gathers cheaply and sorts dearly (Q3's two loops 0.93 s,
+    the merge 7.8 s there): always the loop."""
+    if backend != "tpu":
+        return PROBE_LOOP
+    gathered = n_probe * max(1, int(n_build).bit_length())
+    sorted_ = expand_bucket(n_probe + n_build)
+    if sorted_ <= _MERGE_MAX_ELEMENTS \
+            and gathered >= MERGE_MIN_GATHERED_PER_SORTED * sorted_:
+        return PROBE_MERGE
+    return PROBE_LOOP
+
+
+def _merged_order(skeys, qkeys, bval):
+    """Sort build and probe keys as one list, build before probe on an
+    equal key (the sort is stable and the build comes first).  Each
+    element carries one int32 tag that says where it came from: a build
+    element `bval` + 1 (`bval` >= 0), probe i the value i - P (< 0), so
+    a sort on the tag brings the probes home in order.  The list is
+    padded to the batch axis' bucket with keys that sort last and a tag
+    of 0, so that two joins over one probe, and a table that grows
+    inside its bucket, share one compiled sort.  Returns (tags,
+    is_build, run_start) in merged order; `run_start` marks the first
+    element of each run of equal keys."""
+    nb, nq = skeys.shape[0], qkeys.shape[0]
+    size = expand_bucket(nb + nq)
+    if size > _MERGE_MAX_ELEMENTS:    # probe_lowering never sends one
+        raise ValueError(f"merge probe of {nq} keys against {nb}: "
+                         "past the int32 index domain")
+    pad = size - nb - nq
+    keys, tags = jax.lax.sort(
+        (jnp.concatenate([skeys, qkeys,
+                          jnp.full((pad,), I64_MAX, jnp.int64)]),
+         jnp.concatenate([bval + 1,
+                          jnp.arange(-nq, 0, dtype=jnp.int32),
+                          jnp.zeros((pad,), jnp.int32)])),
+        num_keys=1, is_stable=True)
+    run_start = jnp.concatenate(
+        [jnp.ones((1,), jnp.bool_), keys[1:] != keys[:-1]])
+    return tags, tags > 0, run_start
+
+
+def _probe_order(tags, n_probe: int, *vals):
+    """Merged-order values of the probe elements, back in probe order."""
+    out = jax.lax.sort((tags,) + vals, num_keys=1)
+    return [v[:n_probe] for v in out[1:]]
+
+
+def sorted_rank(skeys, qkeys, lowering: str):
+    """(lo, hi) of `jnp.searchsorted(skeys, qkeys)`, side `left` and
+    `right`, int64 in `qkeys`' shape."""
+    if lowering == PROBE_LOOP:
+        return (jnp.searchsorted(skeys, qkeys, side="left")
+                .astype(jnp.int64),
+                jnp.searchsorted(skeys, qkeys, side="right")
+                .astype(jnp.int64))
+    flat = qkeys.reshape(-1)
+    tags, is_build, run_start = _merged_order(
+        skeys, flat, jnp.zeros(skeys.shape, jnp.int32))
+    # build elements at or before a probe are those with key <= its key;
+    # the count before its run of equal keys, those with key < its key
+    hi = jnp.cumsum(is_build.astype(jnp.int32))
+    lo = jax.lax.cummax(jnp.where(run_start, hi - is_build, 0))
+    lo, hi = _probe_order(tags, flat.shape[0], lo, hi)
+    return (lo.astype(jnp.int64).reshape(qkeys.shape),
+            hi.astype(jnp.int64).reshape(qkeys.shape))
+
+
+@tracing.op_scope("join_probe")
+def merge_unique(skeys, order, pass_sorted, pkeys):
+    """One merge for a probe against a UNIQUE build: (found, bpos) per
+    probe key, `found` where a build row has the key and passes the
+    build's filter (`pass_sorted` in sorted order; None: no filter),
+    `bpos` its flat row (0 where not found).  The build's one element
+    with a key, if any, heads the key's run in the merged order.  Two
+    prefix scans hand it down the run: build elements carry the step
+    from the row before them in sorted order, so a running sum reads the
+    row of the last build element seen, and a running max of the run
+    heads says whether that element heads the probe's own run."""
+    row = order.astype(jnp.int32) + 1       # 0: the build's filter drops it
+    if pass_sorted is not None:
+        row = jnp.where(pass_sorted, row, 0)
+    step = row - jnp.concatenate([jnp.zeros((1,), jnp.int32), row[:-1]])
+    nb = skeys.shape[0]
+    flat = pkeys.reshape(-1)
+    # a step lies in [-nb, nb]: shifted, it is a tag of a build element
+    tags, is_build, run_start = _merged_order(skeys, flat, step + nb)
+    last_row = jnp.cumsum(jnp.where(is_build, tags - (nb + 1), 0))
+    pos = jnp.arange(tags.shape[0], dtype=jnp.int32)
+    head = jax.lax.cummax(jnp.where(run_start, 2 * pos + is_build, 0))
+    hit = jnp.where(head % 2 == 1, last_row, 0) - 1
+    (hit,) = _probe_order(tags, flat.shape[0], hit)
+    hit = hit.reshape(pkeys.shape)
+    return hit >= 0, jnp.maximum(hit, 0).astype(jnp.int64)
+
+
 # Two range flavors:
 #   dense      — the build has NO in-trace filter.  Dead/padded and
 #                NULL-key rows are already key-sentineled by the artifact
 #                encode and sort to the END, so every row inside a real
 #                key's [lo, hi) run is live: counts come straight from
-#                the searchsorted bounds and the k-th match is
-#                order[lo + k].  This is the hot Q3-class shape — no
-#                prefix sums, no extra searchsorteds per execution.
+#                the two bounds and the k-th match is order[lo + k].
 #   pass-aware — a WHERE applies to the build side in-trace.  A prefix
 #                sum over the sorted pass mask counts the PASSING rows of
 #                each range, and the k-th passing row is located with one
-#                more searchsorted into that prefix sum.
+#                more rank into that prefix sum.
 
 @tracing.op_scope("join_probe")
-def match_ranges_dense(skeys, pkeys):
+def match_ranges_dense(skeys, pkeys, lowering: str):
     """(counts, lo) per probe key against an unfiltered sorted build;
     `lo` is in the sorted POSITION domain (k-th match at order[lo+k])."""
-    lo = jnp.searchsorted(skeys, pkeys, side="left").astype(jnp.int64)
-    hi = jnp.searchsorted(skeys, pkeys, side="right").astype(jnp.int64)
+    lo, hi = sorted_rank(skeys, pkeys, lowering)
     return hi - lo, lo
 
 
 @tracing.op_scope("join_probe")
-def match_ranges(skeys, order, pass_flat, pkeys):
+def match_ranges(skeys, order, pass_flat, pkeys, lowering: str):
     """Pass-aware flavor: returns (counts, base, cum) where `counts[p]`
     is the number of PASSING build rows whose key equals `pkeys[p]`,
     `base[p]` the count of passing rows strictly before the range, and
@@ -342,8 +470,7 @@ def match_ranges(skeys, order, pass_flat, pkeys):
     `nth_match` uses to locate the k-th passing row)."""
     pass_sorted = pass_flat[order]
     cum = jnp.cumsum(pass_sorted.astype(jnp.int64))
-    lo = jnp.searchsorted(skeys, pkeys, side="left")
-    hi = jnp.searchsorted(skeys, pkeys, side="right")
+    lo, hi = sorted_rank(skeys, pkeys, lowering)
     zero = jnp.zeros((), dtype=jnp.int64)
     base = jnp.where(lo > 0, cum[jnp.maximum(lo - 1, 0)], zero)
     top = jnp.where(hi > 0, cum[jnp.maximum(hi - 1, 0)], zero)
@@ -351,13 +478,16 @@ def match_ranges(skeys, order, pass_flat, pkeys):
 
 
 @tracing.op_scope("join_gather")
-def nth_match(base, rank, cum, order):
+def nth_match(base, rank, cum, order, lowering: str):
     """Flat build position of the (rank+1)-th PASSING row of a match
     range (garbage when the range has fewer passing rows — callers mask
     with their `matched` flag)."""
     maxc = jnp.maximum(cum[-1], 1)
     target = jnp.clip(base + rank + 1, 1, maxc)
-    pos = jnp.searchsorted(cum, target, side="left")
+    if lowering == PROBE_LOOP:
+        pos = jnp.searchsorted(cum, target, side="left")
+    else:
+        pos, _ = sorted_rank(cum, target, lowering)
     return order[jnp.clip(pos, 0, cum.shape[0] - 1)]
 
 

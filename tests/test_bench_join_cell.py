@@ -37,7 +37,8 @@ METRICS = ["q3_stmt_ms", "plan_ms.join", "bind_ms.join",
            "device_wait_ms.join", "dispatch_ms.join", "device_idle_pct.join",
            "join_roofline", "xla_compiles_in_window.join",
            "join_build_sorts.join", "host_fallbacks.join",
-           "join_device_joins.join", "scatter_slots.join"]
+           "join_device_joins.join", "scatter_slots.join",
+           "join_merge_probes.join", "join_search_loops.join"]
 DRAWS = [("BUILDING", "1995-03-15"), ("MACHINERY", "1995-03-01"),
          ("HOUSEHOLD", "1995-03-31")]
 _EPOCH = datetime.date(1970, 1, 1)
@@ -352,8 +353,7 @@ def _named(root, name):
     return [sp for sp in _spans(root) if sp["name"] == name]
 
 
-@pytest.fixture(scope="module")
-def traced_q3(man):
+def _three_traced_q3(man):
     """Three Q3 on one session, each with its trace: the first binds
     cold, the others bring a fresh SEGMENT and DATE."""
     props = config.global_properties()
@@ -375,6 +375,52 @@ def traced_q3(man):
         s.stop()
         props.decimal_as_float64, props.tracing_enabled = saved
     return recs
+
+
+@pytest.fixture(scope="module")
+def traced_q3(man):
+    """As the CPU backend lowers it: the probe's searches are loops."""
+    return _three_traced_q3(man)
+
+
+@pytest.fixture(scope="module")
+def traced_q3_merged(man):
+    """As the chip lowers it at SF 1 (PR 28): the resolver is steered to
+    the sort-merge here, in the test; the program has no switch."""
+    from snappydata_tpu.ops import join as dj
+
+    saved = dj.probe_lowering
+    dj.probe_lowering = lambda backend, n_probe, n_build: dj.PROBE_MERGE
+    try:
+        return _three_traced_q3(man)
+    finally:
+        dj.probe_lowering = saved
+
+
+def _main_dispatch(root):
+    (sp,) = [sp for sp in _spans(root)
+             if sp["name"] in ("jit_compile", "device_execute")]
+    return sp["attrs"]
+
+
+def test_a_traced_q3_says_how_its_probes_lowered(traced_q3,
+                                                 traced_q3_merged):
+    """Two probes as one merge each and no search loop left; in the loop
+    form none merged and six loops emitted: two bounds a join and the
+    located row of each filtered build (XLA drops the customer's, whose
+    columns nothing reads: five `while` in the HLO)."""
+    for rec in traced_q3_merged:
+        attrs = _main_dispatch(rec["traces"][0]["root"])
+        assert attrs["join_device_joins"] == 2
+        assert attrs["join_merge_probes"] == 2
+        assert attrs["join_search_loops"] == 0
+    for rec in traced_q3:
+        attrs = _main_dispatch(rec["traces"][0]["root"])
+        assert attrs["join_merge_probes"] == 0
+        assert attrs["join_search_loops"] == 6
+    # the same ten rows either way
+    assert [r["answer"] for r in traced_q3_merged] \
+        == [r["answer"] for r in traced_q3]
 
 
 def test_a_traced_q3_stays_on_the_device_and_says_so(traced_q3):
@@ -434,7 +480,8 @@ def test_a_plan_without_a_join_carries_zeros():
             and sp["attrs"].get("phase", "main") == "main"]
     assert len(main) == 1
     for key in ("join_device_joins", "join_probe_rows",
-                "join_expand_out_rows", "groups_overflow"):
+                "join_expand_out_rows", "join_merge_probes",
+                "join_search_loops", "groups_overflow"):
         assert main[0]["attrs"][key] == 0
     assert main[0]["attrs"]["group_slots"] >= 7
     assert not _named(root, "host_ops")
@@ -466,9 +513,9 @@ def test_more_groups_than_slots_is_flagged_and_named():
 
 
 @pytest.mark.parametrize("name", METRICS)
-def test_every_new_metric_reads_a_number_from_the_trace(man, traced_q3,
-                                                        name):
-    window = traced_q3[1:]      # the first statement is the warm-up
+def test_every_new_metric_reads_a_number_from_the_trace(
+        man, traced_q3_merged, name):
+    window = traced_q3_merged[1:]      # the first statement is the warm-up
     ctx = {"statements": window, "back": "session", "front": "session",
            "device": {"busy_s": 2.0, "window_s": 2.5}, "window_s": 2.5,
            "peaks": roofline.peaks_for("TPU v5 lite")}
@@ -478,6 +525,7 @@ def test_every_new_metric_reads_a_number_from_the_trace(man, traced_q3,
         "host_fallbacks.join": 0, "xla_compiles_in_window.join": 0,
         "join_build_sorts.join": 0, "join_device_joins.join": 2,
         "scatter_slots.join": 1, "device_idle_pct.join": 20.0,
+        "join_merge_probes.join": 2, "join_search_loops.join": 0,
         "join_roofline": 100.0 * (2 * window[0]["rows_read"] * 26 / 819e9)
         / 2.0}
     if name in expected:
@@ -486,11 +534,14 @@ def test_every_new_metric_reads_a_number_from_the_trace(man, traced_q3,
         assert value > 0
     # with the warm-up among them the builds show: the reader counts spans
     if name == "join_build_sorts.join":
-        assert man.read(name, dict(ctx, statements=traced_q3)) == 2
+        assert man.read(name, dict(ctx, statements=traced_q3_merged)) == 2
     # a program from before the attrs: None, not an error
-    if name == "join_device_joins.join":
+    attr = {"join_device_joins.join": "join_device_joins",
+            "join_merge_probes.join": "join_merge_probes",
+            "join_search_loops.join": "join_search_loops"}.get(name)
+    if attr is not None:
         bare = json.loads(json.dumps(window))
         for r in bare:
             for sp in _spans(r["traces"][0]["root"]):
-                sp.get("attrs", {}).pop("join_device_joins", None)
+                sp.get("attrs", {}).pop(attr, None)
         assert man.read(name, dict(ctx, statements=bare)) is None

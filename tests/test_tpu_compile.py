@@ -1,7 +1,8 @@
 """Kernels of the main path compiled for the chip without the chip: the
 TPU compiler is installed here and compiles for a described v5e, so what
 it refuses, and what it would hold in HBM, is known before a chip run.
-Nothing runs: no result, no time.
+Nothing runs: no result, no time.  The join case compiles for about two
+minutes (four wide sorts); the rest take seconds.
 
 The topology is described inside a fixture, never at import or
 collection: only the worker that is handed this file loads the TPU's
@@ -65,3 +66,41 @@ def test_dict_space_count_transient_does_not_grow_with_batches(one_chip,
     # each step slices its own rows, so nothing is sized by the table
     assert max(temps) <= code_agg.DICT_SPACE_CHUNK_BYTES, temps
     assert temps[1] <= temps[0] + (1 << 20), temps
+
+
+def test_q3_merge_probes_at_sf1_hold_no_loop_and_share_their_sorts(one_chip):
+    """Both of Q3's probes at SF 1 (6,291,456 probe slots against the
+    filtered orders build of 1,572,864 and the customer build of
+    262,144) as the chip lowers them since PR 28, in one module as the
+    plan holds them: no `while`, no gather, no scatter; two sorts a
+    join, and because both merged lists pad to one bucket (8,388,608)
+    the compiler builds each kind of sort once.  Temporaries stay under
+    16 bytes a merged element."""
+    import jax
+    import jax.numpy as jnp
+
+    from snappydata_tpu.ops import join as dj
+
+    def shape(dims, dt):
+        return jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
+
+    probe, orders, customer = (48, 131072), 1572864, 262144
+    assert dj.probe_lowering("tpu", 48 * 131072, orders) == dj.PROBE_MERGE
+    assert dj.probe_lowering("tpu", 48 * 131072, customer) == dj.PROBE_MERGE
+    assert dj.expand_bucket(48 * 131072 + orders) \
+        == dj.expand_bucket(48 * 131072 + customer) == 8388608
+
+    def both(s1, o1, pass1, k1, s2, o2, k2):
+        return (dj.merge_unique(s1, o1, pass1, k1),
+                dj.merge_unique(s2, o2, None, k2))
+
+    comp = jax.jit(both).lower(
+        shape((orders,), jnp.int64), shape((orders,), jnp.int64),
+        shape((orders,), jnp.bool_), shape(probe, jnp.int64),
+        shape((customer,), jnp.int64), shape((customer,), jnp.int64),
+        shape(probe, jnp.int64)).compile()
+    hlo = comp.as_text()
+    for op in (" while(", " gather(", " scatter("):
+        assert op not in hlo, op
+    assert hlo.count(" sort(") == 4
+    assert comp.memory_analysis().temp_size_in_bytes <= 16 * 8388608
