@@ -395,7 +395,7 @@ def main() -> None:
                 round(delta("agg_reduce_passes") / repeats, 2),
             "strategies": {
                 st: delta(f"agg_strategy_{st}")
-                for st in ("unroll", "scatter", "matmul", "pallas")
+                for st in ("unroll", "scatter", "matmul")
                 if delta(f"agg_strategy_{st}")},
             "gidx_cache_hits": delta("gidx_cache_hits"),
             "gidx_cache_misses": delta("gidx_cache_misses"),
@@ -515,50 +515,6 @@ def main() -> None:
         print(f"bench: compressed-domain bench failed: {e}",
               file=sys.stderr, flush=True)
         compressed = {"error": str(e)}
-
-    # Pallas lanes: on TPU, the engine-level side-by-sides (default-off
-    # knobs); elsewhere the fused decode+filter+aggregate CODE-DOMAIN
-    # kernels run in interpreter mode under opt-in SNAPPY_BENCH_PALLAS=1
-    # (correctness + a trajectory, not hardware speed) — with row-count
-    # sanity asserts against the engine's own answers.
-    pallas = {"q6_pallas_s": "skipped (set SNAPPY_BENCH_PALLAS=1 for "
-                             "cpu interpret)",
-              "q1_pallas_s": "skipped (set SNAPPY_BENCH_PALLAS=1 for "
-                             "cpu interpret)"}
-    if platform != "tpu" and os.environ.get("SNAPPY_BENCH_PALLAS") == "1":
-        try:
-            pallas = _pallas_fused_bench(s, repeats)
-            print(f"bench: fused code-domain kernels (interpret) q6 "
-                  f"{pallas['q6_pallas_s']}s q1 {pallas['q1_pallas_s']}s, "
-                  f"row counts asserted", file=sys.stderr, flush=True)
-        except Exception as e:
-            failures.append(f"pallas_fused: {type(e).__name__}: {e}")
-            print(f"bench: fused pallas bench failed: {e}",
-                  file=sys.stderr, flush=True)
-            pallas = {"q6_pallas_s": f"failed: {e}",
-                      "q1_pallas_s": f"failed: {e}"}
-    if platform == "tpu":
-        pallas = {"q6_pallas_s": None, "q1_pallas_s": None}
-        for field, flag, q in (
-                ("q6_pallas_s", "pallas_reduce", tpch.Q6),
-                ("q1_pallas_s", "pallas_group_reduce", tpch.Q1)):
-            try:
-                setattr(config.global_properties(), flag, True)
-                s.executor.clear_cache()
-                s.sql(q)
-                best = float("inf")
-                for _ in range(repeats):
-                    t0 = time.time()
-                    s.sql(q)
-                    best = min(best, time.time() - t0)
-                pallas[field] = round(best, 4)
-            except Exception as e:
-                failures.append(f"pallas: {type(e).__name__}: {e}")
-                print(f"bench: pallas {field} timing failed: {e}",
-                      file=sys.stderr, flush=True)
-            finally:
-                setattr(config.global_properties(), flag, False)
-                s.executor.clear_cache()
 
     # Q3-class device join+aggregate (the one-to-many expansion path)
     # vs the r05-era host pandas-merge path, value-asserted
@@ -770,8 +726,6 @@ def main() -> None:
             "q6_device_rows_per_s": None if device.get("q6") is None
             else round(n_rows / device["q6"], 1),
             "q1_max_rel_err": q1_max_rel_err,
-            "q6_pallas_s": pallas["q6_pallas_s"],
-            "q1_pallas_s": pallas["q1_pallas_s"],
             # reduction-strategy evidence per headline query (strategy
             # picked by the auto table, fused passes per run, gidx
             # cache behavior across the repeats)
@@ -883,7 +837,7 @@ def _multichip_child() -> None:
     Q1/Q6/Q3C execution at 1/2/4/8 devices — every mesh answer
     value-asserted against the single-device run of the same data.
     Prints ONE JSON line; the parent embeds it as detail.multichip and
-    the committed MULTICHIP_r*.json record."""
+    in the record `--multichip <path>` writes."""
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (
@@ -1915,127 +1869,6 @@ def _code_agg_bench(s, repeats: int) -> dict:
     }
 
 
-def _pallas_fused_bench(s, repeats: int) -> dict:
-    """Fused decode+filter+aggregate kernels over the CODE-DOMAIN binds
-    of the loaded lineitem table (interpret mode off-TPU): Q6 through
-    ops/pallas_reduce.fused_code_filter_sum (code-threshold filters +
-    in-kernel dictionary decode) and the Q1 shape through
-    ops/pallas_group.grouped_code_reduce (per-group Kahan partials over
-    code slots, host-TRANSFORMED dictionaries for the (1-disc)/(1+tax)
-    factors).  Row counts and sums are asserted against the engine's own
-    answers before anything is timed."""
-    import datetime
-
-    import jax
-
-    from snappydata_tpu.ops.pallas_group import grouped_code_reduce
-    from snappydata_tpu.ops.pallas_reduce import fused_code_filter_sum
-    from snappydata_tpu.storage.device import build_device_table
-    from snappydata_tpu.storage.device_decode import CodePlate
-    from snappydata_tpu.utils import tpch
-
-    QTY, PRICE, DISC, TAX, RF, LS, SHIP = 4, 5, 6, 7, 8, 9, 10
-    data = s.catalog.lookup_table("lineitem").data
-    dt = build_device_table(data, None,
-                            [QTY, PRICE, DISC, TAX, RF, LS, SHIP])
-    qp, dp, tp = dt.columns[QTY], dt.columns[DISC], dt.columns[TAX]
-    if not all(isinstance(x, CodePlate) for x in (qp, dp, tp)):
-        raise RuntimeError("lineitem measure columns are not code-bound "
-                           "(scan_compressed_domain off?)")
-    ship, price, valid = dt.columns[SHIP], dt.columns[PRICE], dt.valid
-    B = int(valid.shape[0])
-
-    def days(sdate: str) -> int:
-        d = datetime.date.fromisoformat(sdate)
-        return (d - datetime.date(1970, 1, 1)).days
-
-    def thresh(ci, lit, side):
-        dom, sizes = dt.dict_domains[ci]
-        # the literal at the plate's width: the domain holds the plate's
-        # values, and on f32 plates f32(0.07) sits above the f64 literal,
-        # so `<= 0.07` would lose its own rows
-        lit = np.dtype(dt.columns[ci].dicts.dtype).type(lit)
-        out = np.zeros(B, dtype=np.int32)
-        for i in range(B):
-            sz = int(sizes[i])
-            out[i] = np.searchsorted(dom[i, :sz], lit, side) if sz else 0
-        return out
-
-    # ---- Q6: code-threshold filter + in-kernel discount decode ---------
-    qty_hi = thresh(QTY, 24.0, "left")
-    dlo = thresh(DISC, 0.05, "left")
-    dhi = thresh(DISC, 0.07, "right") - 1
-    slo, shi = days("1994-01-01"), days("1995-01-01")
-    exp_cnt = s.sql(
-        "SELECT count(*) FROM lineitem "
-        "WHERE l_shipdate >= DATE '1994-01-01' "
-        "AND l_shipdate < DATE '1995-01-01' "
-        "AND l_discount BETWEEN 0.05 AND 0.07 "
-        "AND l_quantity < 24").rows()[0][0]
-    exp_rev = s.sql(tpch.Q6).rows()[0][0]
-
-    def run_q6():
-        return fused_code_filter_sum(qp.codes, dp.codes, ship, price,
-                                     valid, dp.dicts, qty_hi, dlo, dhi,
-                                     slo, shi)
-    total, count = jax.block_until_ready(run_q6())   # compile + check
-    assert int(count) == int(exp_cnt), (int(count), int(exp_cnt))
-    rel = abs(float(total) - exp_rev) / max(abs(exp_rev), 1.0)
-    assert rel <= 5e-5, (float(total), exp_rev, rel)
-    best6 = float("inf")
-    for _ in range(max(repeats, 3)):
-        t0 = time.time()
-        jax.block_until_ready(run_q6())
-        best6 = min(best6, time.time() - t0)
-
-    # ---- Q1 shape: grouped code reduction, dictionary-space factors ----
-    rf, ls = dt.columns[RF], dt.columns[LS]
-    rfd, lsd = dt.dictionaries[RF], dt.dictionaries[LS]
-    nls = max(1, len(lsd))
-    G = max(1, len(rfd)) * nls
-    gidx = rf * nls + ls
-    lim = days("1998-12-01") - 90
-    mask = valid & (ship <= lim)
-    qdom, _ = dt.dict_domains[QTY]
-    ddom, _ = dt.dict_domains[DISC]
-    tdom, _ = dt.dict_domains[TAX]
-
-    def run_q1():
-        return grouped_code_reduce(
-            gidx, mask,
-            [("count",),
-             ("sum", None, [(qp.codes, qdom)]),
-             ("sum", price, []),
-             ("sum", price, [(dp.codes, 1.0 - ddom)]),
-             ("sum", price, [(dp.codes, 1.0 - ddom),
-                             (tp.codes, 1.0 + tdom)])],
-            G)
-    outs = jax.block_until_ready(run_q1())
-    engine = {(r[0], r[1]): r for r in s.sql(tpch.Q1).rows()}
-    for g in range(G):
-        cnt = int(outs[0][g])
-        key = (str(rfd[g // nls]), str(lsd[g % nls]))
-        if key not in engine:
-            assert cnt == 0, (key, cnt)
-            continue
-        row = engine[key]
-        assert cnt == int(row[9]), (key, cnt, row[9])   # row-count sanity
-        for got, exp in ((float(outs[1][g]), row[2]),
-                         (float(outs[2][g]), row[3]),
-                         (float(outs[3][g]), row[4]),
-                         (float(outs[4][g]), row[5])):
-            assert abs(got - exp) <= 5e-5 * max(abs(exp), 1.0), \
-                (key, got, exp)
-    best1 = float("inf")
-    for _ in range(max(repeats, 3)):
-        t0 = time.time()
-        jax.block_until_ready(run_q1())
-        best1 = min(best1, time.time() - t0)
-    return {"q6_pallas_s": round(best6, 4), "q1_pallas_s": round(best1, 4),
-            "pallas_mode": "interpret"
-            if jax.default_backend() != "tpu" else "compiled"}
-
-
 def _decode_counters():
     try:
         from snappydata_tpu.storage import device_decode
@@ -2254,7 +2087,7 @@ if __name__ == "__main__":
         sys.exit(0)
     if len(sys.argv) > 1 and sys.argv[1] == "--multichip":
         # standalone multichip run: prints the record and (with an
-        # output path) writes the committed MULTICHIP_r*.json shape
+        # output path) writes it there
         rec = _multichip_bench()
         rec_out = {"n_devices": rec.get("n_devices", 8), "rc": 0,
                    "ok": rec.get("value_mismatches", 1) == 0,

@@ -15,9 +15,6 @@ One process, the normal entry points (`SnappySession`, `session.sql`,
   q3c       the join leg, on the device with default settings (at a cut
             scale where the defaults would reroute it to the host: see
             `reduced`), against a NumPy oracle
-  pallas    the four Pallas entry points compiled (interpret=False) on the
-            loaded table's plates, and Q1/Q6 under the two Pallas knobs,
-            against the XLA lane
   mesh      with more than one device: Q1/Q6/Q3C under MeshContext, answers
             equal to single-device, bytes resident on every device, and the
             composed two-server topology once
@@ -36,9 +33,8 @@ is non-zero and neither line is printed.
 
 Without a TPU the script exits 2 and prints no result. `--cpu-rehearsal`
 together with JAX_PLATFORMS=cpu in the environment runs the same legs at a
-tiny scale on the CPU (Pallas interpreted), labelled `platform: cpu` in
-every line: it is what the tier-1 test runs, and it proves values and
-control flow, no rate.
+tiny scale on the CPU, labelled `platform: cpu` in every line: it is what
+the tier-1 test runs, and it proves values and control flow, no rate.
 """
 
 from __future__ import annotations
@@ -331,124 +327,6 @@ WHERE l_shipdate >= DATE '1994-01-01'
 # legs
 # ---------------------------------------------------------------------------
 
-def leg_pallas(s, q1_rows: list, q6_rev: float) -> dict:
-    """Each of the four Pallas entry points compiled (interpreted only in
-    a CPU rehearsal) on the loaded table's plates, against the XLA lane;
-    then Q1/Q6 through session.sql under the two knobs."""
-    import jax
-    import jax.numpy as jnp
-
-    import bench
-    from snappydata_tpu import config
-    from snappydata_tpu.observability.metrics import global_registry
-    from snappydata_tpu.ops import reduction
-    from snappydata_tpu.ops.pallas_group import grouped_reduce
-    from snappydata_tpu.ops.pallas_reduce import (interpret_default,
-                                                  masked_kahan_sum)
-    from snappydata_tpu.storage.device import build_device_table
-    from snappydata_tpu.utils import tpch
-
-    interpret = interpret_default()
-    check(interpret == (_PLATFORM == "cpu"),
-          "Pallas would run interpreted on an accelerator")
-    seconds = {}
-
-    # fused_code_filter_sum + grouped_code_reduce: bench.py's hook runs
-    # both over the table's code plates and asserts them against the
-    # engine's own Q6/Q1 answers
-    t0 = time.perf_counter()
-    fused = bench._pallas_fused_bench(s, repeats=1)
-    seconds["code_kernels_s"] = time.perf_counter() - t0
-    check(fused["pallas_mode"] == ("interpret" if interpret else "compiled"),
-          f"code kernels ran {fused['pallas_mode']}")
-
-    PRICE, RF, LS, SHIP = 5, 8, 9, 10
-    data = s.catalog.lookup_table("lineitem").data
-    dt = build_device_table(data, None, [PRICE, RF, LS, SHIP])
-    price, valid = dt.columns[PRICE], dt.valid
-    check(price.dtype == jnp.float32, f"price plate is {price.dtype}")
-    mask = valid & (dt.columns[SHIP] <= days("1998-12-01") - 90)
-    nls = max(1, len(dt.dictionaries[LS]))
-    G = max(1, len(dt.dictionaries[RF])) * nls
-    gidx = dt.columns[RF] * nls + dt.columns[LS]
-
-    # masked_kahan_sum vs the XLA lane's f64-accumulated masked sum
-    t0 = time.perf_counter()
-    got = float(masked_kahan_sum(price, mask, interpret=interpret))
-    seconds["masked_kahan_sum_s"] = time.perf_counter() - t0
-    xla = float(jnp.sum(jnp.where(mask, price, 0).astype(jnp.float64)))
-    check(close(got, xla, SUM_TOL), f"masked_kahan_sum {got} vs XLA {xla}")
-
-    # grouped_reduce vs the XLA lane's packed families (the TPU default:
-    # unrolled masked reductions, f64 accumulators) over the same plates
-    t0 = time.perf_counter()
-    outs = [np.asarray(o) for o in grouped_reduce(
-        [("sum", price, mask), ("count", None, mask),
-         ("min", price, mask), ("max", price, mask)],
-        gidx, G + 1, interpret=interpret)]
-    seconds["grouped_reduce_s"] = time.perf_counter() - t0
-
-    @jax.jit
-    def xla_lane(price, mask, gidx):
-        seg, m, v = gidx.reshape(-1), mask.reshape(-1), price.reshape(-1)
-        return (
-            reduction.packed_sum(
-                [jnp.where(m, v, 0).astype(jnp.float64)], seg, G,
-                "unroll")[:, 0],
-            reduction.packed_sum([m.astype(jnp.int32)], seg, G,
-                                 "unroll")[:, 0],
-            reduction.packed_minmax(
-                "min", [jnp.where(m, v, jnp.inf)], seg, G, "unroll")[:, 0],
-            reduction.packed_minmax(
-                "max", [jnp.where(m, v, -jnp.inf)], seg, G, "unroll")[:, 0])
-
-    x_sum, x_cnt, x_min, x_max = (np.asarray(a)
-                                  for a in xla_lane(price, mask, gidx))
-    for g in range(G):
-        check(int(outs[1][g]) == int(x_cnt[g]),
-              f"grouped_reduce count[{g}] {outs[1][g]} vs {x_cnt[g]}")
-        check(close(outs[0][g], x_sum[g], SUM_TOL),
-              f"grouped_reduce sum[{g}] {outs[0][g]} vs {x_sum[g]}")
-        if x_cnt[g]:
-            check(outs[2][g] == x_min[g] and outs[3][g] == x_max[g],
-                  f"grouped_reduce min/max[{g}]")
-    del dt, price, valid, mask, gidx
-
-    # the engine under the knobs: same answers as the XLA lane
-    props = config.global_properties()
-    reg = global_registry()
-    for flag, sql, name in (("pallas_group_reduce", tpch.Q1, "q1"),
-                            ("pallas_reduce", tpch.Q6, "q6")):
-        setattr(props, flag, True)
-        s.executor.clear_cache()
-        try:
-            c0 = reg.counters_snapshot()
-            t0 = time.perf_counter()
-            rows = s.sql(sql).rows()
-            seconds[f"{name}_{flag}_cold_s"] = time.perf_counter() - t0
-            t0 = time.perf_counter()
-            s.sql(sql).rows()
-            seconds[f"{name}_{flag}_warm_s"] = time.perf_counter() - t0
-            ev = lanes(c0, reg.counters_snapshot())
-        finally:
-            setattr(props, flag, False)
-            s.executor.clear_cache()
-        check(ev.get("agg_strategy_pallas", 0) > 0,
-              f"{flag}: the Pallas lane did not run ({ev})")
-        check(ev["host_fallbacks"] == 0, f"{flag}: host fallback ({ev})")
-        if name == "q1":
-            check(rows_equal(rows, q1_rows, SUM_TOL),
-                  f"Q1 under {flag} differs from the XLA lane")
-        else:
-            check(close(rows[0][0], q6_rev, SUM_TOL),
-                  f"Q6 under {flag}: {rows[0][0]} vs XLA lane {q6_rev}")
-    say("pallas", mode="interpret" if interpret else "compiled",
-        kernels=["masked_kahan_sum", "fused_code_filter_sum",
-                 "grouped_reduce", "grouped_code_reduce"],
-        engine_knobs=["pallas_group_reduce", "pallas_reduce"], **seconds)
-    return {"mode": "interpret" if interpret else "compiled", **seconds}
-
-
 def leg_mesh(s, js, n_dev: int, single: dict, tol: float,
              n_rows: int, join_rows: int) -> dict:
     """Q1/Q6 on the main tables and Q3C on the join leg's, under
@@ -730,8 +608,7 @@ def run(sf: float, seed: int, reduced: list) -> dict:
           f"Q3C did not stay on the device: {ev}")
     single["q3c"] = rows
 
-    # ---- Pallas, mesh (pre-mutation table state) ---------------------------
-    pallas = leg_pallas(s, single["q1"], single["q6"][0][0])
+    # ---- mesh (pre-mutation table state) -----------------------------------
     mesh = leg_mesh(s, js, len(devs), single,
                     MESH_TOL_F32 if f32 else MESH_TOL_F64, n_rows,
                     join_rows) if len(devs) > 1 else None
@@ -754,7 +631,6 @@ def run(sf: float, seed: int, reduced: list) -> dict:
         "lineitem_rows": n_rows,
         "reduced": reduced,
         "seconds": seconds,
-        "pallas": pallas,
         "serve": serve,
         "mesh": mesh,
         "peak_bytes_in_use": stats.get("peak_bytes_in_use"),
@@ -799,7 +675,7 @@ def main(argv=None) -> int:
                         "reason": "CPU rehearsal: values and control flow "
                                   "only, no rate"})
         # the chip's dtype policy (f32 plates, f64 accumulators), so the
-        # rehearsal walks the same gates: the Pallas lanes take f32 only
+        # rehearsal walks the same gates
         from snappydata_tpu import config
 
         config.global_properties().decimal_as_float64 = False

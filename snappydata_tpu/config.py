@@ -124,7 +124,7 @@ class Properties:
     # code_agg.DICT_SPACE_MAX_PRODUCT; past it the slot rides the packed
     # families.
     #   auto  engage on TPU backends, stay on the gather path on CPU
-    #   on    engage everywhere eligibility holds (tests and bench.py)
+    #   on    engage everywhere eligibility holds (tests)
     #   off   always gather decoded values
     # Rides the compiled plan's static key: flipping re-specializes,
     # no cache flush. Counted agg_dict_space per engaged execution.
@@ -147,16 +147,6 @@ class Properties:
     # mixed_encoding + not_encoded) before a table is considered worth
     # compacting — avoids rewriting cold tables nobody scans.
     compaction_min_fallbacks: int = 1
-    # Pallas compensated-f32 kernel for global float SUM/AVG instead of
-    # the emulated-f64 segment reduction on TPU (ops/pallas_reduce.py).
-    # Compiles and matches the XLA lane on the v5e (chip_smoke.py checks
-    # it on every PR); default OFF until its rate is measured there.
-    pallas_reduce: bool = False
-    # Fused Pallas grouped-aggregate kernel for the dictionary fast path
-    # (the TPC-H Q1 shape): one VMEM pass per slot batch with per-group
-    # per-lane Kahan partials, f64 combine outside (ops/pallas_group.py).
-    # Same policy as pallas_reduce: checked by chip_smoke.py, default OFF.
-    pallas_group_reduce: bool = False
     # Grouped-aggregate reduction strategy (ops/reduction.py): every
     # compatible slot of a query packs into one [N, S] matrix per
     # accumulator family and reduces in a single fused dispatch.

@@ -52,7 +52,6 @@ from snappydata_tpu.engine.exprs import (STRING_VALUE_FUNCS, CompileError,
                                          _or_null)
 from snappydata_tpu.engine.result import Result, empty_result
 from snappydata_tpu.observability import tracing
-from snappydata_tpu.ops import pallas_group as _pg
 from snappydata_tpu.resource.context import check_current
 from snappydata_tpu.sql import ast
 from snappydata_tpu.sql.analyzer import expr_type, _expr_name
@@ -2861,8 +2860,8 @@ class Compiler:
             # Evaluate slot inputs once, dedup by argument expression:
             # slots over the SAME argument (sum(x)+min(x), avg's
             # sum+count beside an explicit sum) share array OBJECTS, so
-            # the pallas kernel's id()-keyed input dedup fires and count
-            # columns over one mask collapse to a single packed column.
+            # count_col collapses counts over one mask to one packed
+            # column.
             evaluated: List[tuple] = []
             arg_vw: Dict[object, tuple] = {}
             for (kind, arg), run in zip(slots, slot_arg_runs):
@@ -2893,46 +2892,6 @@ class Compiler:
                                          dv.rplate if raw else None)
                 evaluated.append((kind,) + hit)
 
-            # Fused Pallas grouped path (the Q1 shape on TPU):
-            # dictionary/bool fast-path group index, G <= 64, f32 value
-            # plates — eligible slots share ONE streaming VMEM pass with
-            # per-group per-lane Kahan partials (ops/pallas_group.py).
-            # The VMEM budget stops fusing before a wide aggregate would
-            # fail the Mosaic compile; overflow slots take the packed
-            # families below.
-            use_pg = bool(groups) and fast and nseg <= _pg.MAX_GROUPS \
-                and config.global_properties().pallas_group_reduce
-            pg_bytes = _pg.base_vmem_bytes() \
-                + _pg.op_vmem_bytes("count", nseg)
-            pg_masks = {id(valid)}  # the gvalid count op's mask
-            pg_vals: set = set()
-            fused = []  # (slot_idx, kind, values|None, mask)
-            fused_idx: set = set()
-            if use_pg:
-                for i, (kind, v, w, sdt, _raw, _cpl,
-                        _rpl) in enumerate(evaluated):
-                    eligible = kind == "count" or (
-                        kind in ("sum", "min", "max") and v is not None
-                        and v.dtype == jnp.float32)
-                    if not eligible:
-                        continue
-                    if kind == "sum" and dict_space_takes(_cpl):
-                        # the dictionary-space lane below takes this
-                        # slot — it never gathers the value plate
-                        continue
-                    pv = None if kind == "count" else v
-                    cost = _pg.op_vmem_bytes(
-                        kind, nseg, shared_mask=id(w) in pg_masks,
-                        shared_value=pv is not None and id(pv) in pg_vals)
-                    if pg_bytes + cost > _pg.VMEM_BUDGET:
-                        continue
-                    pg_bytes += cost
-                    pg_masks.add(id(w))
-                    if pv is not None:
-                        pg_vals.add(id(pv))
-                    fused.append((i, kind, pv, w))
-                    fused_idx.add(i)
-
             # Packed accumulator families: every remaining slot joins one
             # [N, S] matrix per family and the family reduces in ONE
             # fused dispatch (ops/reduction.py strategy table) — the old
@@ -2956,8 +2915,6 @@ class Compiler:
 
             for i, (kind, v, w, sdt, raw_col, cpl,
                     rpl) in enumerate(evaluated):
-                if i in fused_idx:
-                    continue
                 if kind == "count":
                     rm = None
                     if (rle_ok and rpl is not None and not groups
@@ -3034,21 +2991,6 @@ class Compiler:
                         note["lanes"].add("dict_space")
                         note["dict_space_slots"] += 1
                         continue
-                    if (not groups and v.dtype == jnp.float32
-                            and config.global_properties().pallas_reduce):
-                        # global f32 sum via the Pallas Kahan kernel:
-                        # one compensated-f32 pass instead of the
-                        # emulated-f64 reduction (ops/pallas_reduce.py,
-                        # incl. the cancellation caveat)
-                        from snappydata_tpu.ops.pallas_reduce import \
-                            masked_kahan_sum
-
-                        total = masked_kahan_sum(v, w)
-                        slot_arrays[i] = jnp.stack(
-                            [total, jnp.zeros((), total.dtype)])
-                        note["passes"] += 1
-                        note["strategies"].add("pallas")
-                        continue
                     acc = v.astype(acc_dt)
                     if acc_dt == jnp.int64:
                         if sdt is not None and sdt.name == "decimal":
@@ -3092,10 +3034,9 @@ class Compiler:
                 else:
                     raise CompileError(kind)
 
-            if not fused:
-                # the gvalid count joins the count family (and dedups
-                # with any count slot over the plain validity mask)
-                gvalid_col = count_col(valid)
+            # the gvalid count joins the count family (and dedups with
+            # any count slot over the plain validity mask)
+            gvalid_col = count_col(valid)
 
             # --- family dispatch: one fused reduction each ---
             count_res = None
@@ -3160,19 +3101,7 @@ class Compiler:
                     absmax.astype(jnp.float64)
                     * cnt_w.astype(jnp.float64) * tscale >= 2.0 ** 62)
 
-            if fused:
-                # the gvalid count rides the same streaming pass (its
-                # VMEM share is reserved in pg_bytes' base above)
-                ops = [(k, v, w) for _, k, v, w in fused]
-                ops.append(("count", None, valid))
-                pg_out = _pg.grouped_reduce(ops, gidx, nseg)
-                for (i, _, _, _), r in zip(fused, pg_out[:-1]):
-                    slot_arrays[i] = r
-                counts = pg_out[-1]
-                note["passes"] += 1
-                note["strategies"].add("pallas")
-            else:
-                counts = count_res[:, gvalid_col]
+            counts = count_res[:, gvalid_col]
             if groups:
                 gvalid = counts[:num_groups] > 0
             else:

@@ -81,6 +81,7 @@ def scan_snapshot(catalog=None) -> dict:
     along (including each table's own compressed_fallbacks tally — the
     compaction trigger)."""
     from snappydata_tpu import config
+    from snappydata_tpu.ops import reduction
     from snappydata_tpu.storage import device_decode
 
     snap = global_registry().snapshot()
@@ -96,7 +97,7 @@ def scan_snapshot(catalog=None) -> dict:
         "agg_reduce_passes": c.get("agg_reduce_passes", 0),
         "agg_strategies": {
             s: c.get(f"agg_strategy_{s}", 0)
-            for s in ("unroll", "scatter", "matmul", "pallas")
+            for s in reduction.REPORTED_STRATEGIES
             if c.get(f"agg_strategy_{s}", 0)},
         "gidx_cache_hits": hits,
         "gidx_cache_misses": misses,

@@ -1,5 +1,3 @@
-"""TPU kernels (Pallas) for hot ops the XLA-level path can't express
-optimally. Import from submodules. The kernels compile on an accelerator
-and run in Pallas interpret mode on the CPU backend (the tests)."""
-
-from snappydata_tpu.ops.pallas_reduce import masked_kahan_sum  # noqa: F401
+"""Device-side building blocks of the compiled plans: the packed
+reduction strategies (`reduction`), the code- and run-space aggregate
+lanes (`code_agg`) and the device join (`join`). Import from submodules."""

@@ -50,6 +50,14 @@ from snappydata_tpu.observability import tracing
 
 STRATEGIES = ("auto", "unroll", "scatter", "matmul")
 
+# Every name run_main (engine/executor.py) can put in a plan's
+# note["strategies"], so every `agg_strategy_<name>` counter there is:
+# what a packed family resolved to, plus the two lanes that take a slot
+# before it is packed (ops/code_agg.py). EXPLAIN ANALYZE and the stats
+# service report from this tuple.
+REPORTED_STRATEGIES = tuple(s for s in STRATEGIES if s != "auto") \
+    + ("dict_space", "rle_runs")
+
 # unroll's G-masked-reductions shape only ever wins in the small-G
 # dictionary regime; past this it degrades to scatter even if requested
 UNROLL_MAX_SEGMENTS = 64

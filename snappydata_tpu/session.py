@@ -1128,6 +1128,7 @@ class SnappySession:
 
         from snappydata_tpu.observability import tracing
         from snappydata_tpu.observability.metrics import global_registry
+        from snappydata_tpu.ops import reduction
 
         reg = global_registry()
         c0 = reg.counters_snapshot()
@@ -1182,8 +1183,7 @@ class SnappySession:
             "rle_run_predicates": d("rle_run_predicates"),
             "join_device_joins": d("join_device_joins"),
             "join_host_fallbacks": d("join_host_fallbacks"),
-            "strategies": [s for s in ("unroll", "scatter", "matmul",
-                                       "pallas")
+            "strategies": [s for s in reduction.REPORTED_STRATEGIES
                            if d(f"agg_strategy_{s}")],
             "compressed_fallbacks": {
                 k[len("compressed_fallback_"):]: c1.get(k, 0) - c0.get(k, 0)

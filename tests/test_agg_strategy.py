@@ -7,7 +7,7 @@ Covers the perf-guard contracts the CI must hold:
   old path issued one masked reduction per group per slot);
 - tile partials merge on device (scan_tile_device_merges) and never take
   the per-tile host round trip when the group space is tile-aligned;
-- unroll / scatter / matmul / pallas-interpret agree bit-for-bit on
+- unroll / scatter / matmul agree bit-for-bit on
   exactly-summable inputs across dtypes, null patterns, empty groups,
   and G around the 64-group unroll boundary;
 - the count accumulator widens past the int32 row bound.
@@ -30,10 +30,12 @@ from snappydata_tpu.ops import reduction
 def props():
     p = config.global_properties()
     saved = (p.agg_reduce_strategy, p.gidx_cache_bytes,
-             p.column_batch_rows, p.scan_tile_bytes)
+             p.column_batch_rows, p.scan_tile_bytes,
+             p.decimal_as_float64, p.agg_on_codes)
     yield p
     (p.agg_reduce_strategy, p.gidx_cache_bytes,
-     p.column_batch_rows, p.scan_tile_bytes) = saved
+     p.column_batch_rows, p.scan_tile_bytes,
+     p.decimal_as_float64, p.agg_on_codes) = saved
 
 
 def _counter(name: str) -> int:
@@ -112,30 +114,6 @@ def test_packed_strategies_bit_identical(nseg, dtype):
         assert (a == b).all()
 
 
-def test_pallas_interpret_matches_packed_sums():
-    """The pallas-interpret kernel's f64-combined Kahan sums agree
-    bit-for-bit with the packed families on exactly-summable f32 data."""
-    from snappydata_tpu.ops.pallas_group import grouped_reduce
-
-    rng = np.random.default_rng(3)
-    n, G = 30_000, 7
-    gidx = rng.integers(0, G - 1, n)  # group G-1 empty
-    v = rng.integers(0, 1000, n).astype(np.float32)
-    m = rng.random(n) < 0.9
-    pal = grouped_reduce(
-        [("sum", jnp.asarray(v), jnp.asarray(m)),
-         ("count", None, jnp.asarray(m))], jnp.asarray(gidx), G)
-    col = jnp.asarray(np.where(m, v, 0).astype(np.float64))
-    for strat in ("unroll", "scatter", "matmul"):
-        res = np.asarray(reduction.packed_sum(
-            [col], jnp.asarray(gidx), G, strat))[:, 0]
-        assert (np.asarray(pal[0]) == res).all(), strat
-    cnt = np.asarray(reduction.packed_sum(
-        [jnp.asarray(m.astype(np.int32))], jnp.asarray(gidx), G,
-        "scatter")).astype(np.int64)[:, 0]
-    assert (np.asarray(pal[1]) == cnt).all()
-
-
 def test_matmul_nonfinite_values_stay_group_isolated(props):
     """A NaN/Inf value must poison ONLY its own group: the matmul
     strategy's finite-guard falls back to the isolating scatter."""
@@ -195,6 +173,166 @@ def test_engine_strategies_identical_and_respecialize(props):
         assert _counter(f"agg_strategy_{strat}") > before, \
             f"{strat} was not picked despite the knob"
     s.stop()
+
+
+# Query shapes under the chip's dtype policy (float32 plates, float64
+# accumulators), each against a NumPy oracle that rounds inputs to
+# float32 as the plates do, a product to float32 once (as
+# benchmark/references/q1.py does) and sums in float64.  Each builder
+# returns (session, sql, expected rows).
+
+F32 = np.float32
+
+
+def _f64(a):
+    return a.astype(F32).astype(np.float64)
+
+
+def _shape_q1():
+    """Two string keys, ten aggregates, a filter."""
+    rng = np.random.default_rng(2)
+    n = 120_000
+    flag = rng.choice(np.array(["A", "N", "R"], dtype=object), n)
+    status = rng.choice(np.array(["F", "O"], dtype=object), n)
+    qty = np.round(rng.random(n) * 50, 0)
+    price = np.round(rng.random(n) * 2e4, 2)
+    disc = np.round(rng.random(n) * 0.1, 2)
+    s = SnappySession(catalog=Catalog())
+    s.sql("CREATE TABLE li (flag STRING, status STRING, qty DOUBLE,"
+          " price DOUBLE, disc DOUBLE) USING column")
+    s.insert_arrays("li", [flag, status, qty, price, disc])
+    sql = ("SELECT flag, status, sum(qty), sum(price),"
+           " sum(price * (1 - disc)), avg(qty), avg(disc), count(*),"
+           " min(price), max(price)"
+           " FROM li WHERE qty < 45 GROUP BY flag, status"
+           " ORDER BY flag, status")
+    dp = (price.astype(F32) * (F32(1) - disc.astype(F32))) \
+        .astype(np.float64)
+    exp = []
+    for f in ("A", "N", "R"):
+        for st in ("F", "O"):
+            m = (flag == f) & (status == st) & (qty < 45)
+            c = int(m.sum())
+            q, p, d = _f64(qty)[m], _f64(price)[m], _f64(disc)[m]
+            exp.append((f, st, float(q.sum()), float(p.sum()),
+                        float(dp[m].sum()), float(q.sum()) / c,
+                        float(d.sum()) / c, c, float(p.min()),
+                        float(p.max())))
+    return s, sql, exp
+
+
+def _shape_wide():
+    """12 sums, 6 mins and count(*) over three groups."""
+    rng = np.random.default_rng(5)
+    n = 5_000
+    k = rng.choice(np.array(["x", "y", "z"], dtype=object), n)
+    cols = [np.round(rng.random(n) * 100, 2) for _ in range(12)]
+    s = SnappySession(catalog=Catalog())
+    decls = ", ".join(f"c{i} DOUBLE" for i in range(12))
+    s.sql(f"CREATE TABLE w (k STRING, {decls}) USING column")
+    s.insert_arrays("w", [k] + cols)
+    sums = ", ".join(f"sum(c{i})" for i in range(12))
+    mins = ", ".join(f"min(c{i})" for i in range(6))
+    sql = f"SELECT k, {sums}, {mins}, count(*) FROM w GROUP BY k ORDER BY k"
+    exp = []
+    for key in ("x", "y", "z"):
+        m = k == key
+        exp.append((key,) + tuple(float(_f64(c)[m].sum()) for c in cols)
+                   + tuple(float(_f64(c)[m].min()) for c in cols[:6])
+                   + (int(m.sum()),))
+    return s, sql, exp
+
+
+def _shape_nullable_key():
+    """A nullable key (the extra code slot) and an exact int sum beside
+    float slots; every value is exact in float32."""
+    s = SnappySession(catalog=Catalog())
+    s.sql("CREATE TABLE t (k STRING, v DOUBLE, i INT) USING column")
+    s.sql("INSERT INTO t VALUES ('a', 1.5, 10), ('a', 2.5, 20),"
+          " (NULL, 4.0, 40), ('b', 8.0, 80), (NULL, 0.5, 5)")
+    sql = ("SELECT k, sum(v), sum(i), count(v), min(v), max(v) FROM t"
+           " GROUP BY k ORDER BY k")
+    exp = [(None, 4.5, 45, 2, 0.5, 4.0), ("a", 4.0, 30, 2, 1.5, 2.5),
+           ("b", 8.0, 80, 1, 8.0, 8.0)]
+    return s, sql, exp
+
+
+def _shape_global():
+    """No group: a bare sum, an average and a product under a filter."""
+    rng = np.random.default_rng(3)
+    n = 500_000
+    v = np.round(rng.random(n) * 2e4, 2)
+    q = rng.integers(1, 50, n).astype(np.float64)
+    s = SnappySession(catalog=Catalog())
+    s.sql("CREATE TABLE pr (v DOUBLE, q DOUBLE) USING column")
+    s.insert_arrays("pr", [v, q])
+    sql = "SELECT sum(v), avg(v), sum(v * q) FROM pr WHERE q < 25"
+    m = q < 25
+    sv = float(_f64(v)[m].sum())
+    vq = (v.astype(F32) * q.astype(F32)).astype(np.float64)
+    exp = [(sv, sv / int(m.sum()), float(vq[m].sum()))]
+    return s, sql, exp
+
+
+_F32_SHAPES = {"q1": _shape_q1, "wide": _shape_wide,
+               "nullable_key": _shape_nullable_key,
+               "global": _shape_global}
+
+
+@pytest.mark.parametrize("strategy", ["unroll", "scatter", "matmul"])
+@pytest.mark.parametrize("shape", list(_F32_SHAPES))
+def test_engine_f32_plate_shapes_match_oracle(props, shape, strategy):
+    """Float32 plates with float64 accumulators is the pairing every
+    cell runs on the chip.  Under each forced strategy: keys, counts,
+    NULLs, int sums and row order exact; float sums, averages, min and
+    max to 1e-9 of max(|oracle|, 1), the benchmark's own limit; and the
+    float family really took the forced strategy."""
+    props.decimal_as_float64 = False
+    props.agg_reduce_strategy = strategy
+    s, sql, exp = _F32_SHAPES[shape]()
+    before = _counter(f"agg_strategy_{strategy}")
+    got = s.sql(sql).rows()
+    s.stop()
+    assert _counter(f"agg_strategy_{strategy}") > before
+    assert len(got) == len(exp)
+    for rg, re in zip(got, exp):
+        assert len(rg) == len(re)
+        for a, b in zip(rg, re):
+            if isinstance(b, float):
+                assert isinstance(a, float), (rg, re)
+                assert abs(a - b) <= 1e-9 * max(abs(b), 1.0), (rg, re)
+            else:
+                assert a == b and type(a) is type(b), (rg, re)
+
+
+def test_explain_and_stats_name_every_lane_the_executor_counts(props):
+    """EXPLAIN ANALYZE and /status/api/v1/scan report from the one tuple
+    of names run_main counts under: a dictionary-space sum is named by
+    both, and no `agg_strategy_*` counter exists outside the tuple."""
+    from snappydata_tpu.observability.stats_service import scan_snapshot
+
+    props.agg_on_codes = "on"
+    rng = np.random.default_rng(23)
+    n = 20_000
+    s = SnappySession(catalog=Catalog())
+    s.sql("CREATE TABLE ac (g BIGINT, q DOUBLE) USING column")
+    s.insert_arrays("ac", [
+        rng.integers(0, 6, n).astype(np.int64),
+        rng.choice(np.array([0.5, 1.25, 2.0, 3.75, 8.5]), n)])
+    c0 = global_registry().counters_snapshot()
+    rows = s.sql("EXPLAIN ANALYZE SELECT g, sum(q), count(*) FROM ac "
+                 "GROUP BY g").rows()
+    c1 = global_registry().counters_snapshot()
+    s.stop()
+    raised = {k[len("agg_strategy_"):] for k in c1
+              if k.startswith("agg_strategy_") and c1[k] > c0.get(k, 0)}
+    assert "dict_space" in raised
+    assert raised <= set(reduction.REPORTED_STRATEGIES)
+    agg = [r[0] for r in rows if "strategy=" in r[0]]
+    assert len(agg) == 1
+    named = agg[0].split("strategy=")[1].split()[0].rstrip("]").split(",")
+    assert set(named) == raised
+    assert raised <= set(scan_snapshot()["agg_strategies"])
 
 
 def test_reduce_passes_constant_in_slot_count(props):

@@ -358,99 +358,6 @@ def test_rle_run_arithmetic_matches_expansion():
     assert (mask_rows == (expanded >= 5.0)).all()
 
 
-def test_fused_pallas_kernels_match_engine(tmp_path):
-    """The fused decode+filter+aggregate kernels (interpret mode on
-    CPU) against the engine's answers on a small TPC-H load — the
-    Q6 and Q1 shapes the bench lane times."""
-    import jax
-
-    from snappydata_tpu.ops.pallas_group import grouped_code_reduce
-    from snappydata_tpu.ops.pallas_reduce import fused_code_filter_sum
-    from snappydata_tpu.storage.device import build_device_table
-    from snappydata_tpu.storage.device_decode import CodePlate
-    from snappydata_tpu.utils import tpch
-
-    saved = _props().column_batch_rows
-    _props().column_batch_rows = 1 << 14
-    try:
-        s = SnappySession(catalog=Catalog())
-        tpch.load_tpch(s, sf=0.02, seed=11)
-        data = s.catalog.lookup_table("lineitem").data
-        data.force_rollover()   # tail rows leave the row buffer
-        QTY, PRICE, DISC, TAX, RF, LS, SHIP = 4, 5, 6, 7, 8, 9, 10
-        dt = build_device_table(data, None,
-                                [QTY, PRICE, DISC, TAX, RF, LS, SHIP])
-        qp, dp, tp = dt.columns[QTY], dt.columns[DISC], dt.columns[TAX]
-        assert isinstance(qp, CodePlate) and isinstance(dp, CodePlate)
-        B = int(dt.valid.shape[0])
-
-        def thresh(ci, lit, side):
-            dom, sizes = dt.dict_domains[ci]
-            out = np.zeros(B, dtype=np.int32)
-            for i in range(B):
-                sz = int(sizes[i])
-                out[i] = np.searchsorted(dom[i, :sz], lit, side) \
-                    if sz else 0
-            return out
-
-        days = tpch._days
-        total, count = fused_code_filter_sum(
-            qp.codes, dp.codes, dt.columns[SHIP], dt.columns[PRICE],
-            dt.valid, dp.dicts,
-            thresh(QTY, 24.0, "left"),
-            thresh(DISC, 0.05, "left"),
-            thresh(DISC, 0.07, "right") - 1,
-            days("1994-01-01"), days("1995-01-01"))
-        exp_cnt = s.sql(
-            "SELECT count(*) FROM lineitem "
-            "WHERE l_shipdate >= DATE '1994-01-01' "
-            "AND l_shipdate < DATE '1995-01-01' "
-            "AND l_discount BETWEEN 0.05 AND 0.07 "
-            "AND l_quantity < 24").rows()[0][0]
-        exp_rev = s.sql(tpch.Q6).rows()[0][0]
-        assert int(count) == int(exp_cnt)
-        assert float(total) == pytest.approx(exp_rev, rel=5e-5)
-
-        rf, ls = dt.columns[RF], dt.columns[LS]
-        rfd, lsd = dt.dictionaries[RF], dt.dictionaries[LS]
-        nls = len(lsd)
-        G = len(rfd) * nls
-        gidx = rf * nls + ls
-        mask = dt.valid & (dt.columns[SHIP] <= days("1998-12-01") - 90)
-        qdom, _ = dt.dict_domains[QTY]
-        ddom, _ = dt.dict_domains[DISC]
-        tdom, _ = dt.dict_domains[TAX]
-        outs = jax.block_until_ready(grouped_code_reduce(
-            gidx, mask,
-            [("count",),
-             ("sum", None, [(qp.codes, qdom)]),
-             ("sum", dt.columns[PRICE], []),
-             ("sum", dt.columns[PRICE], [(dp.codes, 1.0 - ddom)]),
-             ("sum", dt.columns[PRICE], [(dp.codes, 1.0 - ddom),
-                                         (tp.codes, 1.0 + tdom)])],
-            G))
-        engine = {(r[0], r[1]): r for r in s.sql(tpch.Q1).rows()}
-        matched = 0
-        for g in range(G):
-            key = (str(rfd[g // nls]), str(lsd[g % nls]))
-            cnt = int(outs[0][g])
-            if key not in engine:
-                assert cnt == 0, (key, cnt)
-                continue
-            matched += 1
-            row = engine[key]
-            assert cnt == int(row[9]), (key, cnt, row[9])
-            for got, exp in ((float(outs[1][g]), row[2]),
-                             (float(outs[2][g]), row[3]),
-                             (float(outs[3][g]), row[4]),
-                             (float(outs[4][g]), row[5])):
-                assert got == pytest.approx(exp, rel=5e-5), (key, got, exp)
-        assert matched == len(engine)
-        s.stop()
-    finally:
-        _props().column_batch_rows = saved
-
-
 def test_scan_snapshot_and_rest_surface():
     import json
     import urllib.request
