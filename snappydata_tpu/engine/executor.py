@@ -319,7 +319,8 @@ class CompiledPlan:
                  agg_notes: Optional[Dict] = None,
                  tile_merge: Optional[Dict] = None,
                  kind: str = "plan",
-                 join_notes: Optional[Dict] = None):
+                 join_notes: Optional[Dict] = None,
+                 decode_notes: Optional[Dict] = None):
         self.relations = relations
         # what the plan IS (agg / global_agg / scan, join_ in front when
         # it joins): the stem of the names its jitted functions carry
@@ -339,6 +340,9 @@ class CompiledPlan:
         # trace-time notes of the plan's joins per static key (how many
         # lowered, probe keys searched, expanded output slots)
         self.join_notes = join_notes
+        # trace-time notes of the plan's CodePlate decodes per (static
+        # key, phase): site -> the form `dict_decode` emitted it in
+        self.decode_notes = {} if decode_notes is None else decode_notes
         # partial-raw merge metadata: per-output merge ops + group-card
         # check for the tiled scan's on-device partial merge
         self.tile_merge = tile_merge
@@ -607,7 +611,7 @@ class CompiledPlan:
                 outs = _settle(self._noted_call(
                     static, "main", fn,
                     (tuple(arrays), tuple(aux), pvals, pre)))
-                self._note_slots(sp, static)
+                self._note_slots(sp, static, ("pre", "main"))
             # a gidx-cache hit SKIPPED the pre pass — its code predicates
             # didn't run this execution (review finding: they were
             # re-counted in proportion to the hit rate)
@@ -626,12 +630,12 @@ class CompiledPlan:
                 outs = _settle(self._noted_call(
                     static, "single", fn,
                     (tuple(arrays), tuple(aux), pvals)))
-                self._note_slots(sp, static)
+                self._note_slots(sp, static, ("single",))
             self._count_compressed(reg, static, ("single",))
         self._count_agg_notes(reg, static)
         return tables, outs, sp
 
-    def _note_slots(self, sp, static) -> None:
+    def _note_slots(self, sp, static, phases) -> None:
         """The main dispatch span says how its aggregate slots reduced,
         from the trace-time notes (so after the call that may trace): how
         many the dictionary-space lane took, and how many of any family
@@ -648,6 +652,20 @@ class CompiledPlan:
         jnote = self.join_notes.get(static) if self.join_notes else None
         for key in _JOIN_NOTE_KEYS:
             sp.set(key, jnote[key] if jnote else 0)
+        # and its dictionary decodes by form: the CodePlate columns
+        # make_ctx decoded (counted at trace time, so the ones XLA then
+        # dropped as unread are in) and the group-key remaps, once a
+        # site over the statement's phases (both phases of a split plan
+        # decode the same plates)
+        from snappydata_tpu.storage.device_decode import (DECODE_GATHER,
+                                                          DECODE_SELECT)
+
+        sites = {}
+        for phase in phases:
+            sites.update(self.decode_notes.get((static, phase), {}))
+        forms = list(sites.values())
+        sp.set("dict_select_plates", forms.count(DECODE_SELECT))
+        sp.set("dict_gather_plates", forms.count(DECODE_GATHER))
         # 1 once the outputs are home and the overflow flag is set
         # (CompiledPlan.execute): the statement then reruns on the host
         sp.set("groups_overflow", 0)
@@ -1216,15 +1234,24 @@ class Compiler:
 
         n_rel = len(self.relations)
 
-        def make_ctx(static, arrays, aux, params) -> "_TraceCtx":
+        # trace-time note per (static key, phase): the form each
+        # CodePlate decode was emitted in, by site
+        # (CompiledPlan._note_slots)
+        decode_notes: Dict[tuple, Dict[tuple, str]] = {}
+
+        def make_ctx(static, arrays, aux, params,
+                     phase="single") -> "_TraceCtx":
             from snappydata_tpu.storage.device_decode import (
                 BitPlate, CodePlate, RlePlate, bit_values, code_values,
-                rle_values)
+                dict_decode_form, rle_values)
 
+            # a fresh note each trace: a retrace under the same static
+            # key (the plates re-bound decoded after a write) starts over
+            decode_note = decode_notes[(static, phase)] = {}
             # unpack per-relation arrays
             rel_runtimes = []
             pos = 0
-            for r in self.relations:
+            for ri, r in enumerate(self.relations):
                 entries = []
                 for ci in r.used:
                     entries.append(arrays[pos])
@@ -1242,6 +1269,10 @@ class Compiler:
                         dv = DVal(code_values(col_arr), null_arr,
                                   f.dtype, _dict_provider(r.info, ci))
                         dv.cplate = col_arr
+                        # counted here, so before XLA drops the decodes
+                        # nothing reads (a column met on its codes alone)
+                        decode_note[(ri, ci)] = dict_decode_form(
+                            col_arr.dicts.shape[1])
                     elif isinstance(col_arr, RlePlate):
                         dv = DVal(rle_values(col_arr, cap), null_arr,
                                   f.dtype, _dict_provider(r.info, ci))
@@ -1254,7 +1285,8 @@ class Compiler:
                                   _dict_provider(r.info, ci))
                     cols[ci] = dv
                 rel_runtimes.append((cols, valid))
-            return _TraceCtx(rel_runtimes, aux, params, static)
+            return _TraceCtx(rel_runtimes, aux, params, static,
+                             decode_note)
 
         join_notes = {} if n_rel > 1 else None
 
@@ -1272,10 +1304,12 @@ class Compiler:
             main_emit = self._agg_main_emit
 
             def traced_pre(static, arrays, aux, params):
-                return pre_emit(make_ctx(static, arrays, aux, params))
+                return pre_emit(make_ctx(static, arrays, aux, params,
+                                         "pre"))
 
             def traced_main(static, arrays, aux, params, pre):
-                return main_emit(make_ctx(static, arrays, aux, params), pre)
+                return main_emit(make_ctx(static, arrays, aux, params,
+                                          "main"), pre)
 
         out_scope = [oc if isinstance(oc, _ScopeCol)
                      else _ScopeCol(oc.name, oc.dtype, oc.dict_provider)
@@ -1287,6 +1321,7 @@ class Compiler:
                           agg_notes=getattr(self, "_agg_notes", None),
                           tile_merge=getattr(self, "_tile_merge", None),
                           join_notes=join_notes,
+                          decode_notes=decode_notes,
                           kind=("join_" if n_rel > 1 else "")
                           + (("agg" if plan.group_exprs else "global_agg")
                              if is_agg else "scan")
@@ -2715,17 +2750,21 @@ class Compiler:
                         # group index straight from the table-global
                         # value domain: a dict-encoded plate remaps its
                         # per-batch CODES through the domain (pure code
-                        # arithmetic, value plate never gathered);
-                        # anything else searchsorts its values
+                        # arithmetic: `remap` [B, Dp] is the table the
+                        # codes decode through, the value plate is never
+                        # read); anything else searchsorts its values
                         gd = jnp.asarray(ctx.aux[ki[2][1]])
                         if (kd.cplate is not None
                                 and ctx.static[code_agg_si] != 0):
+                            from snappydata_tpu.storage.device_decode \
+                                import dict_decode, dict_decode_form
+
                             remap = jnp.searchsorted(
                                 gd, kd.cplate.dicts).astype(jnp.int64)
-                            kv = jnp.take_along_axis(
-                                remap,
-                                kd.cplate.codes.astype(jnp.int32),
-                                axis=1).reshape(-1)
+                            kv = dict_decode(
+                                remap, kd.cplate.codes).reshape(-1)
+                            ctx.decode_note[("group_remap", ki[2][1])] = \
+                                dict_decode_form(remap.shape[1])
                         else:
                             vals = _broadcast_to_mask(
                                 kd.value, out.valid).reshape(-1)
@@ -3298,11 +3337,14 @@ _JOIN_NOTE_KEYS = ("join_device_joins", "join_probe_rows",
 
 
 class _TraceCtx:
-    def __init__(self, rels, aux, params, static):
+    def __init__(self, rels, aux, params, static, decode_note):
         self.rels = rels
         self.aux = aux
         self.params = params
         self.static = static
+        # site -> form of the plan's dictionary decodes (make_ctx's, and
+        # the group-key remaps add theirs)
+        self.decode_note = decode_note
         # trace-time side channel: nested nodes (the expanding join) OR
         # their data-dependent overflow flags here; the region root folds
         # it into the compiled output's third slot so the executor can

@@ -13,11 +13,13 @@ equivalents are vectorized XLA programs applied at cold bind:
 * BOOLEAN_BITSET: upload the packed bits (uint8 [cap/8]) and unpack with
   shift/mask ops — an 8× transfer reduction.
 * VALUE_DICT: low-cardinality numeric columns upload uint8/uint16 codes
-  [cap] plus the tiny value dictionary [D] and gather on device — an
-  itemsize× (≥4×) transfer reduction. This is the encoding the default
-  TPC-H scan engages (l_quantity/l_discount/l_tax are 50/11/9 distinct
-  f64 values), so the bench's device_decode counters are nonzero on the
-  stock workload.
+  [cap] plus the tiny value dictionary [D] and decode on device
+  (`dict_decode`: a tree of selects over the code's bits up to
+  `DICT_SELECT_MAX_WIDTH` slots, a gather past it) — an itemsize× (≥4×)
+  transfer reduction. This is the encoding the default TPC-H scan
+  engages (l_quantity/l_discount/l_tax are 50/11/9 distinct values,
+  padded to 64/16/16 slots), so the bench's device_decode counters are
+  nonzero on the stock workload.
 
 Compressed-domain execution (r06) goes one step further: under
 `scan_compressed_domain` the plates THEMSELVES stay encoded in HBM
@@ -152,12 +154,67 @@ def rle_views_to_plate(rle_cols, cap: int, dt,
     return _rle_expand(place(vals), place(ends), cap)
 
 
+# A dictionary of at most this many (padded) slots decodes by selects, a
+# wider one by the gather.  Set from a sweep on the chip (PERF.md section
+# 6, PR 30; one v5e, a plate of 96 x 131,072 rows, float32): the gather
+# takes 105-130 ms whatever the width; the select tree 0.7 / 0.9 / 1.9 /
+# 5.6 / 19.6 ms at 16 / 64 / 256 / 1,024 / 4,096 slots, so it never loses
+# on run time in the range swept.  What grows is the compile: 0.2 / 0.7 /
+# 9 / 33 / 155 s a fusion.  256 is where uint8 codes end (every 4-byte
+# value's dictionary, so every float32 one under the TPU's plate policy)
+# and the last width whose compile a few dozen executions pay back.
+DICT_SELECT_MAX_WIDTH = 256
+
+DECODE_SELECT = "select"
+DECODE_GATHER = "gather"
+
+
+def dict_decode_form(width: int) -> str:
+    """Which form `dict_decode` emits for a table of `width` slots a
+    batch: the one resolver, asked at trace time (the width is a static
+    shape), and by `make_ctx` for the plan's engagement note."""
+    return DECODE_SELECT if width <= DICT_SELECT_MAX_WIDTH \
+        else DECODE_GATHER
+
+
+def dict_decode(dicts: jnp.ndarray, codes: jnp.ndarray) -> jnp.ndarray:
+    """`dicts[b, codes[b, j]]`, bit for bit: the one decode of a per-batch
+    table `dicts` [B, D] by codes [B, cap] (uint8/uint16, every code
+    below D).  The scope names the step, not the HLO op.
+
+    Up to `DICT_SELECT_MAX_WIDTH` slots it is a tree of selects over the
+    code's bits: level l keeps, of each pair of neighbouring candidates,
+    the one bit l of the code names, so D - 1 selects and log2 D bit
+    tests an element, all elementwise, which XLA fuses into whatever
+    reads the values.  The first level's candidates are the table's
+    columns `dicts[:, k:k+1]`, broadcast over the row axis alone, so
+    under a mesh the decode shards on the batch axis as the gather did.
+    A select passes its operand's bits through (NaN payloads, -0.0).
+    Past the constant it is `take_along_axis`, whose cost on the TPU
+    hardly depends on D (8-10 ns an element)."""
+    with tracing.op_scope("dict_gather"):
+        idx = codes.astype(jnp.int32)
+        if dict_decode_form(dicts.shape[1]) == DECODE_GATHER:
+            return jnp.take_along_axis(dicts, idx, axis=1)
+        cands = [dicts[:, k:k + 1] for k in range(dicts.shape[1])]
+        level = 0
+        while len(cands) > 1:
+            bit = ((idx >> level) & 1).astype(jnp.bool_)
+            # an odd candidate out has bit `level` clear in every code
+            # that can still name it: it passes through
+            cands = [jnp.where(bit, cands[i + 1], cands[i])
+                     for i in range(0, len(cands) - 1, 2)] \
+                + ([cands[-1]] if len(cands) % 2 else [])
+            level += 1
+        return jnp.broadcast_to(cands[0], codes.shape)
+
+
 @jax.jit
 def _valdict_expand(codes: jnp.ndarray, dicts: jnp.ndarray):
-    """codes: [N, cap] uint8; dicts: [N, D] (D padded per call).  Lane j
-    of row i takes dicts[i, codes[i, j]] — a per-batch device gather."""
-    with tracing.op_scope("dict_gather"):
-        return jnp.take_along_axis(dicts, codes.astype(jnp.int32), axis=1)
+    """codes: [N, cap] uint8/uint16; dicts: [N, D] (D padded per call).
+    Lane j of row i takes dicts[i, codes[i, j]]: the eager bind-time
+    decode to a resident [N, cap] plate."""
+    return dict_decode(dicts, codes)
 
 
 def _valdict_code_dtype(vd_cols) -> np.dtype:
@@ -171,7 +228,7 @@ def valdict_views_to_plate(vd_cols, cap: int, dt,
                            place=jnp.asarray) -> jnp.ndarray:
     """Stack N value-dict columns into decoded plates [N, cap]: the
     uint8/uint16 codes and the (padded) dictionaries cross the link, the
-    values-gather runs in-trace."""
+    decode (`dict_decode`) runs on the device."""
     d_max = max(1, max(len(c.dictionary) for c in vd_cols))
     n = len(vd_cols)
     codes = np.zeros((n, cap), dtype=_valdict_code_dtype(vd_cols))
@@ -328,12 +385,10 @@ def bit_plates(bit_cols, b: int, cap: int, place=jnp.asarray) -> BitPlate:
 # --- in-trace consumers ---------------------------------------------------
 
 def code_values(plate: CodePlate) -> jnp.ndarray:
-    """Lazy decode of a CodePlate: a per-batch dictionary gather that XLA
-    fuses into whatever consumes the values (the fused
-    decode+filter+aggregate form of the default scan)."""
-    with tracing.op_scope("dict_gather"):
-        return jnp.take_along_axis(plate.dicts,
-                                   plate.codes.astype(jnp.int32), axis=1)
+    """Lazy decode of a CodePlate (`dict_decode`), which XLA fuses into
+    whatever consumes the values (the fused decode+filter+aggregate form
+    of the default scan) and drops where nothing does."""
+    return dict_decode(plate.dicts, plate.codes)
 
 
 def rle_values(plate: RlePlate, cap: int) -> jnp.ndarray:

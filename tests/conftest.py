@@ -82,3 +82,17 @@ def session():
     s = SnappySession(catalog=Catalog())
     yield s
     s.stop()
+
+
+@pytest.fixture(params=["select_to_the_constant", "gather_alone"])
+def decode_form(request, monkeypatch):
+    """Both lowerings of `device_decode.dict_decode`: as shipped (a
+    dictionary up to `DICT_SELECT_MAX_WIDTH` slots by selects, a wider
+    one by the gather) and with the constant at 0, where every
+    dictionary takes the gather.  Read at trace time: a test builds its
+    session, and so its plans, after asking for the fixture."""
+    from snappydata_tpu.storage import device_decode
+
+    if request.param == "gather_alone":
+        monkeypatch.setattr(device_decode, "DICT_SELECT_MAX_WIDTH", 0)
+    return request.param

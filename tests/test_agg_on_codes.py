@@ -86,10 +86,12 @@ def _assert_rows_equal(a, b):
                 assert x == y, (ra, rb)
 
 
-def test_grouped_matrix_code_vs_decoded():
+def test_grouped_matrix_code_vs_decoded(decode_form):
     """The core equivalence sweep: every aggregate op x numeric/string/
     NULL-bearing group keys x dict/RLE/plain measures x in- and out-of-
-    dictionary filter literals, each value-asserted on == off."""
+    dictionary filter literals, each value-asserted on == off, under
+    both forms of the dictionary decode (the group-key remap of `g`
+    among them)."""
     s, cols, _ = _agg_session()
     queries = [
         "SELECT g, count(*), sum(q), avg(q), min(q), max(q) FROM ac "
@@ -289,7 +291,7 @@ def test_bench_check_guards_code_agg_lane():
 
 
 @pytest.mark.mesh
-def test_mesh_lane_matches_single_device():
+def test_mesh_lane_matches_single_device(decode_form):
     from snappydata_tpu.parallel import MeshContext, data_mesh
 
     s, cols, _ = _agg_session(n=16_000, with_nulls=False)
@@ -430,6 +432,45 @@ def test_q1_plan_holds_no_scatter_under_group_reduce(monkeypatch):
         s.stop()
     finally:
         props.decimal_as_float64, props.agg_reduce_strategy = saved
+
+
+def _gathers_under(hlo: str, scope: str):
+    """Op names of `gather` primitives traced under `scope` (a fused
+    gather may keep the scope on the fusion's line alone)."""
+    import re
+
+    return re.findall(rf'op_name="[^"]*/{scope}/[^"]*gather"', hlo)
+
+
+@pytest.mark.parametrize("label", ["q1", "q6"])
+def test_tpch_plans_hold_no_gather_under_dict_gather(monkeypatch, label):
+    """Q1 `main` and Q6 at the TPC-H widths (l_discount and l_tax 16
+    slots, l_quantity 64), float32 plates as on the chip: the step named
+    `dict_gather` is there and holds no `gather` op.  The control is
+    the same plan with the constant at 0, where the guard finds one."""
+    from snappydata_tpu.storage import device_decode
+    from snappydata_tpu.utils import tpch
+
+    props = _props()
+    saved = props.decimal_as_float64
+    sql = {"q1": tpch.Q1, "q6": tpch.Q6}[label]
+    try:
+        props.decimal_as_float64 = False
+        props.set("agg_on_codes", "on")
+        s = SnappySession(catalog=Catalog())
+        tpch.load_tpch(s, sf=0.002, seed=11)
+        hlo, _ = _hlo_of_main(monkeypatch, s, sql)
+        assert "/dict_gather/" in hlo
+        assert _gathers_under(hlo, "dict_gather") == []
+        s.stop()
+        monkeypatch.setattr(device_decode, "DICT_SELECT_MAX_WIDTH", 0)
+        s = SnappySession(catalog=Catalog())
+        tpch.load_tpch(s, sf=0.002, seed=11)
+        hlo, _ = _hlo_of_main(monkeypatch, s, sql)
+        assert _gathers_under(hlo, "dict_gather")
+        s.stop()
+    finally:
+        props.decimal_as_float64 = saved
 
 
 def test_past_the_product_bound_the_slot_rides_the_packed_family():

@@ -94,10 +94,12 @@ def test_encodings_at_rest_are_what_the_suite_assumes():
     s.stop()
 
 
-def test_property_matrix_code_vs_decoded():
+def test_property_matrix_code_vs_decoded(decode_form):
     """The core equivalence sweep: every comparison op × in/out-of-
     dictionary/boundary literals × every encoding × NULL rows, each
-    value-asserted compressed == decoded."""
+    value-asserted compressed == decoded, under both forms of the
+    dictionary decode (`qty` 64 slots; `wide` 8,192, the gather in
+    both)."""
     s, cols, _ = _mixed_session()
     queries = []
     for op in ("=", "!=", "<", "<=", ">", ">="):
@@ -356,6 +358,71 @@ def test_rle_run_arithmetic_matches_expansion():
     mask_rows = np.asarray(rle_cmp_mask(
         lambda v, lit: v >= lit, plate, jnp.asarray(5.0), cap))
     assert (mask_rows == (expanded >= 5.0)).all()
+
+
+def _decode_width(width):
+    """The parametrised widths name the constant as shipped."""
+    c = device_decode.DICT_SELECT_MAX_WIDTH
+    return {"constant": c, "past_constant": c + 1}.get(width, width)
+
+
+@pytest.mark.parametrize("force", ["select", "gather", "as_shipped"])
+@pytest.mark.parametrize("table_dtype", ["float32", "float64", "int64"])
+@pytest.mark.parametrize("code_dtype", ["uint8", "uint16"])
+@pytest.mark.parametrize("width", [1, 2, 16, 64, "constant",
+                                   "past_constant"])
+def test_dict_decode_is_take_along_axis_bit_for_bit(
+        monkeypatch, width, code_dtype, table_dtype, force):
+    """`dict_decode(dicts, codes)` is `dicts[b, codes[b, j]]` to the bit
+    in either form: NaN, -0.0 and the infinities pass through, a
+    dictionary padded by repeating its last value decodes as one that is
+    not, a wholly padded batch (codes 0 over a row of zeros) reads 0."""
+    import jax
+    import jax.numpy as jnp
+
+    dp = _decode_width(width)
+    monkeypatch.setattr(device_decode, "DICT_SELECT_MAX_WIDTH",
+                        {"select": dp, "gather": 0}.get(
+                            force, device_decode.DICT_SELECT_MAX_WIDTH))
+    want_form = {"select": device_decode.DECODE_SELECT,
+                 "gather": device_decode.DECODE_GATHER}.get(
+        force, device_decode.DECODE_SELECT if width != "past_constant"
+        else device_decode.DECODE_GATHER)
+    assert device_decode.dict_decode_form(dp) == want_form
+    b, cap = 5, 384
+    rng = np.random.default_rng(dp * 7 + len(code_dtype))
+    dt = np.dtype(table_dtype)
+    if dt.kind == "f":
+        dicts = np.sort(rng.standard_normal((b, dp)), axis=1).astype(dt)
+        special = np.array([np.nan, -0.0, 0.0, np.inf, -np.inf], dtype=dt)
+        for i, v in enumerate(special[:dp]):
+            dicts[i % b, (i * 3) % dp] = v
+    else:
+        dicts = np.sort(rng.integers(-2**62, 2**62, (b, dp)), axis=1)
+        dicts.flat[0], dicts.flat[-1] = np.iinfo(dt).min, np.iinfo(dt).max
+    # batch 1: a short dictionary padded by repeating its last value;
+    # batch 2: wholly padded
+    real = max(1, dp // 3)
+    dicts[1, real:] = dicts[1, real - 1]
+    dicts[2, :] = 0
+    hi = min(dp, np.iinfo(code_dtype).max + 1)
+    codes = rng.integers(0, hi, (b, cap)).astype(code_dtype)
+    codes[0, :hi] = np.arange(hi)[:cap]         # every slot met
+    codes[1] = rng.integers(0, min(real, hi), cap)
+    codes[2] = 0
+    want = np.take_along_axis(dicts, codes.astype(np.int64), axis=1)
+
+    def decode(d, c):       # a function of its own: jit caches by function
+        return device_decode.dict_decode(d, c)
+
+    got = np.asarray(jax.jit(decode)(jnp.asarray(dicts),
+                                     jnp.asarray(codes)))
+    assert got.dtype == dt and got.shape == (b, cap)
+    bits = {4: np.uint32, 8: np.uint64}[dt.itemsize]
+    assert (got.view(bits) == want.view(bits)).all()
+    hlo = jax.jit(decode).lower(jnp.asarray(dicts),
+                                jnp.asarray(codes)).as_text()
+    assert ("gather" in hlo) == (want_form == device_decode.DECODE_GATHER)
 
 
 def test_scan_snapshot_and_rest_surface():

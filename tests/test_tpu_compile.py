@@ -1,8 +1,10 @@
 """Kernels of the main path compiled for the chip without the chip: the
 TPU compiler is installed here and compiles for a described v5e, so what
 it refuses, and what it would hold in HBM, is known before a chip run.
-Nothing runs: no result, no time.  The join case compiles for about two
-minutes (four wide sorts); the rest take seconds.
+Nothing runs on the chip: no result, no time (the plan cases run one
+statement over one batch on the CPU, to be handed the plan).  The join
+case compiles for about two minutes (four wide sorts); the rest take
+seconds.
 
 The topology is described inside a fixture, never at import or
 collection: only the worker that is handed this file loads the TPU's
@@ -10,6 +12,7 @@ library.  Keep every such test in this one file.
 """
 
 import os
+import re
 
 import pytest
 
@@ -66,6 +69,83 @@ def test_dict_space_count_transient_does_not_grow_with_batches(one_chip,
     # each step slices its own rows, so nothing is sized by the table
     assert max(temps) <= code_agg.DICT_SPACE_CHUNK_BYTES, temps
     assert temps[1] <= temps[0] + (1 << 20), temps
+
+
+def _plan_compiled_at_sf2(monkeypatch, one_chip, sql):
+    """The statement's main (or single) phase as the engine itself
+    builds it (float32 plates, the lanes on, TPC-H's dictionary widths),
+    compiled for the described chip with every plate at SF 2's shape:
+    96 batches of 131,072 rows.  The plan is traced here over one batch
+    of SF 0.002; only the shapes are SF 2's."""
+    import jax
+    import jax.numpy as jnp
+
+    from snappydata_tpu import SnappySession, config
+    from snappydata_tpu.catalog import Catalog
+    from snappydata_tpu.engine.executor import CompiledPlan
+    from snappydata_tpu.utils import tpch
+
+    seen = []
+    orig = CompiledPlan._noted_call
+
+    def spy(self, static, phase, fn, args):
+        seen.append((phase, fn, args))
+        return orig(self, static, phase, fn, args)
+
+    props = config.global_properties()
+    saved = (props.decimal_as_float64, props.get("agg_on_codes"))
+    try:
+        props.decimal_as_float64 = False
+        props.set("agg_on_codes", "on")
+        s = SnappySession(catalog=Catalog())
+        tpch.load_tpch(s, sf=0.002, seed=11)
+        monkeypatch.setattr(CompiledPlan, "_noted_call", spy)
+        s.sql(sql).rows()
+        monkeypatch.setattr(CompiledPlan, "_noted_call", orig)
+        s.stop()
+    finally:
+        props.decimal_as_float64 = saved[0]
+        props.set("agg_on_codes", saved[1])
+    _phase, fn, args = [x for x in seen if x[0] in ("main", "single")][-1]
+    batches = 96
+
+    def sds(a, dims):
+        return jax.ShapeDtypeStruct(tuple(dims), a.dtype, sharding=one_chip)
+
+    def scalar(a):
+        a = jnp.asarray(a)
+        return sds(a, a.shape)
+
+    shapes = [jax.tree.map(lambda a: sds(a, (batches,) + a.shape[1:]),
+                           args[0]),
+              jax.tree.map(scalar, args[1]), jax.tree.map(scalar, args[2])]
+    if len(args) == 4:      # phase A's outputs are flat over the rows
+        shapes.append(jax.tree.map(
+            lambda a: sds(a, (batches * a.shape[0],) + a.shape[1:]
+                          if a.ndim else ()), args[3]))
+    return fn.lower(*shapes).compile().as_text()
+
+
+@pytest.mark.parametrize("label", ["q6", "q1"])
+def test_plans_at_sf2_hold_no_gather_under_dict_gather(monkeypatch,
+                                                       one_chip, label):
+    """Q6 and Q1 `main` as the v5e's compiler lowers them at SF 2's batch
+    count: the step named `dict_gather` is in the module and no `gather`
+    op is under it; with the constant at 0 (the control) one is."""
+    from snappydata_tpu.storage import device_decode
+    from snappydata_tpu.utils import tpch
+
+    def gathers(hlo):
+        # the TPU's fused gather keeps the scope on the fusion's line
+        # (kind=kCustom), not on the `gather` inside it: read op names
+        return re.findall(r'op_name="[^"]*/dict_gather/[^"]*gather"', hlo)
+
+    sql = {"q1": tpch.Q1, "q6": tpch.Q6}[label]
+    hlo = _plan_compiled_at_sf2(monkeypatch, one_chip, sql)
+    assert "/dict_gather/" in hlo
+    assert gathers(hlo) == []
+    monkeypatch.setattr(device_decode, "DICT_SELECT_MAX_WIDTH", 0)
+    assert gathers(_plan_compiled_at_sf2(monkeypatch, one_chip, sql))
 
 
 def test_q3_merge_probes_at_sf1_hold_no_loop_and_share_their_sorts(one_chip):

@@ -477,12 +477,8 @@ def test_main_dispatch_carries_slot_counts(_restore_knobs, label, lane,
         props.agg_reduce_strategy = saved[1]
 
 
-def test_slot_metrics_read_the_attrs():
-    """The two metric files PR 26 added, through the benchmark's own
-    manifest and `span_attr` reader on hand-built trees: a median over
-    the window's queries; None, not an error, on a program that does
-    not set the attrs."""
-    import json
+def _bench_manifest():
+    """The benchmark's own manifest (and its directory), sound."""
     import sys
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -493,6 +489,17 @@ def test_slot_metrics_read_the_attrs():
 
     m = manifest.Manifest(root)
     assert manifest.problems(m) == []
+    return m, bench
+
+
+def test_slot_metrics_read_the_attrs():
+    """The two metric files PR 26 added, through the benchmark's own
+    manifest and `span_attr` reader on hand-built trees: a median over
+    the window's queries; None, not an error, on a program that does
+    not set the attrs."""
+    import json
+
+    m, bench = _bench_manifest()
     names = ["dict_space_slots.scan", "scatter_slots.scan"]
     listed = [p["name"] for p in m.doc["per_layer"]]
     assert [n for n in listed if n in names] == names
@@ -535,3 +542,108 @@ def test_slot_metrics_read_the_attrs():
     assert read([put, q1, q6]) == [1.0, 0.0]
     bare = stmt("q1", "query", xla_compiles=0)
     assert read([bare, bare]) == [None, None]
+
+
+def _decode_session():
+    """g: six BIGINT values (a dictionary-encoded numeric group key);
+    q: five DOUBLE values; wide: a dictionary past the constant (BIGINT:
+    a 4-byte value's dictionary ends at 256 entries, and DOUBLE is
+    float32 under the chip's plate policy)."""
+    from snappydata_tpu.storage import device_decode
+
+    n = 60_000
+    distinct = 2 * device_decode.DICT_SELECT_MAX_WIDTH + 7
+    assert distinct < n // 4
+    rng = np.random.default_rng(30)
+    s = SnappySession(catalog=Catalog())
+    s.sql("CREATE TABLE dd (k BIGINT, g BIGINT, q DOUBLE, wide BIGINT, "
+          "v DOUBLE) USING column")
+    s.insert_arrays("dd", [
+        np.arange(n, dtype=np.int64),
+        rng.integers(0, 6, n).astype(np.int64),
+        rng.choice(np.array([0.5, 1.25, 2.0, 3.75, 8.5]), n),
+        rng.integers(0, distinct, n).astype(np.int64) * 3,
+        rng.random(n)])
+    s.catalog.describe("dd").data.force_rollover()
+    return s
+
+
+def _decode_tpch():
+    from snappydata_tpu.utils import tpch
+
+    s = SnappySession(catalog=Catalog())
+    tpch.load_tpch(s, sf=0.002, seed=7)
+    return s
+
+
+_DECODE_CASES = {
+    # statement, session, knobs, (select, gather) on the main dispatch
+    "q6_code_plates": ("tpch:Q6", {}, (2, 0)),
+    "q1_code_plates": ("tpch:Q1", {}, (3, 0)),
+    "past_the_constant": ("SELECT sum(wide), count(*) FROM dd",
+                          {}, (0, 1)),
+    "both_widths": ("SELECT sum(wide * q) FROM dd", {}, (1, 1)),
+    "decoded_plates": ("SELECT sum(wide * q) FROM dd",
+                       {"scan_compressed_domain": "off"}, (0, 0)),
+    # the key's own plate and its remap through the table's domain
+    "group_key_remap": ("SELECT g, count(*), sum(v) FROM dd GROUP BY g "
+                        "ORDER BY g", {"agg_on_codes": "on"}, (2, 0)),
+    "group_key_decoded": ("SELECT g, count(*), sum(v) FROM dd GROUP BY g "
+                          "ORDER BY g", {"agg_on_codes": "off"}, (1, 0)),
+}
+
+
+@pytest.mark.parametrize("case", list(_DECODE_CASES))
+def test_decode_form_metrics_read_the_attrs(_restore_knobs, case):
+    """`dict_select_plates` / `dict_gather_plates` on the main dispatch
+    span of real statements, cold and warm, read through the benchmark's
+    own manifest and `span_attr` reader (the four metric files PR 30
+    added): one a site, counted at trace time and so before XLA drops
+    the decodes nothing reads (Q6 meets l_quantity on its codes alone
+    and still counts two); the group-key remap is a site of its own and
+    returns the decoded path's rows."""
+    from snappydata_tpu.utils import tpch
+
+    m, _ = _bench_manifest()
+    cells = {"scan": "tpch_sf2.scan", "served": "tpch_sf2.refresh"}
+    for suffix, cell in cells.items():
+        for attr in ("dict_select_plates", "dict_gather_plates"):
+            entry = next(p for p in m.doc["per_layer"]
+                         if p["name"] == f"{attr}.{suffix}")
+            assert entry["workloads"] == [cell]
+            assert entry["layer"] == "kernels"
+            assert entry["source"] == "program_counter"
+
+    sql, knobs, want = _DECODE_CASES[case]
+    props = _restore_knobs
+    props.decimal_as_float64 = False
+    saved = {k: props.get(k) for k in
+             ("scan_compressed_domain", "agg_on_codes")}
+    try:
+        for k, v in knobs.items():
+            props.set(k, v)
+        if sql.startswith("tpch:"):
+            s, sql = _decode_tpch(), getattr(tpch, sql[5:])
+        else:
+            s = _decode_session()
+        statements = []
+        for expect_name in ("jit_compile", "device_execute"):
+            rows = s.sql(sql).rows()
+            tr = tracing.ring().last().to_dict()
+            assert _main_dispatch(tr["root"])["name"] == expect_name
+            statements.append({"name": case, "kind": "query", "ok": True,
+                               "ms": tr["root"]["ms"],
+                               "traces": [{"kind": "embedded",
+                                           "root": tr["root"]}]})
+        ctx = {"statements": statements, "back": "embedded",
+               "front": "embedded"}
+        for suffix in cells:
+            assert (m.read(f"dict_select_plates.{suffix}", ctx),
+                    m.read(f"dict_gather_plates.{suffix}", ctx)) == want
+        if case == "group_key_remap":
+            props.set("scan_compressed_domain", "off")
+            assert s.sql(sql).rows() == rows
+        s.stop()
+    finally:
+        for k, v in saved.items():
+            props.set(k, v)
