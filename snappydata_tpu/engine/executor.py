@@ -597,7 +597,6 @@ class CompiledPlan:
                 _pre_cache_put(self, static, pkey, tables, pre)
             else:
                 reg.inc("gidx_cache_hits")
-                tracing.annotate("gidx_cache", "hit")
             fn = self._jitted_main.get(static)
             first = fn is None
             if first:
@@ -611,7 +610,8 @@ class CompiledPlan:
                 outs = _settle(self._noted_call(
                     static, "main", fn,
                     (tuple(arrays), tuple(aux), pvals, pre)))
-                self._note_slots(sp, static, ("pre", "main"))
+                self._note_slots(sp, static, ("pre", "main"),
+                                 gidx_cache_hit=not ran_pre)
             # a gidx-cache hit SKIPPED the pre pass — its code predicates
             # didn't run this execution (review finding: they were
             # re-counted in proportion to the hit rate)
@@ -635,16 +635,25 @@ class CompiledPlan:
         self._count_agg_notes(reg, static)
         return tables, outs, sp
 
-    def _note_slots(self, sp, static, phases) -> None:
+    def _note_slots(self, sp, static, phases,
+                    gidx_cache_hit: bool = False) -> None:
         """The main dispatch span says how its aggregate slots reduced,
         from the trace-time notes (so after the call that may trace): how
-        many the dictionary-space lane took, and how many of any family
-        were emitted as a `segment_*` scatter.  0 where none, and on a
+        many the dictionary-space lane took, how many of any family
+        were emitted as a `segment_*` scatter and how many of those
+        belong to the exact-integer family.  0 where none, and on a
         plan that aggregates nothing.  `group_slots` is the static
-        number of group segments the reduce ran over."""
+        number of group segments the reduce ran over and
+        `reduce_padded_rows` the slots it walked (batch bucket x batch
+        capacity, padding included).  `gidx_cache_hit` is 1 where the
+        statement took its group index from the cache and ran the main
+        phase alone."""
         note = self.agg_notes.get(static) if self.agg_notes else None
-        for key in ("dict_space_slots", "scatter_slots", "group_slots"):
+        for key in ("dict_space_slots", "scatter_slots",
+                    "isum_scatter_slots", "group_slots",
+                    "reduce_padded_rows"):
             sp.set(key, note[key] if note else 0)
+        sp.set("gidx_cache_hit", int(gidx_cache_hit))
         # and what its joins were: how many lowered to the device, the
         # probe keys they searched (the probe side's padded slots, one
         # search a join) and the expanded output slots of one-to-many
@@ -2629,7 +2638,10 @@ class Compiler:
             post_scope_types[gi] = expr_type(g)
             if expr_type(g).name == "string":
                 post_dicts[gi] = key_infos[gi][2]
-        post_builder = ExprBuilder(post_scope_types, {}, post_dicts)
+        # avg(BIGINT) is an exact int64 sum over an exact count: divided
+        # in the accumulators' width, as a float sum is
+        post_builder = ExprBuilder(post_scope_types, {}, post_dicts,
+                                   int_div_dtype=_acc_dtype(T.DOUBLE))
         post_runs = [post_builder.emit(_slots_to_cols(e, len(groups)))
                      for e in select_rewritten]
         self.aux_builders.extend(post_builder.aux_builders)
@@ -2867,7 +2879,7 @@ class Compiler:
             # trace is still mutating
             note = {"passes": 0, "strategies": set(), "lanes": set(),
                     "rle_fallbacks": 0, "dict_space_slots": 0,
-                    "scatter_slots": 0}
+                    "scatter_slots": 0, "isum_scatter_slots": 0}
             tok = ctx.static[code_agg_si]
             # dictionary-space SUM counts by a one-hot product shaped
             # for the MXU: auto engages it on the accelerator only (the
@@ -3116,6 +3128,8 @@ class Compiler:
                 ires = reduction.packed_sum(
                     [c for _, c in isum_cols], gidx, num_groups, istrat)
                 family_pass(istrat, len(isum_cols))
+                if istrat == "scatter":
+                    note["isum_scatter_slots"] += len(isum_cols)
                 for pos, (i, _) in enumerate(isum_cols):
                     slot_arrays[i] = ires[:, pos]
             guard_res: Dict[tuple, object] = {}
@@ -3216,7 +3230,9 @@ class Compiler:
                 "rle_fallbacks": note["rle_fallbacks"],
                 "dict_space_slots": note["dict_space_slots"],
                 "scatter_slots": note["scatter_slots"],
+                "isum_scatter_slots": note["isum_scatter_slots"],
                 "group_slots": num_groups,
+                "reduce_padded_rows": n,
                 "table": base_table_ref}
             # nested data-dependent overflows (join expansion past its
             # bucket) ride the same flag: the executor reruns on host

@@ -360,13 +360,20 @@ class ExprBuilder:
 
     def __init__(self, col_types: Dict[int, T.DataType],
                  col_nullable: Dict[int, bool],
-                 dict_getters: Dict[int, Callable[[], np.ndarray]]):
+                 dict_getters: Dict[int, Callable[[], np.ndarray]],
+                 int_div_dtype=None):
         self.col_types = col_types
         self.col_nullable = col_nullable
         self.dict_getters = dict_getters
         # aux builders: fn(params: tuple) -> np.ndarray, run at bind time
         self.aux_builders: List[Callable] = []
         self.param_dtypes: Dict[int, T.DataType] = {}
+        # float width an integer operand of "/" is cast to. None: the
+        # plates' (float32 under the TPU's dtype policy), for a division
+        # per row; the post-aggregate scope passes the accumulators'
+        # float64, where the operands are exact int64 sums and counts
+        # over [G] and float32 would round avg(BIGINT) to 24 bits
+        self.int_div_dtype = int_div_dtype
 
     # -- aux registration --------------------------------------------------
 
@@ -712,6 +719,8 @@ class ExprBuilder:
         }
         is_cmp = op in ("=", "!=", "<", "<=", ">", ">=")
         if op == "/":
+            int_to = self.int_div_dtype
+
             def run_div(rt: Runtime) -> DVal:
                 # exact decimals leave the int domain here: SQL decimal
                 # division result is DOUBLE in this engine (divergence
@@ -720,9 +729,9 @@ class ExprBuilder:
                 a, b = _dec_unscale(left(rt)), _dec_unscale(right(rt))
                 av, bv = a.value, b.value
                 if jnp.issubdtype(jnp.asarray(av).dtype, jnp.integer):
-                    av = av.astype(_float_dtype())
+                    av = av.astype(int_to or _float_dtype())
                 if jnp.issubdtype(jnp.asarray(bv).dtype, jnp.integer):
-                    bv = bv.astype(_float_dtype())
+                    bv = bv.astype(int_to or _float_dtype())
                 null = _or_null(a.null, b.null)
                 null = _or_null(null, b.value == 0)
                 safe = jnp.where(b.value == 0, 1, bv)

@@ -184,3 +184,75 @@ def test_q3_merge_probes_at_sf1_hold_no_loop_and_share_their_sorts(one_chip):
         assert op not in hlo, op
     assert hlo.count(" sort(") == 4
     assert comp.memory_analysis().temp_size_in_bytes <= 16 * 8388608
+
+
+def test_quickstart_main_at_100m_rows_is_two_scatters_that_fit(monkeypatch,
+                                                               one_chip):
+    """`select sym, avg(id) ... group by sym` at the quick-start cell's
+    shape (763 batches in the 768 bucket, 128 group slots), `main` phase
+    as the chip's branch builds it (the backend steered here, in the
+    test): the BIGINT sum is one scatter over a pair of uint32 halves
+    and the count one int32 scatter, both under `group_reduce`; plates
+    and temporaries stay inside a fifth of the chip.  A reduce that
+    takes the integer family off the scatter (ROADMAP S5) changes the
+    first assertion, and says so."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from snappydata_tpu import SnappySession, config
+    from snappydata_tpu.catalog import Catalog
+    from snappydata_tpu.engine.executor import CompiledPlan
+
+    seen = []
+    orig = CompiledPlan._noted_call
+
+    def spy(self, static, phase, fn, args):
+        seen.append((phase, fn, args))
+        return orig(self, static, phase, fn, args)
+
+    props = config.global_properties()
+    saved = props.decimal_as_float64
+    try:
+        props.decimal_as_float64 = False
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(CompiledPlan, "_noted_call", spy)
+        s = SnappySession(catalog=Catalog())
+        s.sql("CREATE TABLE testtable (id BIGINT NOT NULL, "
+              "sym VARCHAR(10) NOT NULL) USING column")
+        ids = np.arange(131072, dtype=np.int64)
+        names = np.array([f"sym{k}" for k in range(100)], dtype=object)
+        s.insert_arrays("testtable", [ids, names[ids % 100]])
+        rows = s.sql("select sym, avg(id) from testtable "
+                     "group by sym").rows()
+        s.stop()
+    finally:
+        monkeypatch.undo()
+        props.decimal_as_float64 = saved
+    assert len(rows) == 100
+    (fn, args), = [(f, a) for ph, f, a in seen if ph == "main"]
+    batches = 768
+
+    def sds(a, dims):
+        return jax.ShapeDtypeStruct(tuple(dims), a.dtype, sharding=one_chip)
+
+    def scalar(a):
+        a = jnp.asarray(a)
+        return sds(a, a.shape)
+
+    shapes = [jax.tree.map(lambda a: sds(a, (batches,) + a.shape[1:]),
+                           args[0]),
+              jax.tree.map(scalar, args[1]), jax.tree.map(scalar, args[2]),
+              jax.tree.map(lambda a: sds(
+                  a, (batches * a.shape[0],) + a.shape[1:] if a.ndim
+                  else ()), args[3])]
+    comp = fn.lower(*shapes).compile()
+    scatters = [ln for ln in comp.as_text().splitlines()
+                if " scatter(" in ln]
+    assert len(scatters) == 2
+    assert all("/group_reduce/scatter-add" in ln for ln in scatters)
+    # what each returns, left of the op: s32[128], and (u32[128], u32[128])
+    assert sorted(ln.split(" scatter(")[0].count("u32[128]")
+                  for ln in scatters) == [0, 2]
+    mem = comp.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9 / 5
