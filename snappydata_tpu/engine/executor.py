@@ -640,18 +640,19 @@ class CompiledPlan:
         """The main dispatch span says how its aggregate slots reduced,
         from the trace-time notes (so after the call that may trace): how
         many the dictionary-space lane took, how many of any family
-        were emitted as a `segment_*` scatter and how many of those
-        belong to the exact-integer family.  0 where none, and on a
-        plan that aggregates nothing.  `group_slots` is the static
-        number of group segments the reduce ran over and
+        were emitted as a `segment_*` scatter, how many of those belong
+        to the exact-integer family, and how many integer columns (int64
+        sums and count masks) the limb product took instead.  0 where
+        none, and on a plan that aggregates nothing.  `group_slots` is
+        the static number of group segments the reduce ran over and
         `reduce_padded_rows` the slots it walked (batch bucket x batch
         capacity, padding included).  `gidx_cache_hit` is 1 where the
         statement took its group index from the cache and ran the main
         phase alone."""
         note = self.agg_notes.get(static) if self.agg_notes else None
         for key in ("dict_space_slots", "scatter_slots",
-                    "isum_scatter_slots", "group_slots",
-                    "reduce_padded_rows"):
+                    "isum_scatter_slots", "limb_matmul_slots",
+                    "group_slots", "reduce_padded_rows"):
             sp.set(key, note[key] if note else 0)
         sp.set("gidx_cache_hit", int(gidx_cache_hit))
         # and what its joins were: how many lowered to the device, the
@@ -2870,6 +2871,8 @@ class Compiler:
             backend = jax.default_backend()
             req = _STRATEGY_NAMES[ctx.static[strategy_si]]
             fsum_strat = fsum_strategy_of(ctx, n, num_groups)
+            istrat = reduction.resolve_strategy(
+                req, backend, num_groups, n, "isum", jnp.int64)
             if pre is None and fsum_strat == "matmul":
                 onehot = reduction.make_onehot(gidx, num_groups,
                                                jnp.float64)
@@ -2879,7 +2882,8 @@ class Compiler:
             # trace is still mutating
             note = {"passes": 0, "strategies": set(), "lanes": set(),
                     "rle_fallbacks": 0, "dict_space_slots": 0,
-                    "scatter_slots": 0, "isum_scatter_slots": 0}
+                    "scatter_slots": 0, "isum_scatter_slots": 0,
+                    "limb_matmul_slots": 0}
             tok = ctx.static[code_agg_si]
             # dictionary-space SUM counts by a one-hot product shaped
             # for the MXU: auto engages it on the accelerator only (the
@@ -3062,8 +3066,18 @@ class Compiler:
                                     jnp.iinfo(jnp.int64).min)))
                             guards.append({"absmax": tag,
                                            "cnt": count_col(w)})
-                        isum_cols.append(
-                            (i, jnp.where(w, acc, jnp.int64(0))))
+                        if istrat == "matmul" and w is valid:
+                            # the limb product drops a row on the dump
+                            # segment by its all-zero one-hot row, and an
+                            # integer has no NaN to leak through it: no
+                            # select pass, and no widened copy either
+                            # (the product widens a chunk at a time)
+                            isum_cols.append(
+                                (i, v if jnp.issubdtype(
+                                    v.dtype, jnp.signedinteger) else acc))
+                        else:
+                            isum_cols.append(
+                                (i, jnp.where(w, acc, jnp.int64(0))))
                     elif fsum_strat == "matmul" and w is valid \
                             and raw_col:
                         # bare non-null column: an invalid row's one-hot
@@ -3110,28 +3124,37 @@ class Compiler:
                 if join_counts:
                     count_res = jnp.round(
                         res[:, len(fsum_cols):]).astype(jnp.int64)
-            if count_ws and count_res is None:
+            # counts follow the float family's strategy (matmul was
+            # handled by joining above): on the unroll path that keeps
+            # the old fast int32 masked sums.  Where that would be a
+            # scatter they resolve as the exact-integer family does, and
+            # under its limb product the masks ride the int64 pack's one
+            # product as 0/1 columns (one read of gidx, one one-hot).
+            limb_counts = (bool(count_ws) and count_res is None
+                           and fsum_strat == "scatter"
+                           and istrat == "matmul")
+            if count_ws and count_res is None and not limb_counts:
                 cdt = reduction.count_pack_dtype(n)
-                # counts follow the float family's strategy (matmul was
-                # handled by joining above): on the unroll path that
-                # keeps the old fast int32 masked sums, on scatter one
-                # int pass — both exact under the bound-checked dtype
                 count_res = reduction.packed_sum(
                     [w.astype(cdt) for w in count_ws], gidx, num_groups,
                     fsum_strat).astype(jnp.int64)
                 family_pass(fsum_strat, len(count_users))
-            for i, c in count_users:
-                slot_arrays[i] = count_res[:, c]
-            if isum_cols:
-                istrat = reduction.resolve_strategy(
-                    req, backend, num_groups, n, "isum", jnp.int64)
-                ires = reduction.packed_sum(
-                    [c for _, c in isum_cols], gidx, num_groups, istrat)
+            if isum_cols or limb_counts:
+                icols = [c for _, c in isum_cols] \
+                    + (count_ws if limb_counts else [])
+                ires = reduction.packed_sum(icols, gidx, num_groups,
+                                            istrat)
                 family_pass(istrat, len(isum_cols))
                 if istrat == "scatter":
                     note["isum_scatter_slots"] += len(isum_cols)
+                elif istrat == "matmul":
+                    note["limb_matmul_slots"] += len(icols)
                 for pos, (i, _) in enumerate(isum_cols):
                     slot_arrays[i] = ires[:, pos]
+                if limb_counts:
+                    count_res = ires[:, len(isum_cols):]
+            for i, c in count_users:
+                slot_arrays[i] = count_res[:, c]
             guard_res: Dict[tuple, object] = {}
             for (mkind, _dtname), entries in minmax.items():
                 mcols = [c for _, c in entries]
@@ -3231,6 +3254,7 @@ class Compiler:
                 "dict_space_slots": note["dict_space_slots"],
                 "scatter_slots": note["scatter_slots"],
                 "isum_scatter_slots": note["isum_scatter_slots"],
+                "limb_matmul_slots": note["limb_matmul_slots"],
                 "group_slots": num_groups,
                 "reduce_padded_rows": n,
                 "table": base_table_ref}

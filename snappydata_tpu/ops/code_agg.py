@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from snappydata_tpu.observability import tracing
+from snappydata_tpu.ops.reduction import divisor_step
 
 # The one-hot product's work grows with groups × dictionary width (the
 # scatter it replaced did not), so that product is the engagement bound:
@@ -72,14 +73,9 @@ def dict_space_engages(nseg: int, codes_shape, dicts_shape) -> bool:
 
 def _chunk_batches(b: int, cap: int, ngroups: int, dp: int) -> int:
     """Batches per step of the count: as many as DICT_SPACE_CHUNK_BYTES
-    of bfloat16 one-hots allow, preferring a divisor of `b` within a
-    factor of two of that so no odd-sized last step is compiled."""
+    of bfloat16 one-hots allow (reduction.divisor_step)."""
     per_batch = cap * (ngroups + dp) * 2
-    most = max(1, min(b, DICT_SPACE_CHUNK_BYTES // per_batch))
-    for c in range(most, (most + 1) // 2 - 1, -1):
-        if b % c == 0:
-            return c
-    return most
+    return divisor_step(b, max(1, min(b, DICT_SPACE_CHUNK_BYTES // per_batch)))
 
 
 def _counts_of(codes, gidx, ngroups: int, dp: int):

@@ -14,12 +14,18 @@ strategy table and the fused kernels:
            sums; r01: Q1 at 827M rows/s on one v5e)
   scatter  jax.ops.segment_{sum,min,max} along axis 0 — one pass, the
            safe default for large G on any backend
-  matmul   one-hot [S,N]@[N,G] in the accumulator dtype — on CPU the
-           one-hot feeds a multithreaded BLAS gemm (measured on the dev
-           container, 24M rows, G=9: gemm with a prebuilt one-hot 0.7s
-           vs 3.0s scatter vs 4.2s packed unroll), and the one-hot is
-           exactly what the executor's group-index cache can reuse
-           across repeated dashboard queries
+  matmul   float sums: one-hot [S,N]@[N,G] in the accumulator dtype — on
+           CPU the one-hot feeds a multithreaded BLAS gemm (measured on
+           the dev container, 24M rows, G=9: gemm with a prebuilt
+           one-hot 0.7s vs 3.0s scatter vs 4.2s packed unroll), and the
+           one-hot is exactly what the executor's group-index cache can
+           reuse across repeated dashboard queries.  Exact integers
+           (int64 sums, count masks): a chunked one-hot product over
+           bfloat16 limbs on the MXU (limb_matmul_sum), what `auto`
+           picks on the TPU past 64 groups, where a scatter runs
+           serially (int64 sum + count over 100.66 M rows into 128
+           slots: 9.06 s by scatter, 21 ms by the product; PERF.md
+           section 6, PR 33)
 
 `agg_reduce_strategy` (config.py) picks one explicitly; `auto` keys on
 backend + G + S + N (see `resolve_strategy`).  Counts ride the float
@@ -31,10 +37,13 @@ an explicit row-count bound instead (`count_pack_dtype`).
 Exactness contract per family:
   float sums  f64 accumulation everywhere (reordered summation only —
               measured max rel err vs math.fsum at Q1 scale: ~8e-14)
-  int sums    int64 scatter/unroll only, NEVER matmul (f64 dot loses
-              bits above 2**53)
-  counts      exact on every strategy (f64 0/1 columns < 2**53, or
-              bound-checked int accumulators)
+  int sums    int64 by scatter, unroll or the limb product (whose
+              float32 partials stay under 2**24 and recombine modulo
+              2**64: the scatter's bits, wrap-around included), NEVER
+              an f64 dot (it loses bits above 2**53)
+  counts      exact on every strategy (f64 0/1 columns < 2**53,
+              bound-checked int accumulators, or a 0/1 limb of the
+              limb product)
   min/max     order-independent; empty groups keep the same +/-inf and
               integer-extreme fillers the unrolled path produced
 """
@@ -81,6 +90,35 @@ MATMUL_ONEHOT_MAX_BYTES = 4 << 30
 # than 2**31 rows; above that the packed count dtype widens to int64
 COUNT_I32_MAX_ROWS = (1 << 31) - 1
 
+# The integer form of `matmul` (limb_matmul_sum): an int64 is LIMB_COUNT
+# unsigned limbs of LIMB_BITS bits, each exact in bfloat16 (every integer
+# up to 256 is), and one contraction sums a limb over LIMB_CHUNK_ROWS
+# rows into a float32, which holds every integer below 2**24.  The
+# invariant is largest limb x rows a contraction < 2**24:
+# 255 x 65,536 = 16,711,680 < 16,777,216.
+LIMB_BITS = 8
+LIMB_COUNT = 64 // LIMB_BITS
+LIMB_CHUNK_ROWS = 1 << 16
+LIMB_ACC_EXACT = 1 << 24
+assert ((1 << LIMB_BITS) - 1) * LIMB_CHUNK_ROWS < LIMB_ACC_EXACT
+
+# Past this many group slots `auto` leaves the exact-integer families on
+# the scatter: the one-hot's work grows with G and the scatter's does
+# not.  Set from a sweep on the v5e over 100,663,296 rows (PERF.md
+# section 6, PR 33; seconds, product against scatter): an int64 sum and
+# a count mask 0.021 / 9.06 at 128 slots, 0.088 / 9.36 at 1,024,
+# 0.361 / 10.5 at 4,096, 0.756 / 11.5 at 8,192, 5.43 / 13.6 at 65,536;
+# a count mask alone 0.012 / 0.88, 0.068 / 0.88, 0.266 / 0.67-0.88,
+# then 1.58 / 0.67-0.88 at 8,192 (its one-limb product lowers worse):
+# the largest power of two at which both packs win.
+LIMB_MATMUL_MAX_SEGMENTS = 4096
+
+# bytes of bfloat16 operands (one-hot and limbs) one step of the product
+# may hold if XLA materialises them (the CPU backend does; the v5e
+# compiler fuses them into the product and holds none): the rows are
+# walked in steps of this size, so the transient does not grow with N
+LIMB_STEP_BYTES = 256 << 20
+
 
 def count_pack_dtype(n_rows: int):
     """Accumulator dtype for packed int counts: int32 while no group can
@@ -99,17 +137,20 @@ def resolve_strategy(requested: str, backend: str, num_segments: int,
     """Pick the fused strategy for one accumulator family.
 
     family: "fsum" (float sums + counts-as-f64), "isum" (exact int64
-    sums), "minmax".  Invalid requests degrade rather than fail:
-    matmul is refused for int sums (inexact) and min/max (not a dot),
-    and for one-hots past MATMUL_ONEHOT_MAX_BYTES; unroll degrades to
-    scatter past UNROLL_MAX_SEGMENTS.
+    sums, and the count masks where the float family would scatter),
+    "minmax".  Invalid requests degrade rather than fail: matmul is
+    refused for min/max (not a dot) and for float one-hots past
+    MATMUL_ONEHOT_MAX_BYTES (the integer form walks its rows in bounded
+    steps and is never refused); unroll degrades to scatter past
+    UNROLL_MAX_SEGMENTS.
     """
     if requested not in STRATEGIES:
         requested = "auto"
     if requested == "matmul" and (
-            family != "fsum"
-            or onehot_bytes(n_rows, num_segments, acc_dtype)
-            > MATMUL_ONEHOT_MAX_BYTES):
+            family == "minmax"
+            or (family == "fsum"
+                and onehot_bytes(n_rows, num_segments, acc_dtype)
+                > MATMUL_ONEHOT_MAX_BYTES)):
         requested = "auto"
     if requested == "unroll" and num_segments > UNROLL_MAX_SEGMENTS:
         requested = "scatter"
@@ -129,6 +170,12 @@ def resolve_strategy(requested: str, backend: str, num_segments: int,
         # (24M rows, G=9: gemm with a prebuilt one-hot 0.7s vs 3.0s
         # scatter vs 4.2s packed unroll), and the one-hot is exactly
         # what the group-index cache amortizes across repeated queries
+        return "matmul"
+    if family == "isum" and backend == "tpu" \
+            and num_segments <= LIMB_MATMUL_MAX_SEGMENTS:
+        # the TPU runs a scatter serially (81-128 ns a row for an
+        # int64, 6.7-8.8 for an int32); the limb product's work grows
+        # with G (0.083 ns a row and group)
         return "matmul"
     return "scatter"
 
@@ -155,6 +202,119 @@ def _pack(cols):
     return jnp.stack(cols, axis=1)
 
 
+def divisor_step(total: int, most: int) -> int:
+    """Units a step of a walk over `total` units bounded by `most` a
+    step: a divisor of `total` within a factor of two of `most` if there
+    is one, so no odd-sized last step is compiled; else `most`."""
+    return next((c for c in range(most, (most + 1) // 2 - 1, -1)
+                 if total % c == 0), most)
+
+
+def _limb_steps(n: int, num_segments: int, width: int):
+    """(rows a chunk, chunks a step, steps, chunks of the remainder step,
+    rows of the tail chunk) for limb_matmul_sum over `n` rows: a chunk is
+    one contraction (at most LIMB_CHUNK_ROWS rows), a step a batch of
+    chunks whose operands fit LIMB_STEP_BYTES (divisor_step)."""
+    row_bytes = (num_segments + width) * 2
+    rows = max(1, min(n, LIMB_CHUNK_ROWS,
+                      max(128, LIMB_STEP_BYTES // row_bytes // 128 * 128)))
+    chunks, tail = divmod(n, rows)
+    step = divisor_step(chunks, max(1, min(
+        chunks, LIMB_STEP_BYTES // (rows * row_bytes))))
+    return (rows, step) + divmod(chunks, step) + (tail,)
+
+
+@tracing.op_scope("group_reduce")
+def limb_matmul_sum(cols, gidx, num_segments: int):
+    """Exact segmented SUM of integer columns (list of [N] arrays: any
+    signed integer dtype, or bool for a count mask) -> [num_segments, S]
+    int64, bit for bit the int64 `segment_sum` (wrap-around included),
+    by a one-hot product on the MXU instead of a scatter, which the TPU
+    runs serially (81 ns a row for an int64; PERF.md section 6, PR 33).
+
+    Each column is its two's-complement bits cut into LIMB_COUNT
+    unsigned limbs held as bfloat16 (a bool mask is one 0/1 limb); the
+    limbs of a chunk of rows are contracted with onehot(gidx) over the
+    REAL groups (a row on the dump segment is an all-zero one-hot row
+    and sums nowhere, so such rows need no masking) in one batched
+    dot_general with a float32 accumulator: exact, because limb x rows
+    stays under 2**24 (LIMB_CHUNK_ROWS).  The chunks' partials are
+    added in uint64 and recombined as sum_k partial_k << (LIMB_BITS*k)
+    modulo 2**64.  Rows are walked in steps bounded by LIMB_STEP_BYTES;
+    each step slices its own rows out of the flat inputs, so what a
+    step materialises does not grow with N."""
+    n = gidx.shape[0]
+    # limb row l of the operand is (source >> shifts[l]) & mask: a source
+    # is a uint32 half of an int64 column (its limbs low to high) or a
+    # count mask (one row); ends[k] is one past source k's last row
+    half = list(range(0, 32, LIMB_BITS))
+    shifts, ends = [], []
+    for c in cols:
+        for rows_of_source in ([[0]] if c.dtype == jnp.bool_
+                               else [half, half]):
+            shifts += rows_of_source
+            ends.append(len(shifts))
+    rows, step, steps, rest, tail = _limb_steps(n, num_segments,
+                                                len(shifts))
+    groups = jnp.arange(num_segments, dtype=jnp.int32)[None, :, None]
+    limb = jnp.arange(len(shifts), dtype=jnp.int32)[None, :, None]
+    shift = jnp.asarray(shifts, jnp.uint32)[None, :, None]
+
+    def sums_at(lo, c: int, r: int):
+        """[G, limbs] uint64 sums over the `c` chunks of `r` rows that
+        start at row `lo`."""
+        def chunks_of(x):
+            return jax.lax.dynamic_slice_in_dim(x, lo, c * r).reshape(c, r)
+
+        oh = (chunks_of(gidx).astype(jnp.int32)[:, None, :]
+              == groups).astype(jnp.bfloat16)
+        sources = []
+        for col in cols:
+            x = chunks_of(col)
+            if x.dtype == jnp.bool_:
+                sources.append(x.astype(jnp.uint32))
+            else:
+                x = x.astype(jnp.int64)
+                sources += [x.astype(jnp.uint32),
+                            (x >> 32).astype(jnp.uint32)]
+        # the operand is built whole, every limb row a select of its
+        # source and one shift, not stacked from rows: a stack is a
+        # sublane shuffle that cost more than the product itself on the
+        # v5e (10 of 27 ms a statement; PERF.md section 6, PR 33)
+        src = sources[-1][:, None, :]
+        for s_, end in zip(sources[-2::-1], ends[-2::-1]):
+            src = jnp.where(limb < end, s_[:, None, :], src)
+        operand = ((src >> shift) & jnp.uint32((1 << LIMB_BITS) - 1)) \
+            .astype(jnp.bfloat16)
+        part = jax.lax.dot_general(
+            oh, operand, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
+        return jnp.sum(part.astype(jnp.uint32).astype(jnp.uint64), axis=0)
+
+    # the loop's carry starts from the first step, not from zeros: under
+    # shard_map a constant carry is unvarying over the mesh axis and the
+    # step's sum is not, which the scan refuses
+    parts = []
+    if steps:
+        first = sums_at(0, step, rows)
+        parts.append(first if steps == 1 else jax.lax.fori_loop(
+            1, steps,
+            lambda i, a: a + sums_at(i * (step * rows), step, rows), first))
+    if rest:
+        parts.append(sums_at(steps * step * rows, rest, rows))
+    if tail:
+        parts.append(sums_at(n - tail, 1, tail))
+    acc = sum(parts) if parts else jnp.zeros(      # no rows at all
+        (num_segments, len(shifts)), jnp.uint64)
+    out, at = [], 0
+    for c in cols:
+        width = 1 if c.dtype == jnp.bool_ else LIMB_COUNT
+        out.append(sum(acc[:, at + k] << jnp.uint64(LIMB_BITS * k)
+                       for k in range(width)))
+        at += width
+    return jax.lax.bitcast_convert_type(jnp.stack(out, axis=1), jnp.int64)
+
+
 @tracing.op_scope("group_reduce")
 def packed_sum(cols, gidx, num_segments: int, strategy: str,
                onehot=None):
@@ -174,18 +334,19 @@ def packed_sum(cols, gidx, num_segments: int, strategy: str,
                 jnp.sum(jnp.where(m, c, jnp.zeros((), c.dtype)))
                 for c in cols]))
         return jnp.stack(outs)
+    if strategy == "matmul" and not jnp.issubdtype(cols[0].dtype,
+                                                   jnp.floating):
+        return limb_matmul_sum(cols, gidx, num_segments)
     packed = _pack(cols)
     if strategy == "matmul":
         oh = make_onehot(gidx, num_segments, packed.dtype) \
             if onehot is None else onehot
-        if jnp.issubdtype(packed.dtype, jnp.floating):
-            return jax.lax.cond(
-                jnp.all(jnp.isfinite(packed)),
-                lambda p, o: (p.T @ o).T,
-                lambda p, _o: jax.ops.segment_sum(
-                    p, gidx, num_segments=num_segments),
-                packed, oh)
-        return (packed.T @ oh).T
+        return jax.lax.cond(
+            jnp.all(jnp.isfinite(packed)),
+            lambda p, o: (p.T @ o).T,
+            lambda p, _o: jax.ops.segment_sum(
+                p, gidx, num_segments=num_segments),
+            packed, oh)
     return jax.ops.segment_sum(packed, gidx, num_segments=num_segments)
 
 

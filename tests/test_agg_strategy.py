@@ -47,12 +47,17 @@ def _counter(name: str) -> int:
 # ---------------------------------------------------------------------
 
 def test_resolve_strategy_degrades_invalid_requests():
-    # matmul refused for exact int sums and min/max, and past the
-    # one-hot byte budget; unroll degrades to scatter past the boundary
+    # matmul refused for min/max and past the one-hot byte budget; kept
+    # for exact int sums (the limb product, whose walker bounds its own
+    # transient); unroll degrades to scatter past the boundary
     assert reduction.resolve_strategy(
-        "matmul", "cpu", 8, 1000, "isum", jnp.int64) != "matmul"
+        "matmul", "cpu", 8, 1000, "isum", jnp.int64) == "matmul"
+    assert reduction.resolve_strategy(
+        "matmul", "tpu", 100_000, 1 << 40, "isum", jnp.int64) == "matmul"
     assert reduction.resolve_strategy(
         "matmul", "cpu", 8, 1000, "minmax", jnp.float64) != "matmul"
+    assert reduction.resolve_strategy(
+        "matmul", "tpu", 128, 1000, "minmax", jnp.int64) != "matmul"
     huge_n = reduction.MATMUL_ONEHOT_MAX_BYTES  # n*G*8 >> budget
     assert reduction.resolve_strategy(
         "matmul", "cpu", 8, huge_n, "fsum", jnp.float64) == "scatter"
@@ -67,6 +72,99 @@ def test_resolve_strategy_degrades_invalid_requests():
         "auto", "tpu", 9, 100_000, "fsum", jnp.float64) == "unroll"
     assert reduction.resolve_strategy(
         "auto", "tpu", 1000, 100_000, "fsum", jnp.float64) == "scatter"
+    # auto: the tpu's exact-integer family takes the limb product from
+    # one past the unroll to the swept bound, the scatter past it; the
+    # cpu (which materialises the one-hots) keeps what it had
+    top = reduction.LIMB_MATMUL_MAX_SEGMENTS
+    for nseg, want in ((reduction.UNROLL_MAX_SEGMENTS, "unroll"),
+                       (reduction.UNROLL_MAX_SEGMENTS + 1, "matmul"),
+                       (top, "matmul"), (top + 1, "scatter")):
+        assert reduction.resolve_strategy(
+            "auto", "tpu", nseg, 100_000_000, "isum", jnp.int64) == want
+    for nseg, want in ((4, "unroll"), (5, "scatter"), (65, "scatter"),
+                       (top, "scatter")):
+        assert reduction.resolve_strategy(
+            "auto", "cpu", nseg, 100_000, "isum", jnp.int64) == want
+    assert reduction.resolve_strategy(
+        "auto", "tpu", 128, 100_000, "minmax", jnp.int64) == "scatter"
+
+
+_I64 = np.iinfo(np.int64)
+
+
+def _limb_case(name: str):
+    """(cols, gidx, G) of one exactness case of the limb product; every
+    case holds rows on the dump segment (gidx == G)."""
+    rng = np.random.default_rng(len(name))
+    chunk = reduction.LIMB_CHUNK_ROWS
+    groups = {"g65": 65, "g1000": 1000}.get(name, 128)
+    n = {"no_rows": 0, "one_row": 1, "one_chunk": chunk,
+         "masked_chunk": 2 * chunk + 17}.get(name, chunk + 4097)
+    gidx = rng.integers(0, groups + 1, n).astype(np.int32)
+    vals = rng.integers(-2**62, 2**62, n, dtype=np.int64)
+    cols = [vals]
+    if name == "extremes":
+        # +-2**62 and the two ends of int64 pile into group 3 and wrap
+        ext = np.array([-1, 0, 2**62, -2**62, _I64.max, _I64.min,
+                        _I64.max, _I64.max, _I64.min], dtype=np.int64)
+        vals[:ext.size] = ext
+        gidx[:ext.size] = 3
+        vals[ext.size:2 * ext.size] = ext
+    elif name == "masked_chunk":
+        gidx[chunk:2 * chunk] = groups     # a whole chunk on the dump
+    elif name == "two_sums_two_masks":
+        cols = [vals, rng.integers(_I64.min, _I64.max, n, dtype=np.int64),
+                rng.random(n) < 0.5, np.ones(n, bool)]
+    elif name == "null_masked":
+        w = rng.random(n) < 0.6
+        cols = [np.where(w, vals, 0), w]
+    elif name == "int32_column":
+        cols = [rng.integers(-2**31, 2**31 - 1, n).astype(np.int32)]
+    return cols, gidx, groups
+
+
+@pytest.mark.parametrize("name", [
+    "g65", "g128", "g1000", "no_rows", "one_row", "one_chunk",
+    "masked_chunk",
+    "extremes", "two_sums_two_masks", "null_masked", "int32_column"])
+def test_limb_product_matches_scatter_bit_for_bit(name):
+    """The integer form of `matmul` against the int64 `segment_sum` and
+    NumPy's `add.at`: every bit, wrap-around included."""
+    import jax
+
+    cols, gidx, groups = _limb_case(name)
+    got = {strat: np.asarray(jax.jit(
+        lambda *c, strat=strat: reduction.packed_sum(
+            [x if strat == "matmul" else x.astype(jnp.int64)
+             for x in c[1:]], c[0], groups, strat).astype(jnp.int64)
+    )(gidx, *cols)) for strat in ("matmul", "scatter")}
+    want = np.zeros((groups + 1, len(cols)), np.int64)
+    with np.errstate(over="ignore"):
+        for j, c in enumerate(cols):
+            np.add.at(want[:, j], gidx, c.astype(np.int64))
+    assert got["matmul"].dtype == np.int64
+    np.testing.assert_array_equal(got["matmul"], got["scatter"])
+    np.testing.assert_array_equal(got["matmul"], want[:groups])
+
+
+def test_limb_steps_cover_every_row_within_the_budget():
+    """The walker's plan: chunks x rows + tail is N, a chunk never
+    passes the exact range of the float32 accumulator, a step's
+    would-be operands stay inside LIMB_STEP_BYTES."""
+    for n, groups, width in ((100_663_296, 128, 9), (100_663_296, 65536, 9),
+                             (6_291_456, 8192, 17), (131_072 * 763, 128, 9),
+                             (1, 65, 1), (65_537, 1000, 8)):
+        rows, step, steps, rest, tail = reduction._limb_steps(
+            n, groups, width)
+        assert (steps * step + rest) * rows + tail == n
+        assert tail < rows <= reduction.LIMB_CHUNK_ROWS
+        assert ((1 << reduction.LIMB_BITS) - 1) * rows \
+            < reduction.LIMB_ACC_EXACT
+        assert step * rows * (groups + width) * 2 \
+            <= max(reduction.LIMB_STEP_BYTES, 128 * (groups + width) * 2)
+    # the quick-start cell's shape: 128 steps of 12 chunks, nothing odd
+    assert reduction._limb_steps(100_663_296, 128, 9) \
+        == (65536, 12, 128, 0, 0)
 
 
 @pytest.mark.parametrize("nseg", [1, 2, 63, 64, 65, 200])
@@ -173,6 +271,149 @@ def test_engine_strategies_identical_and_respecialize(props):
         assert _counter(f"agg_strategy_{strat}") > before, \
             f"{strat} was not picked despite the knob"
     s.stop()
+
+
+def _main_dispatch_attrs():
+    """Attrs of the last traced statement's main dispatch span."""
+    from snappydata_tpu.observability import tracing
+
+    def spans(sp):
+        yield sp
+        for c in sp.get("children", ()):
+            yield from spans(c)
+
+    (sp,) = [sp for sp in spans(tracing.ring().last().to_dict()["root"])
+             if sp["name"] in ("jit_compile", "device_execute")
+             and sp["attrs"].get("phase", "main") == "main"]
+    return sp["attrs"]
+
+
+def _steer(monkeypatch, props, plan: str) -> None:
+    """`knob`: the explicit request, which is how the CPU backend
+    reaches the limb product.  `chip`: the plan `auto` builds on the
+    TPU (float families past 64 groups scatter, the integer ones and
+    the counts take the product), with the backend steered here, in the
+    test, as tests/test_tpu_compile.py does."""
+    if plan == "knob":
+        props.agg_reduce_strategy = "matmul"
+    else:
+        import jax
+
+        props.agg_reduce_strategy = "auto"
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+@pytest.mark.parametrize("plan", ["knob", "chip"])
+def test_engine_limb_product_is_exact_and_says_so(props, monkeypatch, plan):
+    """The quick-start statement's family over ~300 K rows and 100 syms,
+    ids near 2**40 (a float64 dot would round the sums): NumPy's exact
+    answer, and the span says which integer columns the product took.
+    Under the knob the counts stay in the float64 one-hot pack, as
+    before; on the chip's plan the count mask rides the int64 pack's
+    product."""
+    _steer(monkeypatch, props, plan)
+    n = 300_007
+    ids = np.arange(n, dtype=np.int64) * ((1 << 40) // n) - (1 << 39)
+    syms = np.array([f"sym{k}" for k in range(100)], dtype=object)
+    s = SnappySession(catalog=Catalog())
+    try:
+        s.sql("CREATE TABLE t (id BIGINT NOT NULL, sym VARCHAR(10) "
+              "NOT NULL) USING column")
+        s.insert_arrays("t", [ids, syms[np.arange(n) % 100]])
+        before = _counter("agg_strategy_matmul")
+        for _ in range(2):      # the second from the group-index cache
+            rows = s.sql("select sym, sum(id), avg(id), count(*) from t "
+                         "group by sym").rows()
+            attrs = _main_dispatch_attrs()
+            assert attrs["limb_matmul_slots"] == (1 if plan == "knob" else 2)
+            assert attrs["isum_scatter_slots"] == 0
+            assert attrs["scatter_slots"] == 0
+            assert attrs["group_slots"] == 128
+        assert attrs["gidx_cache_hit"] == 1
+        assert _counter("agg_strategy_matmul") >= before + 2
+    finally:
+        s.stop()
+    want = {}
+    for k in range(100):
+        mine = ids[np.arange(n) % 100 == k]
+        total = int(mine.sum())
+        want[f"sym{k}"] = (total, total / len(mine), len(mine))
+    assert {r[0]: tuple(r[1:]) for r in rows} == want
+
+
+@pytest.mark.parametrize("plan", ["knob", "chip"])
+def test_decimal_sum_past_64_groups_keeps_its_overflow_guard(
+        props, monkeypatch, plan):
+    """A DECIMAL(15,2) sum over 100 groups by the limb product is the
+    Decimal oracle's, and a group whose absmax x count passes 2**62
+    still reroutes the statement to the host (the guard is upstream of
+    the product)."""
+    from decimal import Decimal
+
+    _steer(monkeypatch, props, plan)
+    rng = np.random.default_rng(33)
+    n = 40_000
+    keys = (np.arange(n) % 100).astype(np.int32)
+    cents = rng.integers(-10**14, 10**14, n)
+    s = SnappySession(catalog=Catalog())
+    try:
+        s.sql("CREATE TABLE d (k INT NOT NULL, v DECIMAL(15,2)) "
+              "USING column")
+        nulls = rng.random(n) < 0.1
+        s.catalog.describe("d").data.insert_arrays(
+            [keys, cents.astype(np.float64) / 100.0], nulls=[None, nulls])
+        rows = s.sql("SELECT k, sum(v), count(v) FROM d GROUP BY k").rows()
+        attrs = _main_dispatch_attrs()
+        assert attrs["limb_matmul_slots"] == (1 if plan == "knob" else 3)
+        assert attrs["isum_scatter_slots"] == 0
+        got = {r[0]: (r[1], r[2]) for r in rows}
+        for k in range(100):
+            sel = (keys == k) & ~nulls
+            stored = np.round(cents[sel].astype(np.float64) / 100.0 * 100)
+            assert got[k] == (Decimal(int(stored.sum())) / 100, sel.sum())
+        # 6,000 rows of 9,999,999,999,999.99 in one group: 6e18 > 2**62
+        reg = global_registry()
+        fallbacks = reg.counter("host_fallbacks")
+        s.catalog.describe("d").data.insert_arrays(
+            [np.full(6000, 7, np.int32), np.full(6000, 9999999999999.99)])
+        rows = s.sql("SELECT k, sum(v) FROM d GROUP BY k").rows()
+        assert reg.counter("host_fallbacks") == fallbacks + 1
+        big = {r[0]: float(r[1]) for r in rows}[7]
+        assert big == pytest.approx(
+            6000 * 9999999999999.99 + float(got[7][0]), rel=1e-9)
+        assert len(rows) == 100
+    finally:
+        s.stop()
+
+
+def test_limb_product_under_the_mesh(props, monkeypatch):
+    """The chip's plan through `mesh_exec`'s shard_map: each shard
+    reduces its rows by the limb product (its loop's carry has to vary
+    over the mesh axis as its body's sum does), the partials merge by
+    addition; sums that wrap int64 come back as NumPy's."""
+    from snappydata_tpu.parallel import MeshContext, data_mesh
+
+    _steer(monkeypatch, props, "chip")
+    props.column_batch_rows = 4096
+    n = 100_000
+    ids = np.arange(n, dtype=np.int64) * 9_000_000_000_000 - 2**62
+    keys = (np.arange(n) % 100).astype(np.int32)
+    s = SnappySession(catalog=Catalog())
+    try:
+        s.sql("CREATE TABLE t (id BIGINT NOT NULL, k INT NOT NULL) "
+              "USING column")
+        s.insert_arrays("t", [ids, keys])
+        execs = _counter("mesh_shard_execs")
+        with MeshContext(data_mesh(8)):
+            rows = s.sql("SELECT k, sum(id), count(*) FROM t GROUP BY k "
+                         "ORDER BY k").rows()
+        assert _counter("mesh_shard_execs") == execs + 1
+    finally:
+        s.stop()
+    with np.errstate(over="ignore"):
+        want = [(k, int(ids[keys == k].sum()), n // 100)
+                for k in range(100)]
+    assert [tuple(r) for r in rows] == want
 
 
 # Query shapes under the chip's dtype policy (float32 plates, float64

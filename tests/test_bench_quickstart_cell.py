@@ -40,10 +40,12 @@ METRICS = ["groupby_stmt_ms", "plan_ms.quickstart", "bind_ms.quickstart",
            "host_fallbacks.quickstart", "quickstart_roofline",
            "scatter_slots.quickstart", "dict_space_slots.quickstart",
            "group_slots.quickstart", "gidx_cache_hits.quickstart",
-           "isum_scatter_slots.quickstart", "reduce_padded_rows.quickstart"]
+           "isum_scatter_slots.quickstart", "reduce_padded_rows.quickstart",
+           "limb_matmul_slots.quickstart"]
 NEW_ATTRS = {"gidx_cache_hits.quickstart": "gidx_cache_hit",
              "isum_scatter_slots.quickstart": "isum_scatter_slots",
-             "reduce_padded_rows.quickstart": "reduce_padded_rows"}
+             "reduce_padded_rows.quickstart": "reduce_padded_rows",
+             "limb_matmul_slots.quickstart": "limb_matmul_slots"}
 BATCH_ROWS = 1 << 17
 
 
@@ -387,6 +389,9 @@ def test_rehearsal_of_the_cell_is_correct(capsys, monkeypatch, man, trace):
     # every statement of the window found the warm-up's group index
     assert value["gidx_cache_hits.quickstart"] == 1
     assert value["isum_scatter_slots.quickstart"] == 1
+    # the CPU backend's `auto` keeps the scatter: the limb product is
+    # the chip's (tests/test_agg_strategy.py walks it here)
+    assert value["limb_matmul_slots.quickstart"] == 0
     assert value["dict_space_slots.quickstart"] == 0
     assert value["group_slots.quickstart"] == 128
     assert value["reduce_padded_rows.quickstart"] == 12 * BATCH_ROWS
@@ -525,6 +530,7 @@ def test_every_new_metric_reads_a_number_from_the_trace(man, traced, name):
         "gidx_cache_hits.quickstart": 1,
         "isum_scatter_slots.quickstart": 1,
         "reduce_padded_rows.quickstart": 3 * BATCH_ROWS,
+        "limb_matmul_slots.quickstart": 0,
         "device_idle_pct.quickstart": 20.0,
         "quickstart_roofline": 100.0 * (2 * ROWS * 9 / 819e9) / 2.0}
     if name in expected:

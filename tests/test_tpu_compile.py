@@ -186,16 +186,12 @@ def test_q3_merge_probes_at_sf1_hold_no_loop_and_share_their_sorts(one_chip):
     assert comp.memory_analysis().temp_size_in_bytes <= 16 * 8388608
 
 
-def test_quickstart_main_at_100m_rows_is_two_scatters_that_fit(monkeypatch,
-                                                               one_chip):
-    """`select sym, avg(id) ... group by sym` at the quick-start cell's
-    shape (763 batches in the 768 bucket, 128 group slots), `main` phase
-    as the chip's branch builds it (the backend steered here, in the
-    test): the BIGINT sum is one scatter over a pair of uint32 halves
-    and the count one int32 scatter, both under `group_reduce`; plates
-    and temporaries stay inside a fifth of the chip.  A reduce that
-    takes the integer family off the scatter (ROADMAP S5) changes the
-    first assertion, and says so."""
+def _quickstart_main_compiled(monkeypatch, one_chip, strategy, buckets):
+    """`select sym, avg(id) ... group by sym`, `main` phase as the chip's
+    branch builds it (the backend steered here, in the test, through the
+    lowering too: it re-traces), compiled for the described chip at each
+    batch bucket of `buckets` (the quick-start cell's is 768: 763
+    batches, 128 group slots)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -211,10 +207,18 @@ def test_quickstart_main_at_100m_rows_is_two_scatters_that_fit(monkeypatch,
         seen.append((phase, fn, args))
         return orig(self, static, phase, fn, args)
 
+    def sds(a, dims):
+        return jax.ShapeDtypeStruct(tuple(dims), a.dtype, sharding=one_chip)
+
+    def scalar(a):
+        a = jnp.asarray(a)
+        return sds(a, a.shape)
+
     props = config.global_properties()
-    saved = props.decimal_as_float64
+    saved = (props.decimal_as_float64, props.agg_reduce_strategy)
     try:
         props.decimal_as_float64 = False
+        props.agg_reduce_strategy = strategy
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         monkeypatch.setattr(CompiledPlan, "_noted_call", spy)
         s = SnappySession(catalog=Catalog())
@@ -226,27 +230,55 @@ def test_quickstart_main_at_100m_rows_is_two_scatters_that_fit(monkeypatch,
         rows = s.sql("select sym, avg(id) from testtable "
                      "group by sym").rows()
         s.stop()
+        assert len(rows) == 100
+        (fn, args), = [(f, a) for ph, f, a in seen if ph == "main"]
+        comps = []
+        for batches in buckets:
+            shapes = [
+                jax.tree.map(lambda a: sds(a, (batches,) + a.shape[1:]),
+                             args[0]),
+                jax.tree.map(scalar, args[1]), jax.tree.map(scalar, args[2]),
+                jax.tree.map(lambda a: sds(
+                    a, (batches * a.shape[0],) + a.shape[1:] if a.ndim
+                    else ()), args[3])]
+            comps.append(fn.lower(*shapes).compile())
     finally:
         monkeypatch.undo()
-        props.decimal_as_float64 = saved
-    assert len(rows) == 100
-    (fn, args), = [(f, a) for ph, f, a in seen if ph == "main"]
-    batches = 768
+        props.decimal_as_float64, props.agg_reduce_strategy = saved
+    return comps
 
-    def sds(a, dims):
-        return jax.ShapeDtypeStruct(tuple(dims), a.dtype, sharding=one_chip)
 
-    def scalar(a):
-        a = jnp.asarray(a)
-        return sds(a, a.shape)
+def test_quickstart_main_at_100m_rows_holds_no_scatter(monkeypatch,
+                                                       one_chip):
+    """Since PR 33 the BIGINT sum and the count mask are one limb
+    product: no `scatter` in the module, one loop whose product has the
+    one-hot fused into it.  What the temporaries hold is the compiler's
+    split of the int64 plate into uint32 halves at the parameter and
+    their relayout to the flat rows (12 bytes a row at most, 16 with
+    the scatters): that part grows with the batch count, and nothing of
+    the walker's does (a step's limbs are 7 MB)."""
+    rows = 131072
+    for batches, comp in zip((96, 768), _quickstart_main_compiled(
+            monkeypatch, one_chip, "auto", (96, 768))):
+        hlo = comp.as_text()
+        assert " scatter(" not in hlo
+        assert "/group_reduce/" in hlo and " while(" in hlo
+        assert re.search(r"convolution\(%iota_compare_fusion", hlo), \
+            "the one-hot is no longer fused into the product"
+        mem = comp.memory_analysis()
+        assert mem.temp_size_in_bytes <= 12 * batches * rows + (8 << 20)
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+            < 16e9 / 5
 
-    shapes = [jax.tree.map(lambda a: sds(a, (batches,) + a.shape[1:]),
-                           args[0]),
-              jax.tree.map(scalar, args[1]), jax.tree.map(scalar, args[2]),
-              jax.tree.map(lambda a: sds(
-                  a, (batches * a.shape[0],) + a.shape[1:] if a.ndim
-                  else ()), args[3])]
-    comp = fn.lower(*shapes).compile()
+
+def test_quickstart_main_forced_to_scatter_is_two_scatters(monkeypatch,
+                                                           one_chip):
+    """The control: with the families forced to `scatter` the BIGINT sum
+    is one scatter over a pair of uint32 halves and the count one int32
+    scatter, both under `group_reduce` (the plan the cell ran before
+    PR 33, 9.06 s a statement)."""
+    (comp,) = _quickstart_main_compiled(monkeypatch, one_chip, "scatter",
+                                        (768,))
     scatters = [ln for ln in comp.as_text().splitlines()
                 if " scatter(" in ln]
     assert len(scatters) == 2
