@@ -44,7 +44,9 @@ def rehearse(capsys, workload, trace=0, root=ROOT, seed=2147483659):
 def test_manifest_is_what_the_driver_takes():
     m = manifest.Manifest(ROOT)
     assert manifest.problems(m) == []
-    assert [w["name"] for w in m.doc["workloads"]] == CELLS
+    # the cells PR 24 defined, in its order; later PRs add their own
+    assert [w["name"] for w in m.doc["workloads"]
+            if w["name"] in CELLS] == CELLS
     assert all(w["chips"] == 1 for w in m.doc["workloads"])
 
 

@@ -88,7 +88,8 @@ def test_manifest_takes_the_new_entries():
     m = manifest.Manifest(ROOT)
     assert manifest.problems(m) == []
     names = [p["name"] for p in m.doc["per_layer"]]
-    assert names[-len(NEW):] == NEW          # appended, in this order
+    # in this order, wherever later PRs put theirs
+    assert [n for n in names if n in NEW] == NEW
     for n in NEW:
         cells = next(p for p in m.doc["per_layer"]
                      if p["name"] == n)["workloads"]
