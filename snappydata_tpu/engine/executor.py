@@ -657,8 +657,10 @@ class CompiledPlan:
         sp.set("gidx_cache_hit", int(gidx_cache_hit))
         # and what its joins were: how many lowered to the device, the
         # probe keys they searched (the probe side's padded slots, one
-        # search a join) and the expanded output slots of one-to-many
-        # builds (0 where every build is unique)
+        # search a join), the build side's padded slots, the expanded
+        # output slots of one-to-many builds (0 where every build is
+        # unique), how many builds were row tables and how many joins
+        # matched on more than one key pair
         jnote = self.join_notes.get(static) if self.join_notes else None
         for key in _JOIN_NOTE_KEYS:
             sp.set(key, jnote[key] if jnote else 0)
@@ -2182,12 +2184,20 @@ class Compiler:
         def run_join(ctx) -> RelOut:
             return join_body(ctx, left(ctx), right(ctx))
 
+        from snappydata_tpu.storage.table_store import RowTableData
+
+        row_build = int(build_rel is not None
+                        and isinstance(build_rel.info.data, RowTableData))
+
         # everything of this join is under `join` in the HLO's op_name;
         # its parts (ops/join.py) nest their own names
         @tracing.op_scope("join")
         def join_body(ctx, lo, ro) -> RelOut:
             ctx.join_note["join_device_joins"] += 1
             ctx.join_note["join_probe_rows"] += int(lo.valid.size)
+            ctx.join_note["join_build_rows"] += int(ro.valid.size)
+            ctx.join_note["join_row_builds"] += row_build
+            ctx.join_note["join_multikey_joins"] += int(len(equi) > 1)
             lpairs = [lo.cols[k] for k, _ in equi]
             rpairs = [ro.cols[k - nleft] for _, k in equi]
             # translate left string codes into right code space first
@@ -3373,7 +3383,8 @@ def _cards_of(key_infos, ctx):
 
 _JOIN_NOTE_KEYS = ("join_device_joins", "join_probe_rows",
                    "join_expand_out_rows", "join_merge_probes",
-                   "join_search_loops")
+                   "join_search_loops", "join_build_rows",
+                   "join_row_builds", "join_multikey_joins")
 
 
 class _TraceCtx:

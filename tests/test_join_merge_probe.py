@@ -351,3 +351,32 @@ def test_q3_holds_no_while_under_join_probe_with_the_merge(monkeypatch):
     assert _whiles_under(hlo, "join_gather") == []
     assert any(" sort(" in ln and "/join_probe/" in ln
                for ln in hlo.splitlines())
+
+
+@pytest.mark.parametrize("merge", [False, True], ids=["loop", "merge"])
+def test_q3_reads_no_row_build_and_no_multikey_join(monkeypatch, merge):
+    """The counters PR 36 added leave the join cell's program as it was:
+    Q3's two builds are column tables matched on one key pair each, and
+    the build slots are the two builds' padded slots."""
+    from snappydata_tpu.utils import tpch
+
+    if merge:
+        monkeypatch.setattr(dj, "probe_lowering",
+                            lambda backend, n_probe, n_build: dj.PROBE_MERGE)
+    props = config.global_properties()
+    saved = props.tracing_enabled
+    props.tracing_enabled = True
+    s = SnappySession(catalog=Catalog())
+    try:
+        tpch.load_tpch(s, sf=0.002, seed=11)
+        s.sql(tpch.Q3).rows()
+        (main,) = _main_attrs(tracing.ring().last().to_dict()["root"])
+    finally:
+        s.stop()
+        props.tracing_enabled = saved
+    assert main["join_device_joins"] == 2
+    assert main["join_merge_probes"] == (2 if merge else 0)
+    assert main["join_row_builds"] == 0
+    assert main["join_multikey_joins"] == 0
+    assert main["join_build_rows"] > 0
+    assert main["join_build_rows"] % 2 == 0
