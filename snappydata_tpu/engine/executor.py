@@ -648,13 +648,17 @@ class CompiledPlan:
         `reduce_padded_rows` the slots it walked (batch bucket x batch
         capacity, padding included).  `gidx_cache_hit` is 1 where the
         statement took its group index from the cache and ran the main
-        phase alone."""
+        phase alone.  `gidx_run_lane` is 1 where the statement built its
+        group index by run heads over the sorted keys (the generic
+        branch of `compute_pre`), 0 where none or the cache gave it."""
         note = self.agg_notes.get(static) if self.agg_notes else None
         for key in ("dict_space_slots", "scatter_slots",
                     "isum_scatter_slots", "limb_matmul_slots",
                     "group_slots", "reduce_padded_rows"):
             sp.set(key, note[key] if note else 0)
         sp.set("gidx_cache_hit", int(gidx_cache_hit))
+        sp.set("gidx_run_lane", note["gidx_run_lane"]
+               if note and not gidx_cache_hit else 0)
         # and what its joins were: how many lowered to the device, the
         # probe keys they searched (the probe side's padded slots, one
         # search a join), the build side's padded slots, the expanded
@@ -2801,28 +2805,17 @@ class Compiler:
                             .reshape(-1)
                         kv = jnp.where(nb, card, kv)
                     gidx = gidx * ecard + kv
-            else:
-                combined = _combine_keys(
-                    [DVal(_broadcast_to_mask(k.value, out.valid)
-                          .reshape(-1),
-                          _broadcast_to_mask(k.null, out.valid)
-                          .reshape(-1) if k.null is not None else None,
-                          k.dtype) for k in kdvals])
-                combined = jnp.where(valid, combined, _I64_MAX)
-                uniq = jnp.unique(combined, size=num_groups + 1,
-                                  fill_value=_I64_MAX)
-                # overflow ⟺ the sentinel got pushed out of the
-                # (size num_groups+1) unique set ⟺ > num_groups real
-                # keys — silent truncation would return WRONG results,
-                # so the executor reruns on the exact host path
-                if num_groups < n:
-                    overflow = uniq[-1] != _I64_MAX
-                gidx = jnp.searchsorted(uniq, combined)
-            # int32 group index: num_groups <= max_groups (65536) always
-            # fits, and it halves the cached-gidx bytes + one-hot
-            # comparison traffic
-            return (jnp.where(valid, gidx, num_groups)
-                    .astype(jnp.int32), overflow)
+                # int32 group index: num_groups <= max_groups (65536)
+                # always fits, and it halves the cached-gidx bytes +
+                # one-hot comparison traffic
+                return (jnp.where(valid, gidx, num_groups)
+                        .astype(jnp.int32), overflow)
+            combined = _combine_keys(
+                [DVal(_broadcast_to_mask(k.value, out.valid).reshape(-1),
+                      _broadcast_to_mask(k.null, out.valid).reshape(-1)
+                      if k.null is not None else None,
+                      k.dtype) for k in kdvals])
+            return _run_head_index(combined, valid, num_groups)
 
         def fsum_strategy_of(ctx, n, nseg):
             from snappydata_tpu.ops import reduction
@@ -3265,6 +3258,8 @@ class Compiler:
                 "scatter_slots": note["scatter_slots"],
                 "isum_scatter_slots": note["isum_scatter_slots"],
                 "limb_matmul_slots": note["limb_matmul_slots"],
+                # compute_pre's generic branch: the index by run heads
+                "gidx_run_lane": int(bool(groups) and not fast),
                 "group_slots": num_groups,
                 "reduce_padded_rows": n,
                 "table": base_table_ref}
@@ -3349,6 +3344,39 @@ def _slots_to_cols(e: ast.Expr, n_groups: int) -> ast.Expr:
     if isinstance(e, _KeyRef):
         return ast.Col(f"__key{e.key}", None, e.key, e.dtype)
     return e.map_children(lambda c: _slots_to_cols(c, n_groups))
+
+
+def _run_head_index(keys, valid, num_groups: int):
+    """The generic group index from one sort: (gidx int32, overflow).
+    A valid row's id is its key's rank among the distinct valid keys,
+    the rank `searchsorted` over the sorted distinct keys gives.  The
+    keys are sorted with their row numbers; a run of equal keys starts
+    where a sorted key differs from the one before it, and the running
+    count of run heads is the rank.  A sort on the row numbers brings
+    the ids home, as the join's merge brings its probes home
+    (ops/join.py `_probe_order`).  Invalid rows sort last under
+    `_I64_MAX` and read `num_groups`, as does any id past the slots:
+    `overflow` says there were more distinct valid keys than
+    `num_groups` (silent truncation would return WRONG results, so the
+    executor reruns on the exact host path)."""
+    n = keys.shape[0]
+    keys = jnp.where(valid, keys, _I64_MAX)
+    # equal keys share a rank whatever their order: no stable sort needed
+    skeys, rows = jax.lax.sort((keys, jnp.arange(n, dtype=jnp.int32)),
+                               num_keys=1)
+    head = jnp.concatenate([jnp.ones(skeys[:1].shape, jnp.bool_),
+                            skeys[1:] != skeys[:-1]])
+    rank = jnp.cumsum(head.astype(jnp.int32)) - 1
+    _, gidx = jax.lax.sort((rows, jnp.minimum(rank, num_groups)),
+                           num_keys=1)
+    overflow = jnp.asarray(False)
+    if num_groups < n:
+        # the sentinel's run is the last, and a group only where a valid
+        # key equals the sentinel
+        distinct = (rank[-1] + 1 - (skeys[-1] == _I64_MAX)
+                    + jnp.any(valid & (keys == _I64_MAX)))
+        overflow = distinct > num_groups
+    return jnp.where(valid, gidx, num_groups).astype(jnp.int32), overflow
 
 
 @tracing.op_scope("group_keys")

@@ -38,7 +38,8 @@ METRICS = ["q3_stmt_ms", "plan_ms.join", "bind_ms.join",
            "join_roofline", "xla_compiles_in_window.join",
            "join_build_sorts.join", "host_fallbacks.join",
            "join_device_joins.join", "scatter_slots.join",
-           "join_merge_probes.join", "join_search_loops.join"]
+           "join_merge_probes.join", "join_search_loops.join",
+           "gidx_run_lanes.join"]
 DRAWS = [("BUILDING", "1995-03-15"), ("MACHINERY", "1995-03-01"),
          ("HOUSEHOLD", "1995-03-31")]
 _EPOCH = datetime.date(1970, 1, 1)
@@ -319,6 +320,7 @@ def test_rehearsal_of_the_cell_is_correct(capsys, monkeypatch, man, trace):
     assert value["join_build_sorts.join"] == 0
     assert value["join_device_joins.join"] == 2
     assert value["scatter_slots.join"] == 1
+    assert value["gidx_run_lanes.join"] == 1
 
 
 def _off_by_a_millionth(rows):
@@ -436,6 +438,8 @@ def test_a_traced_q3_stays_on_the_device_and_says_so(traced_q3):
         assert attrs["join_expand_out_rows"] == 0
         assert attrs["groups_overflow"] == 0
         assert attrs["scatter_slots"] == 1 and attrs["dict_space_slots"] == 0
+        # Q3's three keys take the generic group index, by run heads
+        assert attrs["gidx_run_lane"] == 1
         # the generic group index: min(max_groups, padded rows) segments
         assert attrs["group_slots"] == 65536
         # both joins probe on the lineitem side's padded slots
@@ -526,6 +530,7 @@ def test_every_new_metric_reads_a_number_from_the_trace(
         "join_build_sorts.join": 0, "join_device_joins.join": 2,
         "scatter_slots.join": 1, "device_idle_pct.join": 20.0,
         "join_merge_probes.join": 2, "join_search_loops.join": 0,
+        "gidx_run_lanes.join": 1,
         "join_roofline": 100.0 * (2 * window[0]["rows_read"] * 26 / 819e9)
         / 2.0}
     if name in expected:
@@ -538,7 +543,8 @@ def test_every_new_metric_reads_a_number_from_the_trace(
     # a program from before the attrs: None, not an error
     attr = {"join_device_joins.join": "join_device_joins",
             "join_merge_probes.join": "join_merge_probes",
-            "join_search_loops.join": "join_search_loops"}.get(name)
+            "join_search_loops.join": "join_search_loops",
+            "gidx_run_lanes.join": "gidx_run_lane"}.get(name)
     if attr is not None:
         bare = json.loads(json.dumps(window))
         for r in bare:

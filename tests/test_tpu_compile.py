@@ -3,8 +3,8 @@ TPU compiler is installed here and compiles for a described v5e, so what
 it refuses, and what it would hold in HBM, is known before a chip run.
 Nothing runs on the chip: no result, no time (the plan cases run one
 statement over one batch on the CPU, to be handed the plan).  The join
-case compiles for about two minutes (four wide sorts); the rest take
-seconds.
+case compiles for about two minutes (four wide sorts) and Q3's group
+index for about 45 s (two); the rest take seconds.
 
 The topology is described inside a fixture, never at import or
 collection: only the worker that is handed this file loads the TPU's
@@ -288,3 +288,29 @@ def test_quickstart_main_forced_to_scatter_is_two_scatters(monkeypatch,
                   for ln in scatters) == [0, 2]
     mem = comp.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9 / 5
+
+
+def test_q3_group_index_at_sf1_is_two_sorts_and_no_loop(one_chip):
+    """Q3's generic group index over its 6,291,456 slots at SF 1 (48
+    batches of 131,072) as the chip lowers it: the keys' sort, a
+    prefix sum over the run heads and the sort that brings the
+    ids home; no `while` (the `searchsorted` it replaced was one of 17
+    steps), no gather, no scatter (`jnp.unique`'s compaction was one).
+    Temporaries stay under 8 bytes a slot."""
+    import jax
+    import jax.numpy as jnp
+
+    from snappydata_tpu.engine.executor import _run_head_index
+
+    n = 48 * 131072
+
+    def shape(dt):
+        return jax.ShapeDtypeStruct((n,), dt, sharding=one_chip)
+
+    comp = jax.jit(_run_head_index, static_argnums=2).lower(
+        shape(jnp.int64), shape(jnp.bool_), 65536).compile()
+    hlo = comp.as_text()
+    for op in (" while(", " gather(", " scatter("):
+        assert op not in hlo, op
+    assert hlo.count(" sort(") == 2
+    assert comp.memory_analysis().temp_size_in_bytes <= 8 * n

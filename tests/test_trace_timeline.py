@@ -471,6 +471,8 @@ def test_main_dispatch_carries_slot_counts(_restore_knobs, label, lane,
             assert sp["name"] == expect_name
             assert (sp["attrs"]["dict_space_slots"],
                     sp["attrs"]["scatter_slots"]) == want
+            # Q1's two dictionary keys: no run-head group index
+            assert sp["attrs"]["gidx_run_lane"] == 0
         s.stop()
     finally:
         props.set("agg_on_codes", saved[0])
