@@ -551,9 +551,10 @@ class SnappyFlightServer(flight.FlightServerBase):
                 else:
                     result = sess.execute_statement(
                         _ast.Query(plan), tuple(req.get("params", ())))
-            table = result_to_arrow(result)
-            chunk = int(req.get("page_rows", 65536))
-            batches = table.to_batches(max_chunksize=max(1, chunk))
+                with tracing.span("encode"):
+                    table = result_to_arrow(result)
+                    chunk = int(req.get("page_rows", 65536))
+                    batches = table.to_batches(max_chunksize=max(1, chunk))
             return flight.GeneratorStream(table.schema, iter(batches))
         if "scan_table" in req:
             # full-table export ticket: stream scan units without ever
@@ -612,12 +613,15 @@ class SnappyFlightServer(flight.FlightServerBase):
                 result = sess.sql(req["sql"],
                                   params=tuple(req.get("params", ())),
                                   query_ctx=ctx)
-        table = result_to_arrow(result)
-        # page as record batches (ref: CachedDataFrame paged collect /
-        # GfxdHeapDataOutputStream result pages) — clients start consuming
-        # before the last page is serialized
-        chunk = int(req.get("page_rows", 65536))
-        batches = table.to_batches(max_chunksize=max(1, chunk))
+            # inside the server's trace, so that it covers what it serves
+            with tracing.span("encode"):
+                table = result_to_arrow(result)
+                # page as record batches (ref: CachedDataFrame paged
+                # collect / GfxdHeapDataOutputStream result pages) —
+                # clients start consuming before the last page is
+                # serialized
+                chunk = int(req.get("page_rows", 65536))
+                batches = table.to_batches(max_chunksize=max(1, chunk))
         return flight.GeneratorStream(table.schema, iter(batches))
 
     def get_flight_info(self, context, descriptor):

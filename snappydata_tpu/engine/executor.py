@@ -728,6 +728,9 @@ class CompiledPlan:
         `bytes` copied.  The span's extent is what it has always been."""
         tables, outs, dispatch = self._run_device(params)
         outs = _transfer(outs)
+        # the Result's assembly and whatever else the statement does
+        # before it closes
+        tracing.step("finish")
         if bool(np.asarray(outs[2])):
             dispatch.set("groups_overflow", 1)
             raise CompileError(
@@ -3893,6 +3896,8 @@ class Executor:
                 plan_key: Optional[str] = None) -> Result:
         from snappydata_tpu.observability.metrics import global_registry
 
+        # the plan cache up to `bind` (a miss's `compile` beside it)
+        tracing.step("plan_lookup")
         check_current()  # cancellation point: every (sub)plan execution
         if self._depth:  # nested calls (unions, host fallback) count once
             return self._execute_with_host_ops(plan, params, plan_key)
@@ -3934,6 +3939,7 @@ class Executor:
             for op in reversed(host_ops):
                 result = self._apply_host_op(op, result, params)
             sp.set("rows_out", result.num_rows)
+        tracing.step("finish")
         return result
 
     # -- core -------------------------------------------------------------

@@ -141,6 +141,9 @@ class MetricsRegistry:
         self._lock = locks.named_lock("observability.metrics_registry")
         self._counters: Dict[str, int] = defaultdict(int)
         self._gauges: Dict[str, Callable[[], float]] = {}
+        # counters kept outside the registry by code that may not take
+        # its lock (the collector's hook), read with the others
+        self._counter_fns: Dict[str, Callable[[], int]] = {}
         self._timers: Dict[str, Timer] = defaultdict(Timer)
 
     def inc(self, name: str, value: int = 1) -> None:
@@ -150,6 +153,19 @@ class MetricsRegistry:
     def gauge(self, name: str, fn: Callable[[], float]) -> None:
         with self._lock:
             self._gauges[name] = fn
+
+    def counter_fn(self, name: str, fn: Callable[[], int]) -> None:
+        """A counter whose value `fn` keeps: it takes no lock and reads
+        a plain int, and it is reported among the counters."""
+        with self._lock:
+            self._counter_fns[name] = fn
+
+    def _counts(self) -> Dict[str, int]:
+        """Every counter, those kept outside included (lock held)."""
+        out = dict(self._counters)
+        for name, fn in self._counter_fns.items():
+            out[name] = fn()
+        return out
 
     def time(self, name: str):
         # one prebuilt context class: defining it per call cost ~20µs of
@@ -163,13 +179,14 @@ class MetricsRegistry:
 
     def counter(self, name: str) -> int:
         with self._lock:
-            return self._counters.get(name, 0)
+            fn = self._counter_fns.get(name)
+            return fn() if fn is not None else self._counters.get(name, 0)
 
     def counters_snapshot(self) -> Dict[str, int]:
         """Counters only — the cheap delta-capture surface EXPLAIN
         ANALYZE and the bench use (no gauge evaluation)."""
         with self._lock:
-            return dict(self._counters)
+            return self._counts()
 
     def snapshot(self) -> dict:
         # gauge callables run OUTSIDE the lock: a gauge that touches the
@@ -177,7 +194,7 @@ class MetricsRegistry:
         # used to self-deadlock on this non-reentrant lock
         with self._lock:
             gauge_fns = list(self._gauges.items())
-            counters = dict(self._counters)
+            counters = self._counts()
             timers = {k: t.to_dict() for k, t in self._timers.items()}
         gauges = {}
         for name, fn in gauge_fns:
@@ -201,7 +218,7 @@ class MetricsRegistry:
         collision-proof sanitized names, histogram buckets + quantile
         gauges for every timer."""
         with self._lock:
-            counters = dict(self._counters)
+            counters = self._counts()
             gauge_fns = list(self._gauges.items())
             timers = {k: (t.to_dict(), t.prometheus_buckets())
                       for k, t in self._timers.items()}

@@ -43,6 +43,15 @@ Span tree invariants:
   ``jax.monitoring`` listener adds ``xla_compiles`` / ``xla_compile_ms``
   / ``retraces`` to whichever span is current (and to the registry),
   whatever that span is called.
+- A statement's host time is named end to end: where one step of it
+  starts in one function and ends in another (``admit``,
+  ``plan_lookup``, ``finish``: `HOST_SPANS`), ``step(name)`` opens a
+  child that lasts until its parent's next child starts or the parent
+  closes, so the root's children tile it.
+- Python's collector is timed where it pauses the program: one
+  process-wide ``gc.callbacks`` hook adds ``gc_ms`` (and ``gc_full`` for
+  the oldest generation) to whichever span is current, and to two
+  module totals the registry reads (``gc_pause_ms``, ``gc_collections``).
 
 Completed traces land in a bounded in-process ring
 (``trace_ring_entries``) served by ``GET /status/api/v1/traces``; any
@@ -55,6 +64,7 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import gc
 import itertools
 import threading
 from snappydata_tpu.utils import locks
@@ -90,7 +100,8 @@ class Span:
     """One timed phase. `attrs` carries the phase's evidence (batch
     counts, cache verdicts, member addresses); children nest."""
 
-    __slots__ = ("name", "attrs", "children", "_t0", "duration_s")
+    __slots__ = ("name", "attrs", "children", "_t0", "duration_s",
+                 "_step")
 
     def __init__(self, name: str, attrs: Optional[dict] = None):
         self.name = name
@@ -98,6 +109,9 @@ class Span:
         self.children: List["Span"] = []
         self._t0 = time.perf_counter()
         self.duration_s: Optional[float] = None
+        # the child `step` opened and no later child has ended yet, with
+        # its profiler annotation
+        self._step = None
 
     def set(self, key: str, value) -> None:
         self.attrs[key] = value
@@ -105,8 +119,18 @@ class Span:
     def add(self, key: str, value) -> None:
         self.attrs[key] = self.attrs.get(key, 0) + value
 
+    def end_step(self) -> None:
+        """Close the step this span has open, if any."""
+        st, self._step = self._step, None
+        if st is not None:
+            st[0].close()
+            if st[1] is not None:
+                st[1].__exit__(None, None, None)
+
     def close(self) -> None:
         if self.duration_s is None:
+            if self._step is not None:
+                self.end_step()
             self.duration_s = time.perf_counter() - self._t0
 
     def to_dict(self, t_root: Optional[float] = None) -> dict:
@@ -175,7 +199,8 @@ class Trace:
         self.kind = kind
         self.origin = origin
         self.ts = time.time()
-        self.root = Span("request")
+        # 0 where no collection ran, so a reader reads 0 and not None
+        self.root = Span("request", {"gc_ms": 0})
         self.status = "ok"
         self.error: Optional[str] = None
         self.duration_s: Optional[float] = None
@@ -356,6 +381,8 @@ class span:
         parent = _span.get()
         if parent is None:
             return _NOOP
+        if parent._step is not None:
+            parent.end_step()
         if len(parent.children) >= _MAX_CHILDREN:
             parent.attrs["children_truncated"] = \
                 parent.attrs.get("children_truncated", 0) + 1
@@ -375,6 +402,31 @@ class span:
             if self._ann is not None:
                 self._ann.__exit__(et, ev, tb)
         return False
+
+
+def step(name: str) -> None:
+    """Open the step `name` of `HOST_SPANS` as a child of the current
+    span, for host time that starts in one function and ends in another
+    further along the statement: it lasts until the current span's next
+    child (span or step) starts, or the current span closes. A step is
+    never the current span, so it holds no children and what runs
+    inside it lands on its parent (attrs, the collector's `gc_ms`). A
+    no-op (one contextvar read) when no trace is active."""
+    parent = _span.get()
+    if parent is None:
+        return
+    if name not in HOST_SPANS:
+        raise ValueError(f"unknown host span {name!r}")
+    if parent._step is not None:
+        parent.end_step()
+    if len(parent.children) >= _MAX_CHILDREN:
+        parent.attrs["children_truncated"] = \
+            parent.attrs.get("children_truncated", 0) + 1
+        return
+    ann = _annotation(name) if _profiling() else None
+    sp = Span(name)
+    parent.children.append(sp)
+    parent._step = (sp, ann)
 
 
 def annotate(key: str, value) -> None:
@@ -421,6 +473,59 @@ jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
 
 
 # -----------------------------------------------------------------------
+# Python's collector, wherever it pauses the program
+# -----------------------------------------------------------------------
+
+_gc_t0 = 0.0
+_gc_pause_s = 0.0
+_gc_collections = 0
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """`gc.callbacks` hook. A collection can start at any bytecode
+    boundary, the registry's lock held or not, so this takes no lock:
+    module floats and ints, and the current span's attrs, under the GIL.
+    Collections do not overlap (the collector does not re-enter), so one
+    start time serves."""
+    global _gc_t0, _gc_pause_s, _gc_collections
+    if phase == "start":
+        _gc_t0 = time.perf_counter()
+        return
+    dt = time.perf_counter() - _gc_t0
+    _gc_pause_s += dt
+    _gc_collections += 1
+    sp = _span.get()
+    if sp is not None:
+        sp.add("gc_ms", round(dt * 1e3, 4))
+        if info.get("generation") == 2:
+            sp.add("gc_full", 1)
+
+
+def gc_pause_ms() -> float:
+    """Milliseconds the collector has paused this process, all threads."""
+    return _gc_pause_s * 1e3
+
+
+def gc_collections() -> int:
+    """Collections the collector has run in this process, all threads."""
+    return _gc_collections
+
+
+gc.callbacks.append(_on_gc)
+
+
+def _register_gc_metrics() -> None:
+    from snappydata_tpu.observability.metrics import global_registry
+
+    reg = global_registry()
+    reg.gauge("gc_pause_ms", gc_pause_ms)
+    reg.counter_fn("gc_collections", gc_collections)
+
+
+_register_gc_metrics()
+
+
+# -----------------------------------------------------------------------
 # names on the device side
 # -----------------------------------------------------------------------
 
@@ -437,6 +542,17 @@ jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
 OP_SCOPES = ("filter", "decode", "dict_gather", "group_index",
              "group_reduce", "group_keys", "join", "join_probe",
              "join_gather", "join_expand")
+
+# the spans that name a statement's host time between the others, so
+# that the root's children tile it: `admit` from the end of `parse` to
+# `optimize` (the governor's estimate and admission, the stream-window,
+# tiled and mesh checks), `plan_lookup` from `Executor.execute` to `bind`
+# (the plan cache, a miss's `compile` beside it), `finish` from the end
+# of `transfer` (or `host_ops`) to the statement's close (the Result's
+# assembly, decimals, the query log, the governor's release), opened by
+# `step`; and `encode`, a served statement's Arrow encode, a span of the
+# Flight server's trace
+HOST_SPANS = ("admit", "plan_lookup", "finish", "encode")
 
 
 def op_scope(name: str):

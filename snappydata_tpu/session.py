@@ -478,9 +478,13 @@ class SnappySession:
         subquery rewrites — inherit the outer context and skip
         re-admission."""
         from snappydata_tpu import resource
+        from snappydata_tpu.observability import tracing
 
         if resource.current_query() is not None:
             return self.execute_statement(stmt, params)
+        # the estimate, admission, the snapshot pin and the route checks
+        # up to `optimize`, which ends the step
+        tracing.step("admit")
         broker = resource.global_broker()
         ctx = query_ctx or resource.new_query(sql_text, self.user)
         if not ctx.sql:

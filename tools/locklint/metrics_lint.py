@@ -23,7 +23,7 @@ from typing import Dict, List, Optional, Set, Tuple
 from .common import Finding, SourceFile, load_sources, str_const
 
 _KIND_OF = {"inc": "counter", "time": "timer", "record_time": "timer",
-            "gauge": "gauge"}
+            "gauge": "gauge", "counter_fn": "counter"}
 _METRIC_HINT_RE = re.compile(r"#\s*locklint:\s*metric=([A-Za-z0-9_.\-]+)")
 
 
