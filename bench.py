@@ -395,7 +395,7 @@ def main() -> None:
                 round(delta("agg_reduce_passes") / repeats, 2),
             "strategies": {
                 st: delta(f"agg_strategy_{st}")
-                for st in ("unroll", "scatter", "matmul")
+                for st in ("unroll", "scatter", "matmul", "runs")
                 if delta(f"agg_strategy_{st}")},
             "gidx_cache_hits": delta("gidx_cache_hits"),
             "gidx_cache_misses": delta("gidx_cache_misses"),

@@ -641,12 +641,14 @@ class CompiledPlan:
         from the trace-time notes (so after the call that may trace): how
         many the dictionary-space lane took, how many of any family
         were emitted as a `segment_*` scatter, how many of those belong
-        to the exact-integer family, and how many integer columns (int64
-        sums and count masks) the limb product took instead.  0 where
-        none, and on a plan that aggregates nothing.  `group_slots` is
-        the static number of group segments the reduce ran over and
-        `reduce_padded_rows` the slots it walked (batch bucket x batch
-        capacity, padding included).  `gidx_cache_hit` is 1 where the
+        to the exact-integer family, how many integer columns (int64
+        sums and count masks) the limb product took instead, and how many
+        slots the run reduce took (`run_reduce_slots`: a generic-key
+        family that would scatter, reduced over its rows' runs in group
+        order).  0 where none, and on a plan that aggregates nothing.
+        `group_slots` is the static number of group segments the reduce
+        ran over and `reduce_padded_rows` the slots it walked (batch
+        bucket x batch capacity, padding included).  `gidx_cache_hit` is 1 where the
         statement took its group index from the cache and ran the main
         phase alone.  `gidx_run_lane` is 1 where the statement built its
         group index by run heads over the sorted keys (the generic
@@ -654,7 +656,8 @@ class CompiledPlan:
         note = self.agg_notes.get(static) if self.agg_notes else None
         for key in ("dict_space_slots", "scatter_slots",
                     "isum_scatter_slots", "limb_matmul_slots",
-                    "group_slots", "reduce_padded_rows"):
+                    "run_reduce_slots", "group_slots",
+                    "reduce_padded_rows"):
             sp.set(key, note[key] if note else 0)
         sp.set("gidx_cache_hit", int(gidx_cache_hit))
         sp.set("gidx_run_lane", note["gidx_run_lane"]
@@ -2820,13 +2823,6 @@ class Compiler:
                       k.dtype) for k in kdvals])
             return _run_head_index(combined, valid, num_groups)
 
-        def fsum_strategy_of(ctx, n, nseg):
-            from snappydata_tpu.ops import reduction
-
-            return reduction.resolve_strategy(
-                _STRATEGY_NAMES[ctx.static[strategy_si]],
-                jax.default_backend(), nseg, n, "fsum", jnp.float64)
-
         def run_pre(ctx):
             """Phase A: (valid, gidx, onehot-or-None, overflow) — the
             group-index-cache entry."""
@@ -2843,7 +2839,10 @@ class Compiler:
             else:
                 num_groups = 1
             onehot = None
-            if fsum_strategy_of(ctx, n, num_groups) == "matmul":
+            if reduction.resolve_strategy(
+                    _STRATEGY_NAMES[ctx.static[strategy_si]],
+                    jax.default_backend(), num_groups, n, "fsum",
+                    jnp.float64) == "matmul":
                 # one-hot over the REAL groups only: an invalid row's
                 # one-hot row is all-zero, so it contributes nothing —
                 # the overflow segment is never consumed downstream
@@ -2876,9 +2875,20 @@ class Compiler:
             nseg = num_groups + 1
             backend = jax.default_backend()
             req = _STRATEGY_NAMES[ctx.static[strategy_si]]
-            fsum_strat = fsum_strategy_of(ctx, n, num_groups)
-            istrat = reduction.resolve_strategy(
-                req, backend, num_groups, n, "isum", jnp.int64)
+            # the generic branch has no small dense index to offer: a
+            # family that would scatter there reduces over the rows' runs
+            # in group order instead (`reduction.run_reduce`), one sort
+            # for every such family, and the keys are read at the runs'
+            # heads
+            by_runs = bool(groups) and not fast
+
+            def strategy_of(family: str, dtype) -> str:
+                s = reduction.resolve_strategy(req, backend, num_groups, n,
+                                               family, dtype)
+                return "runs" if by_runs and s == "scatter" else s
+
+            fsum_strat = strategy_of("fsum", jnp.float64)
+            istrat = strategy_of("isum", jnp.int64)
             if pre is None and fsum_strat == "matmul":
                 onehot = reduction.make_onehot(gidx, num_groups,
                                                jnp.float64)
@@ -2889,7 +2899,7 @@ class Compiler:
             note = {"passes": 0, "strategies": set(), "lanes": set(),
                     "rle_fallbacks": 0, "dict_space_slots": 0,
                     "scatter_slots": 0, "isum_scatter_slots": 0,
-                    "limb_matmul_slots": 0}
+                    "limb_matmul_slots": 0, "run_reduce_slots": 0}
             tok = ctx.static[code_agg_si]
             # dictionary-space SUM counts by a one-hot product shaped
             # for the MXU: auto engages it on the accelerator only (the
@@ -2910,6 +2920,8 @@ class Compiler:
                 note["strategies"].add(strategy)
                 if strategy == "scatter":
                     note["scatter_slots"] += nslots
+                elif strategy == "runs":
+                    note["run_reduce_slots"] += nslots
             rle_ok = (tok != 0 and rle_gate_si is not None
                       and bool(ctx.static[rle_gate_si])
                       and jnp.ndim(out.valid) == 2)
@@ -3110,7 +3122,9 @@ class Compiler:
             gvalid_col = count_col(valid)
 
             # --- family dispatch: one fused reduction each ---
-            count_res = None
+            # (name, strategy, columns, kind) a family; the families that
+            # take the runs share ONE sort of the group index
+            families: List[tuple] = []
             join_counts = bool(count_ws) and fsum_strat == "matmul"
             if fsum_cols or join_counts:
                 cols = [c for _, c in fsum_cols]
@@ -3122,54 +3136,84 @@ class Compiler:
                     for w in count_ws:
                         cols.append(jnp.ones(n, jnp.float64) if w is valid
                                     else jnp.where(w, 1.0, 0.0))
-                res = reduction.packed_sum(cols, gidx, num_groups,
-                                           fsum_strat, onehot=onehot)
+                families.append(("fsum", fsum_strat, cols, "sum"))
                 family_pass(fsum_strat, len(fsum_cols))
-                for pos, (i, _) in enumerate(fsum_cols):
-                    slot_arrays[i] = res[:, pos]
-                if join_counts:
-                    count_res = jnp.round(
-                        res[:, len(fsum_cols):]).astype(jnp.int64)
             # counts follow the float family's strategy (matmul was
             # handled by joining above): on the unroll path that keeps
             # the old fast int32 masked sums.  Where that would be a
             # scatter they resolve as the exact-integer family does, and
             # under its limb product the masks ride the int64 pack's one
             # product as 0/1 columns (one read of gidx, one one-hot).
-            limb_counts = (bool(count_ws) and count_res is None
+            # Over the runs the validity mask's count is its run's
+            # length, and only the other masks are summed.
+            limb_counts = (bool(count_ws) and not join_counts
                            and fsum_strat == "scatter"
                            and istrat == "matmul")
-            if count_ws and count_res is None and not limb_counts:
+            if count_ws and not join_counts and not limb_counts:
                 cdt = reduction.count_pack_dtype(n)
-                count_res = reduction.packed_sum(
-                    [w.astype(cdt) for w in count_ws], gidx, num_groups,
-                    fsum_strat).astype(jnp.int64)
+                families.append(("count", fsum_strat, [
+                    w.astype(cdt) for w in count_ws
+                    if not (fsum_strat == "runs" and w is valid)], "sum"))
                 family_pass(fsum_strat, len(count_users))
             if isum_cols or limb_counts:
                 icols = [c for _, c in isum_cols] \
                     + (count_ws if limb_counts else [])
-                ires = reduction.packed_sum(icols, gidx, num_groups,
-                                            istrat)
+                families.append(("isum", istrat, icols, "sum"))
                 family_pass(istrat, len(isum_cols))
                 if istrat == "scatter":
                     note["isum_scatter_slots"] += len(isum_cols)
                 elif istrat == "matmul":
                     note["limb_matmul_slots"] += len(icols)
-                for pos, (i, _) in enumerate(isum_cols):
-                    slot_arrays[i] = ires[:, pos]
-                if limb_counts:
-                    count_res = ires[:, len(isum_cols):]
+            for mkey, entries in minmax.items():
+                mcols = [c for _, c in entries]
+                mstrat = strategy_of("minmax", mcols[0].dtype)
+                families.append((mkey, mstrat, mcols, mkey[0]))
+                family_pass(mstrat, sum(t[0] == "slot" for t, _ in entries))
+            fres: Dict[object, object] = {}
+            for name, strategy, cols, kind in families:
+                if strategy == "runs":
+                    continue
+                fres[name] = reduction.packed_sum(
+                    cols, gidx, num_groups, strategy,
+                    onehot=onehot if name == "fsum" else None) \
+                    if kind == "sum" else reduction.packed_minmax(
+                        kind, cols, gidx, num_groups, strategy)
+            runs = None
+            if by_runs:
+                queued = [f for f in families if f[1] == "runs"]
+                runs = reduction.run_reduce(
+                    gidx, num_groups, [c for f in queued for c in f[2]],
+                    [f[3] for f in queued for _ in f[2]])
+                at = 0
+                for name, _s, cols, _k in queued:
+                    if cols:
+                        fres[name] = jnp.stack(
+                            runs.tails[at:at + len(cols)], axis=1)
+                    at += len(cols)
+
+            for pos, (i, _) in enumerate(fsum_cols):
+                slot_arrays[i] = fres["fsum"][:, pos]
+            if join_counts:
+                count_res = jnp.round(
+                    fres["fsum"][:, len(fsum_cols):]).astype(jnp.int64)
+            elif limb_counts:
+                count_res = fres["isum"][:, len(isum_cols):]
+            elif fsum_strat == "runs":
+                # the other masks' sums, in the order they were queued
+                summed = iter(range(len(count_ws)))
+                count_res = jnp.stack(
+                    [runs.counts if w is valid
+                     else fres["count"][:, next(summed)]
+                     for w in count_ws], axis=1).astype(jnp.int64)
+            else:
+                count_res = fres["count"].astype(jnp.int64)
+            for pos, (i, _) in enumerate(isum_cols):
+                slot_arrays[i] = fres["isum"][:, pos]
             for i, c in count_users:
                 slot_arrays[i] = count_res[:, c]
             guard_res: Dict[tuple, object] = {}
-            for (mkind, _dtname), entries in minmax.items():
-                mcols = [c for _, c in entries]
-                mstrat = reduction.resolve_strategy(
-                    req, backend, num_groups, n, "minmax",
-                    mcols[0].dtype)
-                mres = reduction.packed_minmax(mkind, mcols, gidx,
-                                               num_groups, mstrat)
-                family_pass(mstrat, sum(t[0] == "slot" for t, _ in entries))
+            for mkey, entries in minmax.items():
+                mres = fres[mkey]
                 for pos, (tag, _) in enumerate(entries):
                     if tag[0] == "slot":
                         slot_arrays[tag[1]] = mres[:, pos]
@@ -3229,8 +3273,7 @@ class Compiler:
                                 if kd.dtype else jnp.int64))
                 else:
                     for kd in key_vals:
-                        k_arr, k_null = _segment_key(
-                            kd, out.valid, valid, gidx, num_groups)
+                        k_arr, k_null = _run_head_key(kd, out.valid, runs)
                         key_arrays.append(k_arr)
                         key_nulls.append(k_null)
                 key_arrays = [k[:num_groups] if k.shape[0] > num_groups else k
@@ -3261,6 +3304,7 @@ class Compiler:
                 "scatter_slots": note["scatter_slots"],
                 "isum_scatter_slots": note["isum_scatter_slots"],
                 "limb_matmul_slots": note["limb_matmul_slots"],
+                "run_reduce_slots": note["run_reduce_slots"],
                 # compute_pre's generic branch: the index by run heads
                 "gidx_run_lane": int(bool(groups) and not fast),
                 "group_slots": num_groups,
@@ -3383,20 +3427,16 @@ def _run_head_index(keys, valid, num_groups: int):
 
 
 @tracing.op_scope("group_keys")
-def _segment_key(kd, mask2d, valid, gidx, num_groups):
-    """One generic GROUP BY key reduced to its value a group (every row
-    of a group holds the same), and its NULL flag where it has one."""
-    kv = _broadcast_to_mask(kd.value, mask2d).reshape(-1)
-    filler = _extreme(kv.dtype, False)
-    k_arr = jax.ops.segment_max(
-        jnp.where(valid, kv, filler), gidx,
-        num_segments=num_groups + 1)[:num_groups]
+def _run_head_key(kd, mask2d, runs):
+    """One generic GROUP BY key's value a group, read at the head of the
+    group's run (every row of a group holds the same), and its NULL flag
+    where it has one.  An empty group reads some row's: its output row
+    is masked by the group's count."""
+    head = runs.rows[jnp.minimum(runs.bounds[:-1], runs.rows.shape[0] - 1)]
+    k_arr = _broadcast_to_mask(kd.value, mask2d).reshape(-1)[head]
     k_null = None
     if kd.null is not None:
-        nb = _broadcast_to_mask(kd.null, mask2d).reshape(-1)
-        k_null = jax.ops.segment_max(
-            (nb & valid).astype(jnp.int32), gidx,
-            num_segments=num_groups + 1)[:num_groups].astype(bool)
+        k_null = _broadcast_to_mask(kd.null, mask2d).reshape(-1)[head]
     return k_arr, k_null
 
 

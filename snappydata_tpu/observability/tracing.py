@@ -537,8 +537,8 @@ _register_gc_metrics()
 # probe keys' binary search of the sorted build keys), `join_gather`
 # (build-side columns fetched through the match positions) and
 # `join_expand` (the one-to-many output axis); the innermost name says
-# which.  `group_keys` is a generic GROUP BY's key columns reduced to
-# one value a group.
+# which.  `group_keys` is a generic GROUP BY's key columns read at each
+# group's run head, one value a group.
 OP_SCOPES = ("filter", "decode", "dict_gather", "group_index",
              "group_reduce", "group_keys", "join", "join_probe",
              "join_gather", "join_expand")

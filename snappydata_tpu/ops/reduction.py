@@ -26,6 +26,10 @@ strategy table and the fused kernels:
            serially (int64 sum + count over 100.66 M rows into 128
            slots: 9.06 s by scatter, 21 ms by the product; PERF.md
            section 6, PR 33)
+  runs     not a request but what `scatter` becomes where the executor
+           built its group index from sorted keys (generic keys): the
+           rows sorted by group index, each group reduced over its
+           contiguous run by a segmented scan (run_reduce)
 
 `agg_reduce_strategy` (config.py) picks one explicitly; `auto` keys on
 backend + G + S + N (see `resolve_strategy`).  Counts ride the float
@@ -50,7 +54,7 @@ Exactness contract per family:
 
 from __future__ import annotations
 
-from typing import Tuple
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -61,11 +65,13 @@ STRATEGIES = ("auto", "unroll", "scatter", "matmul")
 
 # Every name run_main (engine/executor.py) can put in a plan's
 # note["strategies"], so every `agg_strategy_<name>` counter there is:
-# what a packed family resolved to, plus the two lanes that take a slot
-# before it is packed (ops/code_agg.py). EXPLAIN ANALYZE and the stats
-# service report from this tuple.
+# what a packed family resolved to, `runs` where a generic-key family
+# that resolved to `scatter` reduced over its rows' runs instead
+# (run_reduce), plus the two lanes that take a slot before it is packed
+# (ops/code_agg.py). EXPLAIN ANALYZE and the stats service report from
+# this tuple.
 REPORTED_STRATEGIES = tuple(s for s in STRATEGIES if s != "auto") \
-    + ("dict_space", "rle_runs")
+    + ("runs", "dict_space", "rle_runs")
 
 # unroll's G-masked-reductions shape only ever wins in the small-G
 # dictionary regime; past this it degrades to scatter even if requested
@@ -370,6 +376,90 @@ def packed_minmax(kind: str, cols, gidx, num_segments: int,
     packed = _pack(cols)
     seg = jax.ops.segment_min if kind == "min" else jax.ops.segment_max
     return seg(packed, gidx, num_segments=num_segments)
+
+
+class Runs(NamedTuple):
+    """The rows in group order (run_reduce): group g is the run
+    rows[bounds[g]:bounds[g + 1]], and tails[k] is column k reduced
+    over each group's run."""
+    rows: jax.Array      # [N] int32 row numbers, sorted by group index
+    bounds: jax.Array    # [G + 1] int32 run starts; bounds[G]: the dump's
+    tails: tuple         # [G] a column, in its own dtype
+
+    @property
+    def counts(self):
+        """[G] int32 rows a group: its run's length."""
+        return self.bounds[1:] - self.bounds[:-1]
+
+
+_RUN_OPS = {"sum": jnp.add, "min": jnp.minimum, "max": jnp.maximum}
+
+
+def _identity(dtype, kind: str):
+    """What an empty group reads: 0 for a sum, the filler for min/max."""
+    if kind == "sum":
+        return jnp.zeros((), dtype)
+    return _extreme_of(dtype, kind == "min")
+
+
+def segmented_scan(head, cols, kinds):
+    """Inclusive scans of `cols` ([N] arrays) by `kinds` that restart
+    at every row where `head` is set: after the step of distance d a
+    row holds its run's reduction over the d rows up to it, so
+    ceil(log2 N) elementwise steps over shifted copies (Hillis and
+    Steele), each a contiguous slice the TPU's compiler lowers at once.
+    A row that starts its run keeps its own value whatever came before
+    it: a NaN or an Inf never leaves its run."""
+    def down(x, d, fill):
+        """x moved d rows on, its first d rows `fill`."""
+        return jnp.concatenate([jnp.full((d,), fill, x.dtype), x[:-d]])
+
+    ops = [_RUN_OPS[k] for k in kinds]
+    fills = [_identity(c.dtype, k) for c, k in zip(cols, kinds)]
+    cols = list(cols)
+    d = 1
+    while d < head.shape[0]:
+        cols = [jnp.where(head, x, op(down(x, d, fill), x))
+                for op, x, fill in zip(ops, cols, fills)]
+        head = head | down(head, d, False)
+        d *= 2
+    return cols
+
+
+@tracing.op_scope("group_reduce")
+def run_reduce(gidx, num_segments: int, cols, kinds):
+    """Segmented reductions of `cols` (list of [N] arrays, masked into
+    their identity as for the scatter) by group index, where each group
+    is reduced over its contiguous run of rows instead of scattered:
+    the TPU runs a scatter serially (87 ns a row for a float64 sum into
+    65,536 slots; PERF.md section 5), a sort and a scan it does not.
+
+    One sort keyed on `gidx` carries the row numbers and the columns
+    (a row of the dump segment, `num_segments`, sorts last); a segmented
+    scan over the sorted columns (segmented_scan) is read at each run's
+    tail.  A sum stays in its column's dtype (float64, or int64 wrapping
+    as `segment_sum` does); `kinds[k]` is "sum", "min" or "max", and an
+    empty group reads the identity (0, or the min/max filler the column
+    was masked with, as `segment_min`/`max` give).  The run starts are
+    found by a search of the `num_segments + 1` group ids over the
+    sorted index, unrolled: no row-sized loop."""
+    n = gidx.shape[0]
+    out = jax.lax.sort((gidx, jnp.arange(n, dtype=jnp.int32))
+                       + tuple(cols), num_keys=1)
+    sorted_gidx, rows, sorted_cols = out[0], out[1], out[2:]
+    bounds = jnp.searchsorted(
+        sorted_gidx, jnp.arange(num_segments + 1, dtype=sorted_gidx.dtype),
+        method="scan_unrolled").astype(jnp.int32)
+    tails = ()
+    if cols:
+        head = jnp.concatenate([jnp.ones((1,), jnp.bool_),
+                                sorted_gidx[1:] != sorted_gidx[:-1]])
+        scanned = segmented_scan(head, sorted_cols, kinds)
+        nonempty = bounds[1:] > bounds[:-1]
+        tail = jnp.maximum(bounds[1:] - 1, 0)
+        tails = tuple(jnp.where(nonempty, s[tail], _identity(s.dtype, k))
+                      for s, k in zip(scanned, kinds))
+    return Runs(rows, bounds, tails)
 
 
 def _extreme_of(dtype, positive: bool):

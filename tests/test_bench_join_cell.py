@@ -39,7 +39,7 @@ METRICS = ["q3_stmt_ms", "plan_ms.join", "bind_ms.join",
            "join_build_sorts.join", "host_fallbacks.join",
            "join_device_joins.join", "scatter_slots.join",
            "join_merge_probes.join", "join_search_loops.join",
-           "gidx_run_lanes.join"]
+           "gidx_run_lanes.join", "run_reduce_slots.join"]
 DRAWS = [("BUILDING", "1995-03-15"), ("MACHINERY", "1995-03-01"),
          ("HOUSEHOLD", "1995-03-31")]
 _EPOCH = datetime.date(1970, 1, 1)
@@ -319,7 +319,24 @@ def test_rehearsal_of_the_cell_is_correct(capsys, monkeypatch, man, trace):
     assert value["xla_compiles_in_window.join"] == 0
     assert value["join_build_sorts.join"] == 0
     assert value["join_device_joins.join"] == 2
-    assert value["scatter_slots.join"] == 1
+    assert value["scatter_slots.join"] == 0
+    assert value["gidx_run_lanes.join"] == 1
+    assert value["run_reduce_slots.join"] == 1
+
+
+def test_rehearsal_with_the_families_forced_to_scatter_reduces_over_runs(
+        capsys, monkeypatch, _restore_knobs):
+    """`agg_reduce_strategy` `scatter` on Q3's generic plan is the run
+    reduce, the lane the chip takes at SF 1: here end to end against the
+    NumPy reference, exact."""
+    monkeypatch.setattr(_restore_knobs, "agg_reduce_strategy", "scatter")
+    res = _rehearse(capsys, monkeypatch, trace=1, seed=3900000017)
+    assert res["correct"] is True and res["failed"] == 0
+    assert res["checks"]["sum_rel_gap"]["value"] == 0.0
+    assert res["checks"]["exact_mismatches"]["value"] == 0
+    value = {k: v["value"] for k, v in res["metrics"].items()}
+    assert value["scatter_slots.join"] == 0
+    assert value["run_reduce_slots.join"] == 1
     assert value["gidx_run_lanes.join"] == 1
 
 
@@ -437,9 +454,11 @@ def test_a_traced_q3_stays_on_the_device_and_says_so(traced_q3):
         assert attrs["join_device_joins"] == 2
         assert attrs["join_expand_out_rows"] == 0
         assert attrs["groups_overflow"] == 0
-        assert attrs["scatter_slots"] == 1 and attrs["dict_space_slots"] == 0
-        # Q3's three keys take the generic group index, by run heads
+        assert attrs["scatter_slots"] == 0 and attrs["dict_space_slots"] == 0
+        # Q3's three keys take the generic group index, by run heads, and
+        # its sum is reduced over the rows' runs, not scattered
         assert attrs["gidx_run_lane"] == 1
+        assert attrs["run_reduce_slots"] == 1
         # the generic group index: min(max_groups, padded rows) segments
         assert attrs["group_slots"] == 65536
         # both joins probe on the lineitem side's padded slots
@@ -528,9 +547,9 @@ def test_every_new_metric_reads_a_number_from_the_trace(
     expected = {
         "host_fallbacks.join": 0, "xla_compiles_in_window.join": 0,
         "join_build_sorts.join": 0, "join_device_joins.join": 2,
-        "scatter_slots.join": 1, "device_idle_pct.join": 20.0,
+        "scatter_slots.join": 0, "device_idle_pct.join": 20.0,
         "join_merge_probes.join": 2, "join_search_loops.join": 0,
-        "gidx_run_lanes.join": 1,
+        "gidx_run_lanes.join": 1, "run_reduce_slots.join": 1,
         "join_roofline": 100.0 * (2 * window[0]["rows_read"] * 26 / 819e9)
         / 2.0}
     if name in expected:
@@ -544,7 +563,8 @@ def test_every_new_metric_reads_a_number_from_the_trace(
     attr = {"join_device_joins.join": "join_device_joins",
             "join_merge_probes.join": "join_merge_probes",
             "join_search_loops.join": "join_search_loops",
-            "gidx_run_lanes.join": "gidx_run_lane"}.get(name)
+            "gidx_run_lanes.join": "gidx_run_lane",
+            "run_reduce_slots.join": "run_reduce_slots"}.get(name)
     if attr is not None:
         bare = json.loads(json.dumps(window))
         for r in bare:

@@ -498,8 +498,10 @@ def test_a_traced_statement_says_where_its_group_index_came_from(traced):
         assert attrs["reduce_padded_rows"] == batch_bucket(3) * BATCH_ROWS \
             == 3 * BATCH_ROWS > ROWS
         assert attrs["groups_overflow"] == 0
-        # a dictionary key: the group index is no run-head index
+        # a dictionary key: the group index is no run-head index, and no
+        # family reduces over runs
         assert attrs["gidx_run_lane"] == 0
+        assert attrs["run_reduce_slots"] == 0
     assert sorted(traced[0]["answer"]) == sorted(traced[2]["answer"])
 
 

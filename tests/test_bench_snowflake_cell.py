@@ -479,8 +479,10 @@ def test_a_traced_q5_says_what_its_five_joins_are(traced_q5,
             assert (attrs["join_search_loops"] == 0) == (merges == 5)
             assert attrs["join_expand_out_rows"] == 0
             assert attrs["groups_overflow"] == 0
-            # five nations grouped on their codes, not by run heads
+            # five nations grouped on their codes, not by run heads, and
+            # reduced without the runs
             assert attrs["gidx_run_lane"] == 0
+            assert attrs["run_reduce_slots"] == 0
             assert attrs["join_probe_rows"] == 5 * 131072
             # orders and customer (one padded batch each) and the row
             # tables' own 100, 25 and 5 rows

@@ -3,8 +3,9 @@ TPU compiler is installed here and compiles for a described v5e, so what
 it refuses, and what it would hold in HBM, is known before a chip run.
 Nothing runs on the chip: no result, no time (the plan cases run one
 statement over one batch on the CPU, to be handed the plan).  The join
-case compiles for about two minutes (four wide sorts) and Q3's group
-index for about 45 s (two); the rest take seconds.
+case compiles for about two minutes (four wide sorts), Q3's group
+index for about 45 s (two) and Q3's whole plan for about a minute; the
+rest take seconds.
 
 The topology is described inside a fixture, never at import or
 collection: only the worker that is handed this file loads the TPU's
@@ -314,3 +315,68 @@ def test_q3_group_index_at_sf1_is_two_sorts_and_no_loop(one_chip):
         assert op not in hlo, op
     assert hlo.count(" sort(") == 2
     assert comp.memory_analysis().temp_size_in_bytes <= 8 * n
+
+
+def test_q3_at_sf1_reduces_over_runs_with_no_scatter_and_no_loop(
+        monkeypatch, one_chip):
+    """Q3's plan as the chip builds it (the backend steered here, in the
+    test, through the lowering too: its probes merge and its families
+    resolve as on the chip), compiled for the described v5e with every
+    relation at SF 1's 48 batches: 6,291,456 slots, 65,536 group slots.
+    The float64 sum, the count and the three keys are read from the
+    rows' runs in group order, so no `scatter` is left (the parent held
+    five: the sum as a pair of float32, the count and three
+    `segment_max`), and no `while` (the run starts are a search of the
+    65,537 group ids, unrolled; the probes are merges).  The run
+    reduce's sort of the group index with its row numbers and the sum
+    is one `sort` beside the index's two and the merges'.  The control,
+    that a plan of the fast branch still scatters where asked, is
+    `test_quickstart_main_forced_to_scatter_is_two_scatters`."""
+    import jax
+    import jax.numpy as jnp
+
+    from snappydata_tpu import SnappySession, config
+    from snappydata_tpu.catalog import Catalog
+    from snappydata_tpu.engine.executor import CompiledPlan
+    from snappydata_tpu.utils import tpch
+
+    seen = []
+    orig = CompiledPlan._noted_call
+
+    def spy(self, static, phase, fn, args):
+        seen.append((phase, fn, args))
+        return orig(self, static, phase, fn, args)
+
+    def sds(a, dims):
+        return jax.ShapeDtypeStruct(tuple(dims), a.dtype, sharding=one_chip)
+
+    def scalar(a):
+        a = jnp.asarray(a)
+        return sds(a, a.shape)
+
+    props = config.global_properties()
+    saved = props.decimal_as_float64
+    try:
+        props.decimal_as_float64 = False
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(CompiledPlan, "_noted_call", spy)
+        s = SnappySession(catalog=Catalog())
+        tpch.load_tpch(s, sf=0.002, seed=11)
+        s.sql(tpch.Q3).rows()
+        s.stop()
+        (fn, args), = [(f, a) for ph, f, a in seen if ph == "single"]
+        comp = fn.lower(
+            jax.tree.map(lambda a: sds(a, (48,) + a.shape[1:]), args[0]),
+            jax.tree.map(scalar, args[1]),
+            jax.tree.map(scalar, args[2])).compile()
+    finally:
+        monkeypatch.undo()
+        props.decimal_as_float64 = saved
+    hlo = comp.as_text()
+    assert " scatter(" not in hlo
+    assert " while(" not in hlo
+    assert "/group_reduce/" in hlo and "/group_keys/" in hlo
+    # two merges of two sorts each, the index's two, the run reduce's one
+    assert hlo.count(" sort(") == 7
+    mem = comp.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9 / 5

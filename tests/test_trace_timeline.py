@@ -471,8 +471,9 @@ def test_main_dispatch_carries_slot_counts(_restore_knobs, label, lane,
             assert sp["name"] == expect_name
             assert (sp["attrs"]["dict_space_slots"],
                     sp["attrs"]["scatter_slots"]) == want
-            # Q1's two dictionary keys: no run-head group index
+            # Q1's two dictionary keys: no run-head group index, no runs
             assert sp["attrs"]["gidx_run_lane"] == 0
+            assert sp["attrs"]["run_reduce_slots"] == 0
         s.stop()
     finally:
         props.set("agg_on_codes", saved[0])
