@@ -64,11 +64,11 @@ def result_to_arrow(result, sel: Optional[np.ndarray] = None) -> pa.Table:
             try:
                 arrays.append(pa.array(cells, type=pt))
             except (pa.ArrowInvalid, pa.ArrowTypeError):
-                # the engine's int64-overflow fallback returns an
-                # APPROXIMATE float total that can be wider than the
-                # declared precision (decimal_sum_type caps at p=18) —
-                # widen the wire type rather than failing the export
-                # the local session happily answers (advisor round 5)
+                # a float-domain value (a float-plated decimal, or a
+                # host-evaluated column) can be wider than the declared
+                # precision — widen the wire type rather than failing
+                # the export the local session happily answers
+                # (advisor round 5)
                 try:
                     arrays.append(pa.array(
                         cells, type=pa.decimal128(38, dtype.scale)))

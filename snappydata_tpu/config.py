@@ -79,7 +79,8 @@ class Properties:
 
     # Execution
     decimal_as_float64: Optional[bool] = None  # None → auto (x64 iff CPU backend)
-    # Exact DECIMAL(p<=18): scaled-int64 device plates + int aggregation
+    # Exact DECIMAL, every precision up to 38: scaled-int64 device values
+    # + int aggregation at Spark 2.1's types, guarded past p = 18
     # (types.DecimalType docstring; ref ColumnEncoding.scala:137-140
     # readDecimal — real fixed-point semantics). OFF reverts decimals to
     # the float path everywhere.

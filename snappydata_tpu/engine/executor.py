@@ -47,9 +47,10 @@ import numpy as np
 from snappydata_tpu import config
 from snappydata_tpu import types as T
 from snappydata_tpu.engine import hosteval
-from snappydata_tpu.engine.exprs import (STRING_VALUE_FUNCS, CompileError,
-                                         DVal, ExprBuilder, Runtime,
-                                         _or_null)
+from snappydata_tpu.engine.exprs import (DECIMAL_OVERFLOW,
+                                         STRING_VALUE_FUNCS, CompileError,
+                                         DecimalAvg, DVal, ExprBuilder,
+                                         Runtime, _or_null)
 from snappydata_tpu.engine.result import Result, empty_result
 from snappydata_tpu.observability import tracing
 from snappydata_tpu.resource.context import check_current
@@ -115,10 +116,15 @@ class _RelationInput:
         from snappydata_tpu.storage.device import build_device_table
         from snappydata_tpu.storage.table_store import RowTableData
 
-        if isinstance(self.info.data, RowTableData):
-            return _row_table_device(self.info, self.used)
-        return build_device_table(self.info.data, None, self.used,
-                                  code_ok=self.allow_code)
+        try:
+            if isinstance(self.info.data, RowTableData):
+                return _row_table_device(self.info, self.used)
+            return build_device_table(self.info.data, None, self.used,
+                                      code_ok=self.allow_code)
+        except T.DecimalOverflow as e:
+            # a stored decimal too wide for its int64 plate: the exact
+            # host path reads it instead
+            raise CompileError(str(e)) from e
 
     def keep_mask(self, dt, params) -> Optional[np.ndarray]:
         """bool [B] of batches that can contain matches; None = keep all."""
@@ -320,7 +326,8 @@ class CompiledPlan:
                  tile_merge: Optional[Dict] = None,
                  kind: str = "plan",
                  join_notes: Optional[Dict] = None,
-                 decode_notes: Optional[Dict] = None):
+                 decode_notes: Optional[Dict] = None,
+                 decimal_notes: Optional[Dict] = None):
         self.relations = relations
         # what the plan IS (agg / global_agg / scan, join_ in front when
         # it joins): the stem of the names its jitted functions carry
@@ -343,6 +350,10 @@ class CompiledPlan:
         # trace-time notes of the plan's CodePlate decodes per (static
         # key, phase): site -> the form `dict_decode` emitted it in
         self.decode_notes = {} if decode_notes is None else decode_notes
+        # and, per (static key, phase), the shapes of the decimal
+        # expressions past int64's precision it guarded
+        self.decimal_notes = {} if decimal_notes is None \
+            else decimal_notes
         # partial-raw merge metadata: per-output merge ops + group-card
         # check for the tiled scan's on-device partial merge
         self.tile_merge = tile_merge
@@ -685,6 +696,15 @@ class CompiledPlan:
         forms = list(sites.values())
         sp.set("dict_select_plates", forms.count(DECODE_SELECT))
         sp.set("dict_gather_plates", forms.count(DECODE_GATHER))
+        # and its exact decimals: the expressions past int64's precision
+        # lowered to guarded int64 (distinct shapes, the most any one
+        # phase traced), and the SUM and AVG over a decimal reduced as
+        # exact scaled int64
+        sp.set("decimal_wide_exprs", max(
+            (len(self.decimal_notes.get((static, phase), ()))
+             for phase in phases), default=0))
+        sp.set("decimal_exact_slots",
+               note["decimal_exact_slots"] if note else 0)
         # 1 once the outputs are home and the overflow flag is set
         # (CompiledPlan.execute): the statement then reruns on the host
         sp.set("groups_overflow", 0)
@@ -734,12 +754,16 @@ class CompiledPlan:
         # the Result's assembly and whatever else the statement does
         # before it closes
         tracing.step("finish")
-        if bool(np.asarray(outs[2])):
+        flag = int(np.asarray(outs[2]))
+        if flag:
             dispatch.set("groups_overflow", 1)
+            if flag & DECIMAL_OVERFLOW:
+                raise CompileError(
+                    "decimal overflow: an exact decimal's scaled value "
+                    "could leave int64: exact host path")
             raise CompileError(
-                "device overflow (group-by cardinality beyond max_groups, "
-                "an exact-decimal sum at int64 risk, or a join expansion "
-                "past its bound): host path")
+                "device overflow (group-by cardinality beyond max_groups "
+                "or a join expansion past its bound): host path")
         return self._assemble(outs, tables)
 
     def execute_raw(self, params: Tuple):
@@ -1260,6 +1284,8 @@ class Compiler:
         # CodePlate decode was emitted in, by site
         # (CompiledPlan._note_slots)
         decode_notes: Dict[tuple, Dict[tuple, str]] = {}
+        # and the wide decimal expressions it guarded, by shape
+        decimal_notes: Dict[tuple, set] = {}
 
         def make_ctx(static, arrays, aux, params,
                      phase="single") -> "_TraceCtx":
@@ -1270,6 +1296,7 @@ class Compiler:
             # a fresh note each trace: a retrace under the same static
             # key (the plates re-bound decoded after a write) starts over
             decode_note = decode_notes[(static, phase)] = {}
+            decimal_wide = decimal_notes[(static, phase)] = set()
             # unpack per-relation arrays
             rel_runtimes = []
             pos = 0
@@ -1308,7 +1335,7 @@ class Compiler:
                     cols[ci] = dv
                 rel_runtimes.append((cols, valid))
             return _TraceCtx(rel_runtimes, aux, params, static,
-                             decode_note)
+                             decode_note, decimal_wide)
 
         join_notes = {} if n_rel > 1 else None
 
@@ -1344,6 +1371,7 @@ class Compiler:
                           tile_merge=getattr(self, "_tile_merge", None),
                           join_notes=join_notes,
                           decode_notes=decode_notes,
+                          decimal_notes=decimal_notes,
                           kind=("join_" if n_rel > 1 else "")
                           + (("agg" if plan.group_exprs else "global_agg")
                              if is_agg else "scan")
@@ -1514,7 +1542,8 @@ class Compiler:
             flatmask = valid2.reshape(-1)
             n = int(flatmask.shape[0])
             idx = jnp.arange(n)
-            rt = Runtime(out.cols, ctx.params, ctx.aux_slice(builder))
+            rt = Runtime(out.cols, ctx.params, ctx.aux_slice(builder),
+                         ctx, out.valid)
 
             def flat(dv: DVal):
                 v = _broadcast_to_mask(dv.value, valid2).reshape(-1)
@@ -1672,7 +1701,7 @@ class Compiler:
             for i, dv in enumerate(win_vals):
                 ext_cols[len(scope) + i] = dv
             rt2 = Runtime(ext_cols, ctx.params,
-                          ctx.aux_slice(ext_builder))
+                          ctx.aux_slice(ext_builder), ctx)
             pairs = []
             for r in out_runs:
                 dv = r(rt2)
@@ -1744,7 +1773,8 @@ class Compiler:
 
             def run_filter(ctx) -> RelOut:
                 out = child(ctx)
-                rt = Runtime(out.cols, ctx.params, ctx.aux_slice(builder))
+                rt = Runtime(out.cols, ctx.params,
+                             ctx.aux_slice(builder), ctx, out.valid)
                 with tracing.op_scope("filter"):
                     p = pred(rt)
                     keep = p.value
@@ -1776,7 +1806,8 @@ class Compiler:
 
             def run_project(ctx) -> RelOut:
                 out = child(ctx)
-                rt = Runtime(out.cols, ctx.params, ctx.aux_slice(builder))
+                rt = Runtime(out.cols, ctx.params,
+                             ctx.aux_slice(builder), ctx, out.valid)
                 cols = {}
                 for i, r in enumerate(runs):
                     dv = r(rt)
@@ -2427,7 +2458,8 @@ class Compiler:
                     valid = jnp.concatenate([valid, ext_valid])
                 out = RelOut(cols, valid)
             if residual_run is not None:
-                rt = Runtime(out.cols, ctx.params, ctx.aux_slice(builder))
+                rt = Runtime(out.cols, ctx.params,
+                             ctx.aux_slice(builder), ctx, out.valid)
                 p = residual_run(rt)
                 keep = p.value
                 if p.null is not None:
@@ -2504,9 +2536,19 @@ class Compiler:
             slots.append(key)
             return len(slots) - 1
 
+        # the SUM and AVG calls over an exact decimal: reduced as exact
+        # scaled int64 (the `decimal_exact_slots` attr)
+        exact_decimal_calls = set()
+
+        def exact_decimal(arg) -> bool:
+            at = expr_type(arg) if arg is not None else None
+            return isinstance(at, T.DecimalType) and at.is_exact
+
         def rewrite(e: ast.Expr) -> ast.Expr:
             if isinstance(e, ast.Func) and e.name in ast.AGG_FUNCS:
                 arg = e.args[0] if e.args else None
+                if e.name in ("sum", "avg") and exact_decimal(arg):
+                    exact_decimal_calls.add((e.name, arg))
                 if e.name == "count":
                     return _SlotRef(slot_of("count", arg), T.LONG)
                 if e.name in ("count_distinct", "approx_count_distinct"):
@@ -2519,13 +2561,16 @@ class Compiler:
                 if e.name == "avg":
                     # the sum slot may be shared with an explicit
                     # sum(x): for exact decimals it holds scaled int64,
-                    # so the slot ref must carry the decimal type — the
-                    # division then unscales (avg = exact sum / count)
+                    # so the slot ref carries the decimal type, and the
+                    # average is the exact sum over the exact count
+                    # rounded HALF_UP at Spark's DECIMAL(p+4, s+4)
                     at = expr_type(arg) if arg is not None else T.DOUBLE
                     st = T.decimal_sum_type(at) if at.name == "decimal" \
                         else T.DOUBLE
                     s = _SlotRef(slot_of("sum", arg), st)
                     c = _SlotRef(slot_of("count", arg), T.LONG)
+                    if exact_decimal(arg):
+                        return DecimalAvg(s, c, T.decimal_avg_type(at))
                     return ast.BinOp("/", s, c)
                 if e.name in ("stddev", "variance"):
                     if arg is not None \
@@ -2829,7 +2874,8 @@ class Compiler:
             from snappydata_tpu.ops import reduction
 
             out = child(ctx)
-            rt = Runtime(out.cols, ctx.params, ctx.aux_slice(builder))
+            rt = Runtime(out.cols, ctx.params, ctx.aux_slice(builder),
+                         ctx, out.valid)
             valid = out.valid.reshape(-1)
             gidx, overflow = compute_pre(ctx, rt, out, valid)
             n = valid.shape[0]
@@ -2848,13 +2894,15 @@ class Compiler:
                 # the overflow segment is never consumed downstream
                 onehot = reduction.make_onehot(gidx, num_groups,
                                                jnp.float64)
-            return valid, gidx, onehot, overflow
+            # a wide decimal's bound checks in the filter or the keys
+            return valid, gidx, onehot, overflow | ctx.overflow
 
         def run_main(ctx, pre=None) -> tuple:
             from snappydata_tpu.ops import code_agg, reduction
 
             out = child(ctx)
-            rt = Runtime(out.cols, ctx.params, ctx.aux_slice(builder))
+            rt = Runtime(out.cols, ctx.params, ctx.aux_slice(builder),
+                         ctx, out.valid)
             if pre is None:
                 valid = out.valid.reshape(-1)
                 gidx, overflow = compute_pre(ctx, rt, out, valid)
@@ -3223,9 +3271,10 @@ class Compiler:
                 absmax = guard_res[g["absmax"]]
                 cnt_w = count_res[:, g["cnt"]]
                 tscale = jnp.asarray(ctx.aux[tile_scale_aux], jnp.float64)
-                overflow = overflow | jnp.any(
+                overflow = overflow | jnp.where(jnp.any(
                     absmax.astype(jnp.float64)
-                    * cnt_w.astype(jnp.float64) * tscale >= 2.0 ** 62)
+                    * cnt_w.astype(jnp.float64) * tscale >= 2.0 ** 62),
+                    jnp.int32(DECIMAL_OVERFLOW), jnp.int32(0))
 
             counts = count_res[:, gvalid_col]
             if groups:
@@ -3290,7 +3339,8 @@ class Compiler:
                     arr[:num_groups], None, slot_dtypes[si])
             post_rt = Runtime({**post_cols, **slot_cols}, ctx.params,
                               ctx.aux_range(post_aux_off,
-                                            len(post_builder.aux_builders)))
+                                            len(post_builder.aux_builders)),
+                              ctx)
             pairs = []
             for run, dt in zip(post_runs, out_types):
                 dv = run(post_rt)
@@ -3305,6 +3355,7 @@ class Compiler:
                 "isum_scatter_slots": note["isum_scatter_slots"],
                 "limb_matmul_slots": note["limb_matmul_slots"],
                 "run_reduce_slots": note["run_reduce_slots"],
+                "decimal_exact_slots": len(exact_decimal_calls),
                 # compute_pre's generic branch: the index by run heads
                 "gidx_run_lane": int(bool(groups) and not fast),
                 "group_slots": num_groups,
@@ -3459,7 +3510,8 @@ _JOIN_NOTE_KEYS = ("join_device_joins", "join_probe_rows",
 
 
 class _TraceCtx:
-    def __init__(self, rels, aux, params, static, decode_note):
+    def __init__(self, rels, aux, params, static, decode_note,
+                 decimal_wide=None):
         self.rels = rels
         self.aux = aux
         self.params = params
@@ -3474,6 +3526,9 @@ class _TraceCtx:
         self.overflow = jnp.asarray(False)
         # and what the joins were (CompiledPlan._note_slots)
         self.join_note = dict.fromkeys(_JOIN_NOTE_KEYS, 0)
+        # the shapes of the decimal expressions past int64's precision
+        # lowered under the guard (exprs._dec_guard)
+        self.decimal_wide = set() if decimal_wide is None else decimal_wide
 
     def aux_slice(self, builder) -> List:
         off = getattr(builder, "_aux_offset", 0)
@@ -3583,13 +3638,14 @@ def _acc_dtype(dt: Optional[T.DataType], value_dtype=None):
     f64: summing ~1e8 values of magnitude 1e4 into 1e10 group totals in
     f32 leaves ~3 trustworthy digits (round-3 verdict), while
     f32-rounded inputs accumulated in f64 keep relative error ≤1e-6.
-    DECIMAL with scaled-int64 plates (the exact path, p≤18) accumulates
-    in int64 — EXACT, matching the reference's BigDecimal contract
+    DECIMAL with scaled-int64 values (the exact path, any precision)
+    accumulates in int64 under the sum's overflow guard — EXACT,
+    matching the reference's BigDecimal contract
     (encoders/.../encoding/ColumnEncoding.scala:137-140 readDecimal)
     with native int ops instead of emulated f64; float-domain decimals
-    (p>18) keep the f64 accumulator. XLA emulates f64 adds on TPU;
-    reductions are bandwidth-bound, so the extra ALU cost does not move
-    the bottleneck."""
+    (`decimal_exact` off) keep the f64 accumulator. XLA emulates f64
+    adds on TPU; reductions are bandwidth-bound, so the extra ALU cost
+    does not move the bottleneck."""
     if dt is not None and dt.name == "decimal":
         if value_dtype is not None \
                 and jnp.issubdtype(value_dtype, jnp.integer):

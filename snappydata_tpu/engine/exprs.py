@@ -181,11 +181,76 @@ def _or_null(a, b):
 # Exact decimals: a DVal whose dtype is an exact DecimalType carries the
 # SCALED int64 unscaled value (types.DecimalType docstring). The binop /
 # cast emitters below keep +,-,*,%, comparisons and casts in the exact
-# integer domain when the result precision fits int64, and unscale to
-# float64 otherwise. Every other consumer (math funcs, division, IN
-# tables, mixed CASE branches) receives the PLAIN float domain via
-# _dec_unscale — scaled ints must never leak into value-blind float math.
+# integer domain at Spark 2.1's result types for every precision up to
+# 38. A result past types.DECIMAL_INT64_PRECISION stays int64 too: each
+# product, rescale and alignment that could leave int64 ORs a bound
+# check (`_dec_guard`, a float32 magnitude test against 2^62) into the
+# statement's overflow flag, and the executor reruns a flagged statement
+# exactly on the host. Division, math funcs, IN tables and mixed CASE
+# branches receive the PLAIN float domain via _dec_unscale — scaled
+# ints must never leak into value-blind float math.
 # ---------------------------------------------------------------------------
+
+# the overflow flag's bit for a decimal whose scaled value could leave
+# int64 (the executor names it in the host fallback's reason)
+DECIMAL_OVERFLOW = 2
+_GUARD_BOUND = float(2 ** 62)
+
+
+@dataclasses.dataclass(frozen=True)
+class DecimalAvg(ast.Expr):
+    """avg over an exact decimal once reduced: the exact sum over the
+    exact count, rounded HALF_UP at the average's scale (`dtype`, Spark
+    2.1's DECIMAL(p + 4, s + 4)); NULL over no rows."""
+
+    sum: ast.Expr = None
+    count: ast.Expr = None
+    dtype: T.DataType = None
+
+    def children(self):
+        return (self.sum, self.count)
+
+    def map_children(self, fn):
+        return DecimalAvg(fn(self.sum), fn(self.count), self.dtype)
+
+
+def _f32_abs(v):
+    """|v| of int64 values in float32: within 2^-23 of the true
+    magnitude, which is all a bound test against 2^62 needs to be sound
+    for int64's 2^63 (and int64's minimum has no int64 absolute)."""
+    return jnp.abs(jnp.asarray(v).astype(jnp.float32))
+
+
+def _dec_guard(rt: "Runtime", key, risky) -> None:
+    """OR one bound check over the rows in play into the statement's
+    overflow flag, under its DECIMAL_OVERFLOW bit, and note `key` (a
+    wide expression's shape; None for one not counted) for the
+    `decimal_wide_exprs` attr."""
+    sink = rt.sink
+    if sink is None:
+        raise CompileError("a decimal past int64's precision outside a "
+                           "guarded region: host path")
+    if key is not None:
+        sink.decimal_wide.add(key)
+    if risky is None:
+        return
+    if rt.valid is not None and jnp.ndim(risky) == jnp.ndim(rt.valid):
+        risky = risky & rt.valid
+    sink.overflow = sink.overflow | jnp.where(
+        jnp.any(risky), jnp.int32(DECIMAL_OVERFLOW), jnp.int32(0))
+
+
+def _shape_key(e: ast.Expr):
+    """A wide expression as its trace sees it: tokenized literals by
+    their type alone, so two copies of one subexpression (each `1` its
+    own slot) count once."""
+    def strip(x):
+        if isinstance(x, (ast.ParamLiteral, ast.Param)):
+            return ast.Lit("?", x.dtype)
+        return x.map_children(strip)
+
+    return strip(e)
+
 
 def _dec_scale(d: DVal) -> Optional[int]:
     """Scale when d is an exact scaled-int decimal DVal, else None."""
@@ -223,7 +288,8 @@ def _dec_wrap_unscaled(run: Callable[["Runtime"], DVal]
 
 def _dec_rescale_int(value, from_scale: int, to_scale: int):
     """Scaled int64 -> scaled int64 at another scale, rounding half away
-    from zero on downscale (Spark/java BigDecimal HALF_UP)."""
+    from zero on downscale (Spark/java BigDecimal HALF_UP). An upscale
+    past int64 wraps: callers guard it (`_dec_aligned`)."""
     if to_scale == from_scale:
         return value
     if to_scale > from_scale:
@@ -231,6 +297,22 @@ def _dec_rescale_int(value, from_scale: int, to_scale: int):
     f = 10 ** (from_scale - to_scale)
     av = jnp.abs(value)
     return jnp.sign(value) * ((av + f // 2) // f)
+
+
+def _dec_aligned(value, dt: T.DecimalType, to_scale: int, summed: bool):
+    """(value at `to_scale`, its bound check or None). The check is None
+    where the operand's type keeps the aligned value inside int64; a
+    `summed` operand of a result past int64's precision is held under
+    2^62 even unscaled, so that the sum of two stays inside."""
+    k = max(to_scale - dt.scale, 0)
+    out = _dec_rescale_int(value, dt.scale, to_scale)
+    if dt.precision + k <= T.DECIMAL_INT64_PRECISION or not (k or summed):
+        return out, None
+    return out, _f32_abs(value) * np.float32(10.0 ** k) >= _GUARD_BOUND
+
+
+def _or_risk(a, b):
+    return a if b is None else b if a is None else a | b
 
 
 def _as_dec_operand(d: DVal):
@@ -294,13 +376,15 @@ _FLIP_CMP = {"<": ">", "<=": ">=", ">": "<", ">=": "<=",
              "=": "=", "!=": "!="}
 
 
-def _dec_binop(op: str, fn, a: DVal, b: DVal, is_cmp: bool
-               ) -> Optional[DVal]:
+def _dec_binop(op: str, fn, a: DVal, b: DVal, is_cmp: bool,
+               rt: "Runtime", key) -> Optional[DVal]:
     """Exact integer-domain lowering of a binop with >= 1 decimal side.
     None -> the caller unscales both sides and runs plain float math.
     Scale/precision rules shared with the analyzer via
     types.decimal_binop_type, so declared output scale always equals
-    the computed representation's."""
+    the computed representation's. A result past int64's precision, or
+    an alignment that could leave int64, is guarded through `rt`'s
+    overflow sink; `key` names a wide result for the counter."""
     av, adt = _as_dec_operand(a)
     bv, bdt = _as_dec_operand(b)
     if av is None or bv is None:
@@ -321,21 +405,28 @@ def _dec_binop(op: str, fn, a: DVal, b: DVal, is_cmp: bool
     null = _or_null(a.null, b.null)
     if is_cmp:
         s = max(adt.scale, bdt.scale)
-        if max(adt.precision + (s - adt.scale),
-               bdt.precision + (s - bdt.scale)) \
-                > T.DECIMAL_EXACT_MAX_PRECISION:
-            return None  # alignment could overflow int64: f64 compare
-        va = _dec_rescale_int(av, adt.scale, s)
-        vb = _dec_rescale_int(bv, bdt.scale, s)
+        va, ra = _dec_aligned(av, adt, s, summed=False)
+        vb, rb = _dec_aligned(bv, bdt, s, summed=False)
+        risky = _or_risk(ra, rb)
+        if risky is not None:
+            _dec_guard(rt, None, risky)
         return DVal(fn(va, vb), null, T.BOOLEAN)
     out_dt = T.decimal_binop_type(op, adt, bdt)
     if not isinstance(out_dt, T.DecimalType) or not out_dt.is_exact:
         return None
+    wide = out_dt.precision > T.DECIMAL_INT64_PRECISION
     if op == "*":
         # scales add under int multiply: result is already at out_dt.scale
+        if wide:
+            _dec_guard(rt, key,
+                       _f32_abs(av) * _f32_abs(bv) >= _GUARD_BOUND)
         return DVal(av * bv, null, out_dt)
-    va = _dec_rescale_int(av, adt.scale, out_dt.scale)
-    vb = _dec_rescale_int(bv, bdt.scale, out_dt.scale)
+    summed = wide and op in ("+", "-")
+    va, ra = _dec_aligned(av, adt, out_dt.scale, summed)
+    vb, rb = _dec_aligned(bv, bdt, out_dt.scale, summed)
+    risky = _or_risk(ra, rb)
+    if wide or risky is not None:
+        _dec_guard(rt, key if wide else None, risky)
     return DVal(fn(va, vb), null, out_dt)
 
 
@@ -343,10 +434,16 @@ class Runtime:
     """Runtime arrays handed to emitted closures inside the trace."""
 
     def __init__(self, cols: Dict[int, DVal], params: Sequence,
-                 aux: Sequence):
+                 aux: Sequence, sink=None, valid=None):
         self.cols = cols
         self.params = params  # traced scalars, one per tokenized literal
         self.aux = aux        # traced aux arrays, in registration order
+        # the trace context whose overflow flag a wide decimal's bound
+        # check joins (`_dec_guard`); None where nothing reads one
+        self.sink = sink
+        # the rows in play (a filter's survivors): a bound check reads
+        # only these; None checks every slot
+        self.valid = valid
 
 
 class ExprBuilder:
@@ -433,6 +530,9 @@ class ExprBuilder:
 
         if isinstance(e, ast.Lit):
             return self._emit_literal(e.value, e.dtype)
+
+        if isinstance(e, DecimalAvg):
+            return self._emit_decimal_avg(e)
 
         if isinstance(e, (ast.ParamLiteral, ast.Param)):
             pos, dtype = e.pos, e.dtype
@@ -534,8 +634,12 @@ class ExprBuilder:
 
             q = _d.Decimal(value if isinstance(value, (_d.Decimal, int))
                            else repr(float(value)))
-            const = np.asarray(int(q.scaleb(eff.scale).to_integral_value(
-                rounding=_d.ROUND_HALF_UP)), dtype=np.int64)
+            scaled = int(q.scaleb(eff.scale).to_integral_value(
+                rounding=_d.ROUND_HALF_UP))
+            if abs(scaled) >= 2 ** 63:
+                raise CompileError(f"a {eff} literal leaves int64 once "
+                                   f"scaled: host path")
+            const = np.asarray(scaled, dtype=np.int64)
         else:
             const = np.asarray(value, dtype=eff.device_dtype())
 
@@ -740,6 +844,7 @@ class ExprBuilder:
             return run_div
 
         fn = fns[op]
+        key = None if is_cmp else _shape_key(e)
 
         def run_bin(rt: Runtime) -> DVal:
             a, b = left(rt), right(rt)
@@ -752,7 +857,7 @@ class ExprBuilder:
                 if cm is not None:
                     return cm
             if _dec_scale(a) is not None or _dec_scale(b) is not None:
-                out = _dec_binop(op, fn, a, b, is_cmp)
+                out = _dec_binop(op, fn, a, b, is_cmp, rt, key)
                 if out is not None:
                     return out
                 # result leaves the exact domain (float operand, or the
@@ -979,6 +1084,28 @@ class ExprBuilder:
 
         return run_case
 
+    def _emit_decimal_avg(self, e: DecimalAvg) -> Callable[[Runtime], DVal]:
+        """The sum at its scale s, brought to the average's scale t by
+        10^(t - s) under the guard (so that 2|n| stays inside int64),
+        then n / count rounded half away from zero: sign(n) *
+        ((2|n| + c) // 2c)."""
+        srun, crun, dt = self.emit(e.sum), self.emit(e.count), e.dtype
+
+        def run_avg(rt: Runtime) -> DVal:
+            sv, cv = srun(rt), crun(rt)
+            up = 10 ** (dt.scale - sv.dtype.scale)
+            n = sv.value.astype(jnp.int64)
+            _dec_guard(rt, None, _f32_abs(n) * np.float32(2 * up)
+                       >= _GUARD_BOUND)
+            n = n * up
+            c = cv.value.astype(jnp.int64)
+            empty = c == 0
+            c = jnp.where(empty, 1, c)
+            q = jnp.sign(n) * ((2 * jnp.abs(n) + c) // (2 * c))
+            return DVal(q, _or_null(_or_null(sv.null, cv.null), empty), dt)
+
+        return run_avg
+
     def _emit_cast(self, e: ast.Cast) -> Callable[[Runtime], DVal]:
         child = self.emit(e.child)
         to = e.to
@@ -986,15 +1113,19 @@ class ExprBuilder:
             raise CompileError("CAST to string not supported on device")
         np_dt = to.device_dtype()
         to_exact = to.name == "decimal" and getattr(to, "is_exact", False)
+        # a target past int64's precision: the scaled value is guarded
+        to_wide = to_exact and to.precision > T.DECIMAL_INT64_PRECISION
 
         def run_cast(rt: Runtime) -> DVal:
             c = child(rt)
             s_from = _dec_scale(c)
             if s_from is not None:
                 if to_exact:  # decimal -> decimal: integer rescale
-                    return DVal(_dec_rescale_int(
-                        c.value.astype(jnp.int64), s_from, to.scale),
-                        c.null, to)
+                    v, risky = _dec_aligned(c.value.astype(jnp.int64),
+                                            c.dtype, to.scale, False)
+                    if risky is not None:
+                        _dec_guard(rt, None, risky)
+                    return DVal(v, c.null, to)
                 if T.is_integral(to):
                     # decimal -> int truncates toward zero (Spark), done
                     # exactly in the int domain
@@ -1005,14 +1136,19 @@ class ExprBuilder:
                 c = _dec_unscale(c)
             if to_exact:
                 v = c.value
+                f = 10 ** to.scale
                 if jnp.issubdtype(jnp.asarray(v).dtype, jnp.integer):
-                    return DVal(v.astype(jnp.int64) * (10 ** to.scale),
-                                c.null, to)
+                    if to_wide:
+                        _dec_guard(rt, None, _f32_abs(v) * np.float32(f)
+                                   >= _GUARD_BOUND)
+                    return DVal(v.astype(jnp.int64) * f, c.null, to)
                 # HALF_UP (half away from zero), matching
                 # decimal_to_unscaled / _dec_rescale_int — jnp.round
                 # would tie to even
-                vf = v.astype(jnp.float64) * (10 ** to.scale)
+                vf = v.astype(jnp.float64) * f
                 scaled = jnp.sign(vf) * jnp.floor(jnp.abs(vf) + 0.5)
+                if to_wide:
+                    _dec_guard(rt, None, jnp.abs(vf) >= _GUARD_BOUND)
                 return DVal(scaled.astype(jnp.int64), c.null, to)
             return DVal(c.value.astype(np_dt), c.null, to)
 

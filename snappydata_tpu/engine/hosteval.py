@@ -628,6 +628,147 @@ def _or_null(a, b):
 # Result-level ops
 # --------------------------------------------------------------------------
 
+# --------------------------------------------------------------------------
+# Exact decimals on the host: the rerun of a statement whose device
+# guard saw a scaled value that could leave int64 (exprs._dec_guard).
+# Values are scaled integers at the expression's Spark type: int64 up
+# to DECIMAL_INT64_PRECISION, Python ints in an object array past it,
+# so no product, sum or average rounds or wraps.
+# --------------------------------------------------------------------------
+
+def _exact_decimal_type(dt) -> bool:
+    return isinstance(dt, T.DecimalType) and dt.is_exact
+
+
+def _scaled_ints(v, scale: int, wide: bool) -> np.ndarray:
+    """Host values (the float64 mirror, integers, or Decimal objects) as
+    integers scaled by 10^scale, rounded HALF_UP as the device's plates
+    are: int64, or Python ints in an object array where `wide`."""
+    import decimal as _d
+
+    a = np.asarray(v)
+    if a.dtype != object and a.dtype.kind in "iub":
+        out = a.astype(np.int64)
+        if wide:
+            out = out.astype(object)
+        return out * (10 ** scale)
+    if a.dtype != object:
+        f = a.astype(np.float64) * float(10 ** scale)
+        r = np.floor(np.abs(f) + 0.5)
+        if not r.size or np.nanmax(r) < 2.0 ** 53:
+            out = (np.sign(f) * r).astype(np.int64)
+            return out.astype(object) if wide else out
+    out = np.empty(a.shape, dtype=object)
+    for i, x in enumerate(a.ravel()):
+        q = _d.Decimal(0) if x is None or x != x else _d.Decimal(
+            x if isinstance(x, (_d.Decimal, int, np.integer))
+            else repr(float(x)))
+        out.flat[i] = int(q.scaleb(scale).to_integral_value(
+            rounding=_d.ROUND_HALF_UP))
+    return out if wide else out.astype(np.int64)
+
+
+def eval_exact_decimal(e: ast.Expr, cols, nulls, params, n):
+    """(scaled integers at expr_type(e)'s scale, null mask) for an exact
+    DECIMAL expression: +, -, *, %, negation and decimal casts in
+    integers, anything else through eval_expr and scaled once."""
+    dt = expr_type(e)
+    wide = dt.precision > T.DECIMAL_INT64_PRECISION
+    if isinstance(e, ast.Alias):
+        return eval_exact_decimal(e.child, cols, nulls, params, n)
+    if isinstance(e, ast.BinOp) and e.op in ("+", "-", "*", "%"):
+        sides = [_exact_operand(x, cols, nulls, params, n, wide)
+                 for x in (e.left, e.right)]
+        if all(sd is not None for sd in sides):
+            (a, sa, an), (b, sb, bn) = sides
+            if e.op == "*" and sa + sb == dt.scale:
+                return a * b, _or_null(an, bn)
+            if e.op != "*":
+                a = a * (10 ** (dt.scale - sa))
+                b = b * (10 ** (dt.scale - sb))
+                v = {"+": lambda: a + b, "-": lambda: a - b,
+                     "%": lambda: a % np.where(b == 0, 1, b)}[e.op]()
+                nl = _or_null(an, bn)
+                if e.op == "%":
+                    nl = _or_null(nl, np.broadcast_to(b == 0, (n,)))
+                return v, nl
+    if isinstance(e, ast.UnaryOp) and e.op == "-":
+        v, nl = eval_exact_decimal(e.child, cols, nulls, params, n)
+        return -v, nl
+    if isinstance(e, ast.Cast) and _exact_decimal_type(
+            expr_type(e.child)):
+        v, nl = eval_exact_decimal(e.child, cols, nulls, params, n)
+        k = dt.scale - expr_type(e.child).scale
+        if k >= 0:
+            return v * (10 ** k), nl
+        f = 10 ** -k
+        v = np.asarray(v, dtype=object)
+        return np.array([(1 if x >= 0 else -1) * ((abs(x) + f // 2) // f)
+                         for x in v.ravel()], dtype=object) \
+            .reshape(v.shape), nl
+    v, nl = eval_expr(e, cols, nulls, params, n)
+    return _scaled_ints(np.broadcast_to(v, (n,)), dt.scale, wide), nl
+
+
+def _exact_operand(x, cols, nulls, params, n, wide: bool):
+    """(scaled integers, scale, nulls) of a decimal or integer operand,
+    None for a float one."""
+    xt = expr_type(x)
+    if _exact_decimal_type(xt):
+        v, nl = eval_exact_decimal(x, cols, nulls, params, n)
+        scale = xt.scale
+    elif xt.name in T._INT_DIGITS:
+        v, nl = eval_expr(x, cols, nulls, params, n)
+        v, scale = _scaled_ints(v, 0, False), 0
+    else:
+        return None
+    v = np.broadcast_to(np.asarray(v), (n,))
+    return (v.astype(object) if wide else v), scale, nl
+
+
+def _exact_sum(v) -> int:
+    """The exact sum of scaled integers as a Python int: int64 in two
+    32-bit halves (neither half's sum can wrap below 2^31 rows)."""
+    a = np.asarray(v)
+    if a.dtype == object:
+        return int(sum(a.tolist()))
+    a = a.astype(np.int64)
+    return (int(np.sum(a >> 32)) << 32) + int(np.sum(a & 0xFFFFFFFF))
+
+
+def _decimal_objects(v, dt) -> np.ndarray:
+    """Scaled integers -> decimal.Decimal objects of type `dt`."""
+    return np.array([T.unscaled_to_python(dt, x)
+                     for x in np.asarray(v).ravel()], dtype=object)
+
+
+def _as_decimal(dt, v):
+    import decimal as _d
+
+    return v if isinstance(v, _d.Decimal) \
+        else T.float_to_python_decimal(dt, v)
+
+
+def _plain_number(v):
+    import decimal as _d
+
+    return float(v) if isinstance(v, _d.Decimal) else v
+
+
+def _exact_decimal_agg(name: str, arg, v) -> object:
+    """SUM or AVG over the scaled integers `v` of an exact decimal
+    `arg`: Decimal at Spark 2.1's type, the average rounded HALF_UP;
+    NULL past the type's precision, as Spark's CheckOverflow gives."""
+    at = expr_type(arg)
+    total = _exact_sum(v)
+    if name == "sum":
+        return T.unscaled_to_python(T.decimal_sum_type(at), total)
+    t = T.decimal_avg_type(at)
+    num, c = total * 10 ** (t.scale - at.scale), len(v)
+    return T.unscaled_to_python(
+        t, (1 if num >= 0 else -1) * ((2 * abs(num) + c) // (2 * c)))
+
+
 from snappydata_tpu.engine.result import Result  # noqa: E402
 from snappydata_tpu.engine.result import \
     unscale_decimal_col as _unscale_decimal_col  # noqa: E402
@@ -1123,8 +1264,36 @@ def eval_plan(plan: ast.Plan, params, executor) -> Result:
     from snappydata_tpu.resource.context import check_current
 
     check_current()  # host fallback entry = cancellation point
+    if isinstance(plan, ast.Project):
+        # the statement's own outputs: an exact decimal computed in
+        # integers, as the device computes it
+        cols, nulls, names, dtypes, n = _eval_rel(plan.child, params,
+                                                  executor)
+        cols, nulls, names, dtypes, _n = _project(
+            plan.exprs, cols, nulls, params, n, exact=True)
+        return Result(names, cols, nulls, dtypes)
     cols, nulls, names, dtypes, n = _eval_rel(plan, params, executor)
     return Result(names, cols, nulls, dtypes)
+
+
+def _project(exprs, cols, nulls, params, n, exact: bool = False):
+    """(names, cols, nulls, dtypes, n) of a projection; with `exact`,
+    exact-decimal arithmetic as Decimal objects."""
+    out_c, out_n, out_names, out_t = [], [], [], []
+    for e in exprs:
+        dt = expr_type(e)
+        base = e.child if isinstance(e, ast.Alias) else e
+        if exact and _exact_decimal_type(dt) \
+                and not isinstance(base, ast.Col):
+            v, nl = eval_exact_decimal(e, cols, nulls, params, n)
+            v = _decimal_objects(np.broadcast_to(v, (n,)), dt)
+        else:
+            v, nl = eval_expr(e, cols, nulls, params, n)
+        out_c.append(np.broadcast_to(v, (n,)))
+        out_n.append(np.broadcast_to(nl, (n,)) if nl is not None else None)
+        out_names.append(_expr_name(e))
+        out_t.append(dt)
+    return out_c, out_n, out_names, out_t, n
 
 
 def _eval_rel(plan: ast.Plan, params, executor):
@@ -1199,14 +1368,7 @@ def _eval_rel(plan: ast.Plan, params, executor):
 
     if isinstance(plan, ast.Project):
         cols, nulls, names, dtypes, n = _eval_rel(plan.child, params, executor)
-        out_c, out_n, out_names, out_t = [], [], [], []
-        for e in plan.exprs:
-            v, nl = eval_expr(e, cols, nulls, params, n)
-            out_c.append(np.broadcast_to(v, (n,)))
-            out_n.append(np.broadcast_to(nl, (n,)) if nl is not None else None)
-            out_names.append(_expr_name(e))
-            out_t.append(expr_type(e))
-        return out_c, out_n, out_names, out_t, n
+        return _project(plan.exprs, cols, nulls, params, n)
 
     if isinstance(plan, ast.Join):
         return _eval_join(plan, params, executor)
@@ -1418,7 +1580,12 @@ def _eval_aggregate(plan: ast.Aggregate, params, executor):
             nmask.append(v is None)
             vals.append(v)
         dt = out_types[-1]
-        if dt.name in ("string", "array", "map"):
+        if _exact_decimal_type(dt):
+            # exact decimals leave as Decimal objects (finalize_decimals
+            # keeps them), so the rerun of a guarded statement is exact
+            arr = np.array([None if v is None else _as_decimal(dt, v)
+                            for v in vals], dtype=object)
+        elif dt.name in ("string", "array", "map"):
             arr = np.empty(len(vals), dtype=object)
             for j, v in enumerate(vals):
                 arr[j] = v
@@ -1452,6 +1619,14 @@ def _agg_one(e: ast.Expr, key, groups, idx, cols, nulls, params, n):
     if isinstance(e, ast.Func) and e.name in ast.AGG_FUNCS:
         if e.name == "count" and not e.args:
             return len(idx)
+        if e.name in ("sum", "avg") \
+                and _exact_decimal_type(expr_type(e.args[0])):
+            v, nl = eval_exact_decimal(e.args[0], cols, nulls, params, n)
+            v = np.broadcast_to(v, (n,))[idx]
+            if nl is not None:
+                v = v[~np.broadcast_to(nl, (n,))[idx]]
+            return _exact_decimal_agg(e.name, e.args[0], v) \
+                if len(v) else None
         v, nl = eval_expr(e.args[0], cols, nulls, params, n)
         v = np.broadcast_to(v, (n,))[idx]
         if nl is not None:
@@ -1489,6 +1664,9 @@ def _agg_one(e: ast.Expr, key, groups, idx, cols, nulls, params, n):
         b = _agg_one(e.right, key, groups, idx, cols, nulls, params, n)
         if a is None or b is None:
             return None
+        if e.op == "/" or isinstance(a, float) or isinstance(b, float):
+            # an exact decimal meets float math (DOUBLE) as a float
+            a, b = _plain_number(a), _plain_number(b)
         return {"+": lambda: a + b, "-": lambda: a - b, "*": lambda: a * b,
                 "/": lambda: a / b if b else None,
                 "%": lambda: a % b}[e.op]()

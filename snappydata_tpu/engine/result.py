@@ -57,13 +57,19 @@ class Result:
 
 
 def unscale_decimal_col(c: np.ndarray, dt) -> np.ndarray:
-    """One column out of the exact-decimal scaled-int64 domain into
-    plain float64 (no-op for anything else) — the SINGLE implementation
-    every host consumer shares."""
-    if dt is not None and dt.name == "decimal" \
-            and getattr(dt, "is_exact", False) \
-            and np.issubdtype(np.asarray(c).dtype, np.integer):
+    """One column out of the exact-decimal domain (the device's scaled
+    int64, or the exact host rerun's Decimal objects) into plain
+    float64 (no-op for anything else) — the SINGLE implementation every
+    host consumer shares."""
+    if dt is None or dt.name != "decimal" \
+            or not getattr(dt, "is_exact", False):
+        return c
+    arr = np.asarray(c)
+    if np.issubdtype(arr.dtype, np.integer):
         return np.asarray(c, dtype=np.float64) / (10 ** dt.scale)
+    if arr.dtype == object:
+        return np.array([np.nan if v is None else float(v) for v in arr],
+                        dtype=np.float64)
     return c
 
 
@@ -84,10 +90,16 @@ def finalize_decimals(res: Result) -> Result:
     """User-boundary decode of DECIMAL columns to decimal.Decimal
     objects (the JDBC-BigDecimal analogue; ref readDecimal,
     encoders/.../encoding/ColumnEncoding.scala:137-140). Inside the
-    engine decimals ride as scaled int64 (exact path) or plain floats
-    (host fallback / p>18); both decode here:
+    engine an exact decimal of any precision up to 38 rides as scaled
+    int64 at Spark 2.1's result type (SUM DECIMAL(p+10, s), AVG
+    DECIMAL(p+4, s+4), a product of two money columns DECIMAL(32, 4));
+    the exact host rerun of a statement whose int64 guard tripped hands
+    back Decimal objects at that type, and plain floats come from a
+    float-plated decimal (`decimal_exact` off) or a host-evaluated
+    column:
 
     - integer column + exact DecimalType -> Decimal(v) * 10^-s, EXACT;
+    - object column -> kept (Decimal objects already, or host objects);
     - float column + DecimalType -> Decimal quantized at the column
       scale (exact whenever the f64 faithfully held the value).
 

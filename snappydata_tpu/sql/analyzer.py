@@ -150,10 +150,14 @@ def expr_type(e: ast.Expr) -> T.DataType:
                 "multi-column COUNT(DISTINCT a, b) is not supported yet")
         if low in ("count", "count_distinct", "approx_count_distinct"):
             return T.LONG
-        if low in ("avg", "stddev", "variance"):
-            # avg(decimal) = exact int64 sum / exact count, computed and
-            # declared as DOUBLE (divergence from the reference's
-            # scale+4 decimal quotient, types.DecimalType docstring)
+        if low == "avg":
+            # avg(decimal) is Spark 2.1's DECIMAL(p+4, s+4): the exact
+            # sum over the exact count, rounded HALF_UP at that scale
+            at = expr_type(e.args[0])
+            if at.name == "decimal":
+                return T.decimal_avg_type(at)
+            return T.DOUBLE
+        if low in ("stddev", "variance"):
             return T.DOUBLE
         if low == "sum":
             at = expr_type(e.args[0])

@@ -92,18 +92,25 @@ class DecimalType(DataType):
     semantics, encoders/.../encoding/ColumnEncoding.scala:137-140
     readDecimal):
 
-    - p <= 18 ("exact"): DEVICE representation is the scaled int64
-      unscaled value (v * 10^s) — SUM/MIN/MAX/COUNT/GROUP BY and
-      +,-,*,% / comparisons run as fast native integer ops and stay
-      EXACT; results decode to decimal.Decimal at the client edge. The
-      HOST mirror (plates, WAL, deltas, hosteval fallback, and
-      cross-server partial aggregates re-entering the distributed
-      merge) stays float64, which round-trips any
-      <= 15-significant-digit decimal exactly — so end-to-end
-      exactness holds through p=15 (per-shard partials included) and
-      device aggregation exactness through p=18.
-    - p > 18: lowers to the float path (f32 plates on TPU with f64
-      accumulators, <= 1e-6 relative — the pre-round-5 behavior).
+    - "exact" (the `decimal_exact` property, on by default), for every
+      p up to 38: the DEVICE representation is the scaled int64
+      unscaled value (v * 10^s). SUM/AVG/MIN/MAX/COUNT/GROUP BY and
+      +,-,*,% / comparisons run as native integer ops at Spark 2.1's
+      result types and stay EXACT; results decode to decimal.Decimal
+      at the client edge. Up to p = 18 every value fits int64 by its
+      type. Past it the value rides int64 under a guard: each product,
+      rescale, alignment and sum whose true value could leave int64
+      ORs a bound check into the statement's overflow flag, and a
+      tripped flag reruns the whole statement on the host in Python
+      integers (engine/hosteval.py), never clamping and never
+      wrapping. The HOST mirror (plates, WAL, deltas, and cross-server
+      partial aggregates re-entering the distributed merge) stays
+      float64, which round-trips any <= 15-significant-digit decimal
+      exactly: a stored value is exact through p = 15, a computed
+      result through p = 38.
+    - Division and math functions unscale to float64 (DOUBLE results).
+    - `decimal_exact` off: every decimal is a float plate (f32 on the
+      TPU with f64 accumulators, <= 1e-6 relative).
     """
 
     precision: int = 38
@@ -116,8 +123,7 @@ class DecimalType(DataType):
     def is_exact(self) -> bool:
         from snappydata_tpu import config
 
-        return (self.precision <= 18
-                and config.global_properties().decimal_exact)
+        return config.global_properties().decimal_exact
 
     @property
     def scale_factor(self) -> int:
@@ -225,34 +231,53 @@ def common_type(a: DataType, b: DataType) -> DataType:
 # ---------------------------------------------------------------------------
 # Exact-decimal type algebra (shared by the analyzer's expr_type and the
 # runtime's scaled-int lowering so declared scale always matches the
-# computed representation). Result precision/scale follow Spark's
-# DecimalPrecision rules, capped: a result that would exceed precision
-# 18 lowers to DOUBLE instead (int64 can't hold it; the reference holds
-# p <= 38 via BigDecimal — documented divergence).
+# computed representation). Result precision/scale follow Spark 2.1's
+# DecimalPrecision rules, bounded at 38 (`DecimalType.bounded`); an
+# integer operand is DECIMAL(digits, 0) (INT 10, BIGINT 20). A result
+# past DECIMAL_INT64_PRECISION still rides int64, under the overflow
+# guard (DecimalType docstring).
 # ---------------------------------------------------------------------------
 
-DECIMAL_EXACT_MAX_PRECISION = 18
+DECIMAL_MAX_PRECISION = 38
+# the widest precision whose every value fits int64 by its type alone
+DECIMAL_INT64_PRECISION = 18
 
-_INT_DIGITS = {"boolean": 1, "byte": 3, "short": 5, "int": 10, "long": 19}
+_INT_DIGITS = {"boolean": 1, "byte": 3, "short": 5, "int": 10, "long": 20}
+
+
+# wide enough for any DECIMAL(38, s) digit string, so that scaling one
+# never rounds
+EXACT_CONTEXT = __import__("decimal").Context(prec=80)
+
+
+class DecimalOverflow(ValueError):
+    """A decimal value whose scaled form leaves int64: the statement
+    takes the exact host path."""
 
 
 def _int_as_decimal(t: DataType) -> "DecimalType":
     return DecimalType("decimal", _INT_DIGITS[t.name], 0)
 
 
+def decimal_bounded(p: int, s: int) -> "DecimalType":
+    """Spark's DecimalType.bounded: precision and scale capped at 38."""
+    return DecimalType("decimal", min(p, DECIMAL_MAX_PRECISION),
+                       min(s, DECIMAL_MAX_PRECISION))
+
+
 def _decimal_align_type(a: "DecimalType", b: "DecimalType") -> DataType:
     s = max(a.scale, b.scale)
-    p = max(a.precision - a.scale, b.precision - b.scale) + s
-    if p > DECIMAL_EXACT_MAX_PRECISION:
-        return DOUBLE
-    return DecimalType("decimal", p, s)
+    return decimal_bounded(
+        max(a.precision - a.scale, b.precision - b.scale) + s, s)
 
 
 def decimal_binop_type(op: str, a: DataType, b: DataType
                        ) -> Optional[DataType]:
     """Result type of a +,-,*,%,/ over operands where at least one side
-    is decimal. None = not a decimal-typed operation (caller falls back
-    to common_type). DOUBLE = the operation leaves the exact domain."""
+    is decimal (Spark 2.1). None = not a decimal-typed operation
+    (caller falls back to common_type). DOUBLE = the operation leaves
+    the exact domain: division, a float operand, or a product of
+    float-plated decimals."""
     if "decimal" not in (a.name, b.name):
         return None
     if op == "/":
@@ -264,31 +289,37 @@ def decimal_binop_type(op: str, a: DataType, b: DataType
     da = a if a.name == "decimal" else _int_as_decimal(a)
     db = b if b.name == "decimal" else _int_as_decimal(b)
     if op == "*":
-        p = da.precision + db.precision + 1
-        s = da.scale + db.scale
-        if p > DECIMAL_EXACT_MAX_PRECISION or not (
-                isinstance(da, DecimalType) and da.is_exact
+        if not (isinstance(da, DecimalType) and da.is_exact
                 and isinstance(db, DecimalType) and db.is_exact):
             return DOUBLE
-        return DecimalType("decimal", p, s)
-    if op in ("+", "-", "%"):
-        s = max(da.scale, db.scale)
-        p = max(da.precision - da.scale, db.precision - db.scale) + s + 1
-        if p > DECIMAL_EXACT_MAX_PRECISION:
-            return DOUBLE
-        return DecimalType("decimal", p, s)
+        return decimal_bounded(da.precision + db.precision + 1,
+                               da.scale + db.scale)
+    s = max(da.scale, db.scale)
+    if op in ("+", "-"):
+        return decimal_bounded(
+            max(da.precision - da.scale, db.precision - db.scale) + s + 1,
+            s)
+    if op == "%":
+        return decimal_bounded(
+            min(da.precision - da.scale, db.precision - db.scale) + s, s)
     return None
 
 
 def decimal_sum_type(dt: DataType) -> DataType:
-    """SUM over a decimal column: widen precision (Spark: p+10), capped
-    at the exact-int64 limit — the in-trace overflow check reroutes to
-    the host path if a group total could actually exceed int64."""
+    """SUM over a decimal: DECIMAL(p + 10, s), bounded (Spark 2.1). The
+    in-trace overflow check reroutes to the host path if a group total
+    could leave int64."""
     if not isinstance(dt, DecimalType) or not dt.is_exact:
         return DOUBLE
-    return DecimalType("decimal",
-                       min(dt.precision + 10, DECIMAL_EXACT_MAX_PRECISION),
-                       dt.scale)
+    return decimal_bounded(dt.precision + 10, dt.scale)
+
+
+def decimal_avg_type(dt: DataType) -> DataType:
+    """AVG over a decimal: DECIMAL(p + 4, s + 4), bounded (Spark 2.1),
+    the exact sum over the count rounded HALF_UP at that scale."""
+    if not isinstance(dt, DecimalType) or not dt.is_exact:
+        return DOUBLE
+    return decimal_bounded(dt.precision + 4, dt.scale + 4)
 
 
 def decimal_to_unscaled(dt: DataType, arr) -> np.ndarray:
@@ -297,14 +328,23 @@ def decimal_to_unscaled(dt: DataType, arr) -> np.ndarray:
     matching _dec_rescale_int and java BigDecimal — np.round would tie
     to even and disagree with the device rescale path)."""
     a = np.asarray(arr, dtype=np.float64) * float(dt.scale_factor)
-    return (np.sign(a) * np.floor(np.abs(a) + 0.5)).astype(np.int64)
+    r = np.floor(np.abs(a) + 0.5)
+    if r.size and np.max(r) >= 2.0 ** 63:
+        raise DecimalOverflow(
+            f"a {dt} value leaves int64 once scaled: host path")
+    return (np.sign(a) * r).astype(np.int64)
 
 
 def unscaled_to_python(dt: DataType, v: int):
-    """Scaled int64 -> decimal.Decimal at the column scale."""
+    """Scaled integer -> decimal.Decimal at the column scale; None (SQL
+    NULL, Spark's CheckOverflow) where it has more digits than the
+    type's precision."""
     import decimal as _d
 
-    return _d.Decimal(int(v)).scaleb(-dt.scale)
+    v = int(v)
+    if abs(v) >= 10 ** dt.precision:
+        return None
+    return _d.Decimal(v).scaleb(-dt.scale, context=EXACT_CONTEXT)
 
 
 def decimal_float_converter(dt: DataType):

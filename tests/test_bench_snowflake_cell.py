@@ -253,9 +253,10 @@ def test_manifest_takes_the_snowflake_cell(man):
     cell = man.cell(CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == \
         (CONFIG, "join_q5", 1)
-    assert cell == man.doc["workloads"][-1]
+    # the fifth cell, appended after the four before it
+    assert cell == man.doc["workloads"][4]
     e2e = {e["name"]: e for e in man.doc["end_to_end"]}
-    assert e2e["query_rows_per_s"]["workloads"][-1] == CELL
+    assert e2e["query_rows_per_s"]["workloads"][3] == CELL
     assert [m["name"] for m in man.metrics_of(CELL, "end_to_end")] == \
         ["query_rows_per_s", "setup_s"]
     assert [m["name"] for m in man.metrics_of(CELL, "per_layer")] == METRICS

@@ -4,8 +4,8 @@ it refuses, and what it would hold in HBM, is known before a chip run.
 Nothing runs on the chip: no result, no time (the plan cases run one
 statement over one batch on the CPU, to be handed the plan).  The join
 case compiles for about two minutes (four wide sorts), Q3's group
-index for about 45 s (two) and Q3's whole plan for about a minute; the
-rest take seconds.
+index for about 45 s (two), Q3's whole plan for about a minute and the
+decimal Q1 for about 20 s; the rest take seconds.
 
 The topology is described inside a fixture, never at import or
 collection: only the worker that is handed this file loads the TPU's
@@ -72,12 +72,13 @@ def test_dict_space_count_transient_does_not_grow_with_batches(one_chip,
     assert temps[1] <= temps[0] + (1 << 20), temps
 
 
-def _plan_compiled_at_sf2(monkeypatch, one_chip, sql):
+def _plan_compiled_at_sf2(monkeypatch, one_chip, sql, load=None):
     """The statement's main (or single) phase as the engine itself
     builds it (float32 plates, the lanes on, TPC-H's dictionary widths),
     compiled for the described chip with every plate at SF 2's shape:
     96 batches of 131,072 rows.  The plan is traced here over one batch
-    of SF 0.002; only the shapes are SF 2's."""
+    of SF 0.002; only the shapes are SF 2's.  `load(session)` makes the
+    tables in place of the in-tree TPC-H loader."""
     import jax
     import jax.numpy as jnp
 
@@ -99,7 +100,7 @@ def _plan_compiled_at_sf2(monkeypatch, one_chip, sql):
         props.decimal_as_float64 = False
         props.set("agg_on_codes", "on")
         s = SnappySession(catalog=Catalog())
-        tpch.load_tpch(s, sf=0.002, seed=11)
+        (load or (lambda s: tpch.load_tpch(s, sf=0.002, seed=11)))(s)
         monkeypatch.setattr(CompiledPlan, "_noted_call", spy)
         s.sql(sql).rows()
         monkeypatch.setattr(CompiledPlan, "_noted_call", orig)
@@ -380,3 +381,31 @@ def test_q3_at_sf1_reduces_over_runs_with_no_scatter_and_no_loop(
     assert hlo.count(" sort(") == 7
     mem = comp.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9 / 5
+
+
+def test_decimal_q1_at_sf2_holds_no_scatter_and_no_loop(monkeypatch,
+                                                        one_chip):
+    """TPC-H Q1 over DECIMAL(15,2) money, its `main` phase as the chip's
+    backend lowers it (the strategies steered to the TPU's in the test):
+    two guarded s64 products a row and the exact int64 sums by unrolled
+    masked reductions: no scatter and no loop."""
+    import jax
+
+    from snappydata_tpu.utils import tpch
+
+    money = ("l_quantity", "l_extendedprice", "l_discount", "l_tax")
+    cols = ", ".join(f"CAST({c} AS DECIMAL(15,2)) AS {c}"
+                     if c in money else c
+                     for c in ("l_returnflag", "l_linestatus", "l_shipdate")
+                     + money)
+
+    def load(s):
+        tpch.load_tpch(s, sf=0.002, seed=11)
+        s.sql(f"CREATE TABLE li_dec AS SELECT {cols} FROM lineitem")
+
+    sql = tpch.Q1.replace("FROM lineitem", "FROM li_dec")
+    assert sql != tpch.Q1
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    hlo = _plan_compiled_at_sf2(monkeypatch, one_chip, sql, load)
+    assert " scatter(" not in hlo and " while(" not in hlo
+    assert "s64[" in hlo
